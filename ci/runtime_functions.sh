@@ -27,8 +27,10 @@ multichip_dryrun() {
 }
 
 sanity_bench() {
-    # the headline bench (prints one JSON line; heartbeats on stderr,
-    # internal deadline degrades instead of dying — see bench.py)
+    # the headline bench, on a machine with a TPU (prints one JSON
+    # line; heartbeats on stderr, internal deadline degrades instead of
+    # dying; exits non-zero on any other platform or when a phase
+    # raised — see bench.py)
     python bench.py
 }
 
@@ -106,31 +108,48 @@ telemetry_smoke() {
 }
 
 benchdiff_smoke() {
-    # round-over-round trend gate, three halves:
-    # 1) tools/benchdiff.py must parse EVERY committed BENCH_r*/
-    #    OPPERF_* artifact without crashing (r05's rc=124/parsed:null
-    #    included — flagged as a REGRESSION with reason "missing
-    #    metric") — unpinned, so new rounds stay covered;
-    # 2) the --fail-on-regression exit contract is asserted on the
-    #    r01–r05 window PINNED by glob, so a good future r06 making
-    #    the latest round green cannot flip this gate red;
-    # 3) round 14: BENCH_r06 exists — the unpinned run must give it a
-    #    real VERDICT (baseline/ok/improved/regression-with-a-number),
-    #    never the r05 "missing metric" shape again.
-    python tools/benchdiff.py > /tmp/benchdiff_smoke.txt
-    cat /tmp/benchdiff_smoke.txt
-    grep -Eq "r05 .*regression: missing metric" /tmp/benchdiff_smoke.txt
-    grep -Eq "^r06 " /tmp/benchdiff_smoke.txt
-    if grep -Eq "r06 .*missing metric" /tmp/benchdiff_smoke.txt; then
-        echo "benchdiff_smoke: r06 must carry a metric-backed verdict"
+    # round-over-round trend gate on a SYNTHETIC history (the repo
+    # commits no bench records; speed lives in PERF.md and the
+    # driver's ledger): five rounds whose last lost its metric
+    # (rc=124, parsed:null — the shape a killed run leaves).
+    # 1) tools/benchdiff.py must parse every record without crashing
+    #    and flag r05 as a REGRESSION with reason "missing metric";
+    # 2) earlier rounds carry metric-backed verdicts;
+    # 3) --fail-on-regression exits nonzero on the r05 gap.
+    local d
+    d=$(mktemp -d)
+    python - "$d" <<'PY'
+import json, sys
+d = sys.argv[1]
+for n, rc, parsed in [(1, 0, {"value": 2500.0}), (2, 0, {"value": 2600.0}),
+                      (3, 0, {"value": 2812.5}), (4, 0, {"value": 2849.29}),
+                      (5, 124, None)]:
+    json.dump({"n": n, "cmd": "bench", "rc": rc, "parsed": parsed},
+              open(f"{d}/BENCH_r{n:02d}.json", "w"))
+for n, ms in [(3, 1.0), (4, 1.05)]:
+    with open(f"{d}/OPPERF_r{n:02d}.jsonl", "w") as f:
+        for k, op in enumerate(("BatchNorm", "Convolution"), 1):
+            f.write(json.dumps({"op": op, "avg_time_ms": ms * k,
+                                "runs": 5}) + "\n")
+PY
+    python tools/benchdiff.py --bench "$d/BENCH_r*.json" \
+        --opperf "$d/OPPERF_r*.jsonl" > "$d/benchdiff_smoke.txt"
+    cat "$d/benchdiff_smoke.txt"
+    grep -Eq "r05 .*regression: missing metric" "$d/benchdiff_smoke.txt"
+    grep -Eq "^r04 " "$d/benchdiff_smoke.txt"
+    if grep -Eq "r04 .*missing metric" "$d/benchdiff_smoke.txt"; then
+        echo "benchdiff_smoke: r04 must carry a metric-backed verdict"
+        rm -rf "$d"
         return 1
     fi
-    if python tools/benchdiff.py --bench 'BENCH_r0[1-5].json' \
-            --opperf 'OPPERF_r0[1-5].jsonl' --fail-on-regression \
+    if python tools/benchdiff.py --bench "$d/BENCH_r*.json" \
+            --opperf "$d/OPPERF_r*.jsonl" --fail-on-regression \
             > /dev/null 2>&1; then
         echo "benchdiff_smoke: expected nonzero exit on the r05 gap"
+        rm -rf "$d"
         return 1
     fi
+    rm -rf "$d"
 }
 
 pallas_smoke() {

@@ -6,15 +6,19 @@ the TPU-native stand-in for the reference's C++ io/ tree
 (src/io/iter_image_recordio_2.cc + image_aug_default.cc).  All heavy
 loops run with the GIL released (ctypes drops it for the call).
 
-``get_lib()`` returns None when the toolchain/libjpeg are unavailable;
-callers fall back to the pure-Python (PIL/cv2) path.
+``get_lib()`` returns None when the library cannot be had and the
+callers then take the pure-Python (PIL/cv2) path; the first call says
+which of the two causes it was: no toolchain on this host, or a build
+or load that failed (with the compiler's own words).
 """
 from __future__ import annotations
 
 import ctypes
 import os
+import shutil
 import subprocess
 import threading
+import warnings
 
 import numpy as onp
 
@@ -59,18 +63,30 @@ def _build():
 
 
 def get_lib():
-    """The loaded native library, building it on first call; None if
-    the build fails (pure-Python fallback paths take over)."""
+    """The loaded native library, building it on first call; None
+    (after one warning naming the cause) where it cannot be had and
+    the pure-Python paths take over."""
     global _lib, _tried
     with _lock:
         if _tried:
             return _lib
         _tried = True
+        if shutil.which("g++") is None:
+            warnings.warn(
+                "native data plane: no g++ on this host, so "
+                "src/recordio_native.cc is not built; decode and "
+                "augment run in pure Python", RuntimeWarning,
+                stacklevel=2)
+            return None
         try:
-            path = _build()
-            lib = ctypes.CDLL(path)
-        except Exception:
-            _lib = None
+            lib = ctypes.CDLL(_build())
+        except (subprocess.CalledProcessError, OSError) as e:
+            detail = (getattr(e, "stderr", "") or str(e)).strip()
+            warnings.warn(
+                "native data plane: building or loading "
+                "src/recordio_native.cc FAILED though g++ is present; "
+                "decode and augment run in pure Python.  "
+                + detail[-2000:], RuntimeWarning, stacklevel=2)
             return None
         i64 = ctypes.c_int64
         u8p = ctypes.POINTER(ctypes.c_uint8)

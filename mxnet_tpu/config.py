@@ -19,7 +19,8 @@ from typing import Any, Callable, Optional
 from .base import MXNetError
 
 __all__ = ["register_env", "get_env", "list_env", "describe_env",
-           "setup_compilation_cache", "ParamStruct", "field"]
+           "compilation_cache_dir", "setup_compilation_cache",
+           "ParamStruct", "field"]
 
 _ENV: dict[str, "EnvVar"] = {}
 
@@ -150,12 +151,14 @@ register_env("MXNET_GPU_MEM_POOL_TYPE", "Naive", str,
              "Reference allocator strategy; XLA owns HBM pooling.",
              live=False)
 register_env("JAX_COMPILATION_CACHE_DIR", "", str,
-             "Persistent XLA compilation cache directory.  When set, "
-             "every jitted program (train step, CachedOp, executor, "
+             "Persistent XLA compilation cache directory.  Every "
+             "jitted program (train step, CachedOp, executor, "
              "predictor) is cached on disk keyed by HLO, so re-binds "
-             "and bench recaptures skip recompilation entirely.  The "
-             "reference analog is the cuDNN algo registry persisting "
-             "autotune winners across Bind calls.")
+             "and bench recaptures skip recompilation entirely.  Set: "
+             "that directory and no other.  Empty: the fixed, "
+             "git-ignored <checkout>/.cache/xla.  The reference analog "
+             "is the cuDNN algo registry persisting autotune winners "
+             "across Bind calls.")
 register_env("MXNET_CONV_1X1_DOT", False, bool,
              "Lower channel-last 1x1 convolutions to dot_general "
              "(native MXU matmul, no layout change).  Off by default; "
@@ -169,8 +172,8 @@ register_env("MXNET_AUTOTUNE", 1, int,
              "(cudnn_tune='fastest' on every bind).")
 register_env("MXNET_AUTOTUNE_CACHE_DIR", "", str,
              "Directory for autotune.json (persisted variant winners). "
-             "Empty = next to JAX_COMPILATION_CACHE_DIR, falling back "
-             "to ~/.cache/mxnet_tpu.")
+             "Empty = beside the XLA compilation cache "
+             "(config.compilation_cache_dir).")
 register_env("MXNET_PALLAS_OPT", "", str,
              "Hand override for the 'fused_bucket_opt' autotune "
              "variant (round 14): 1 forces the Pallas fused-bucket "
@@ -513,16 +516,30 @@ register_env("DMLC_PS_ROOT_PORT", "9091", str, "Coordinator port.")
 # ------------------------------------------- persistent compilation cache
 _CC_STATE = {"dir": None}
 
+#: where the caches live when JAX_COMPILATION_CACHE_DIR does not say:
+#: one fixed, git-ignored directory inside the checkout.  The path is
+#: part of the cache key, so it is never a temporary, per-pid or
+#: per-run name.
+_DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".cache", "xla")
 
-def setup_compilation_cache(path=None):
-    """Enable jax's persistent compilation cache (no-op when unset).
 
-    Reads ``JAX_COMPILATION_CACHE_DIR`` from the registry unless an
-    explicit ``path`` is given; returns the active cache dir or None.
-    Wired into bench.py, ``Module.bind``, ``make_train_step`` and the
-    parallel predictor so a recapture/re-bind of an already-seen
-    program costs a disk read instead of an XLA compile (the cuDNN
-    algo-registry persistence analog,
+def compilation_cache_dir():
+    """The one directory the persistent caches use (XLA programs, and
+    ``autotune.json`` beside them): ``JAX_COMPILATION_CACHE_DIR`` if
+    set, and then no other; else ``<checkout>/.cache/xla``."""
+    return get_env("JAX_COMPILATION_CACHE_DIR") or _DEFAULT_CACHE_DIR
+
+
+def setup_compilation_cache():
+    """Enable jax's persistent compilation cache at
+    :func:`compilation_cache_dir` and return that directory.
+
+    Called by bench.py, ``Module.bind``, ``make_train_step``, the
+    parallel predictor and chip_smoke.py, so a recapture/re-bind of an
+    already-seen program costs a disk read instead of an XLA compile
+    (the cuDNN algo-registry persistence analog,
     src/operator/nn/cudnn/cudnn_algoreg-inl.h).
 
     The min-compile-time/min-entry-size thresholds are dropped to zero
@@ -530,21 +547,15 @@ def setup_compilation_cache(path=None):
     cache — bench recapture robustness matters more here than cache
     hygiene.
     """
-    p = path if path is not None else get_env("JAX_COMPILATION_CACHE_DIR")
-    if not p:
-        return None
+    p = compilation_cache_dir()
     if _CC_STATE["dir"] == p:
         return p  # already active — config.update churn is not free
     import jax
 
     os.makedirs(p, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", p)
-    for opt, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                     ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(opt, val)
-        except (AttributeError, KeyError):
-            pass  # knob absent in this jax — the cache still works
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _CC_STATE["dir"] = p
     return p
 

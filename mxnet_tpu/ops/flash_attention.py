@@ -44,6 +44,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from . import kernel_target
 from .registry import register_op
 
 _BLOCK_Q = 128
@@ -175,46 +176,39 @@ def _flash_forward_pallas(q, k, v, causal, sm_scale, block_q=_BLOCK_Q,
     return out.reshape(b, h, sq, d)
 
 
-_FALLBACK_SEEN = set()
+#: VMEM the kernel may plan for its K and V blocks.  Both enter as one
+#: block of the whole key sequence and Pallas double-buffers each, so
+#: the plan is 4 * seq_k * head_dim (padded to the 128 lanes) *
+#: itemsize; the rest of the v5e compiler's 16 MiB scoped limit is left
+#: to the q/o blocks and the f32 score and accumulator tiles.  Read off
+#: the chip's compiler (tests/test_tpu_compile.py): at d=128 bf16 holds
+#: seq_k 14336 and is refused at 16384 (16.12M of 16.00M), f32 holds
+#: 6144 and is refused at 8192.
+_KV_VMEM_BUDGET = 12 * 1024 * 1024
+
+
+def max_seq_k(head_dim, dtype):
+    """Longest key sequence the kernel holds for this head size and
+    dtype (a multiple of the 128 block), from the VMEM plan above."""
+    lanes = -(-int(head_dim) // 128) * 128
+    per_key = 4 * lanes * jnp.dtype(dtype).itemsize
+    return _KV_VMEM_BUDGET // per_key // _BLOCK_K * _BLOCK_K
 
 
 def _fallback_event(reason, q, k, block_q, block_k):
-    """A shape that wanted the kernel but fell back to the fused jnp
-    math: emit an ``autotune`` run-log event naming the reason (the
-    silent half of _can_use_pallas, now attributed).  Deduped per
-    (shapes, blocks) — an eager predict loop re-executes this per
-    call, and N identical records explain nothing the first did not."""
-    dedup = (tuple(q.shape), tuple(k.shape), block_q, block_k)
-    if dedup in _FALLBACK_SEEN:
-        return
-    try:
-        from .. import telemetry
-
-        if telemetry.current() is None:
-            return  # unarmed: nothing recorded, don't latch the dedup
-        telemetry.event(
-            "autotune", op="flash_attention", winner="naive",
-            cached=False, reason=str(reason),
-            shape=str((tuple(q.shape), tuple(k.shape))),
-            blocks=f"{block_q}x{block_k}")
-        _FALLBACK_SEEN.add(dedup)
-    except Exception:
-        pass  # telemetry must never kill a trace
+    """A shape that wanted the kernel but runs the fused jnp math:
+    counted, and named in the run log (ops/kernel_target.declined)."""
+    kernel_target.declined(
+        "flash_attention", reason, "naive",
+        shape=(tuple(q.shape), tuple(k.shape)),
+        blocks=f"{block_q}x{block_k}")
 
 
-def _on_tpu_target():
-    from .pallas_conv import _on_tpu  # ONE backend probe for all three
-    #                                   kernel families (ops package
-    #                                   import order: probe lazily)
-
-    return _on_tpu()
-
-
-def _can_use_pallas(q, k, block_q, block_k):
-    """Feasibility of the kernel for this shape+platform.  No longer a
-    silent gate: a tile-alignment miss emits a telemetry event naming
-    the reason (and the ``pallas_pad`` variant exists exactly so these
-    shapes can still race aligned-padded)."""
+def _kernel_holds(q, k, block_q, block_k):
+    """Can the kernel hold this shape at these blocks?  Decided here,
+    before lowering: a miss is a counted event naming the reason, not
+    a compiler error (and the ``pallas_pad`` variant exists so that
+    unaligned shapes can still race padded)."""
     sq, sk = q.shape[2], k.shape[2]
     if sq % block_q or sk % block_k:
         _fallback_event(
@@ -223,11 +217,15 @@ def _can_use_pallas(q, k, block_q, block_k):
             " the pallas_pad variant can race this shape padded",
             q, k, block_q, block_k)
         return False
-    return _on_tpu_target()
-
-
-def _tiles(q, k, block_q=_BLOCK_Q, block_k=_BLOCK_K):
-    return q.shape[2] % block_q == 0 and k.shape[2] % block_k == 0
+    limit = max_seq_k(k.shape[3], k.dtype)
+    if sk > limit:
+        _fallback_event(
+            f"seq_k {sk} exceeds the kernel's VMEM plan (K and V enter "
+            f"as whole-sequence blocks; {limit} keys fit at head_dim "
+            f"{k.shape[3]} {jnp.dtype(k.dtype).name})",
+            q, k, block_q, block_k)
+        return False
+    return True
 
 
 @functools.partial(jax.custom_vjp,
@@ -239,11 +237,7 @@ def _flash(q, k, v, causal, sm_scale, interpret, variant, kv_valid,
                                 kv_valid=kv_valid, q_valid=q_valid)
     if variant in _VARIANT_BLOCKS:
         bq, bk = _VARIANT_BLOCKS[variant]
-        if not _tiles(q, k, bq, bk):
-            _fallback_event(
-                f"forced variant {variant!r} cannot tile "
-                f"(seq_q {q.shape[2]}, seq_k {k.shape[2]})",
-                q, k, bq, bk)
+        if not _kernel_holds(q, k, bq, bk):
             return _naive_attention(q, k, v, causal, sm_scale,
                                     kv_valid=kv_valid, q_valid=q_valid)
         # an explicitly chosen kernel variant runs the kernel even
@@ -251,12 +245,12 @@ def _flash(q, k, v, causal, sm_scale, interpret, variant, kv_valid,
         return _flash_forward_pallas(
             q, k, v, causal, sm_scale, block_q=bq, block_k=bk,
             kv_valid=kv_valid, q_valid=q_valid,
-            interpret=interpret or not _on_tpu_target())
-    # default heuristic (no variant decision): kernel on TPU where the
-    # shape tiles, fused jnp otherwise — _can_use_pallas emits the
-    # attributed fallback event on a tile-alignment miss
-    if (interpret and _tiles(q, k)) or \
-            _can_use_pallas(q, k, _BLOCK_Q, _BLOCK_K):
+            interpret=interpret or not kernel_target.on_tpu())
+    # default heuristic (no variant decision): the kernel on a TPU (or
+    # where a test asked for interpret mode) when it holds the shape,
+    # the fused jnp math otherwise
+    if _kernel_holds(q, k, _BLOCK_Q, _BLOCK_K) and \
+            (interpret or kernel_target.on_tpu()):
         return _flash_forward_pallas(q, k, v, causal, sm_scale,
                                      kv_valid=kv_valid,
                                      q_valid=q_valid,

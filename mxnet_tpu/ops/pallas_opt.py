@@ -32,33 +32,16 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pallas imports only where available (CPU wheels carry it too)
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
+from . import kernel_target
 
 #: LARS buckets with more parameters than this fall back to jnp (the
 #: per-segment reductions unroll inside the kernel)
 _MAX_SEGMENTS = 128
 
 _LANE = 128
-
-
-def _on_tpu():
-    from .pallas_conv import _on_tpu as _probe  # the shared backend
-    #                                             probe (one copy)
-
-    return _probe()
-
-
-def _default_interpret():
-    """Interpret mode off-TPU: same kernel code, reference semantics —
-    slow, so it only ever runs when a test forces the variant or a
-    CPU race measures it (where it loses to jnp, correctly)."""
-    return not _on_tpu()
 
 
 def _view2d(flat):
@@ -264,10 +247,13 @@ def _elementwise_call(kernel, n_in, n_out, operands, out_dtypes,
                  for dt in out_dtypes]
     scratch = []
     if with_finite:
-        out_specs = out_specs + [pl.BlockSpec((1, 1), lambda i: (0, 0))]
+        # the count is a scalar: Mosaic stores scalars to SMEM only
+        # ("Cannot store scalars to VMEM"), so both the grid-carried
+        # accumulator and the (1, 1) result live there
+        out_specs = out_specs + [pl.BlockSpec(memory_space=pltpu.SMEM)]
         out_shape = out_shape + [jax.ShapeDtypeStruct((1, 1),
                                                       jnp.float32)]
-        scratch = [pltpu.VMEM((1, 1), jnp.float32)]
+        scratch = [pltpu.SMEM((1, 1), jnp.float32)]
     outs = pl.pallas_call(
         functools.partial(kernel, rows=rows, bm=bm),
         grid=(nb,),
@@ -294,8 +280,6 @@ def supported(opt, dtype, nseg=None):
 
     from ..optimizer.optimizer import LARS, SGD, Adam
 
-    if not _HAVE_PALLAS:
-        return "pallas unavailable"
     dt = onp.dtype(dtype)
     if type(opt) is SGD:
         if dt not in (onp.dtype(onp.float32), onp.dtype(jnp.bfloat16)):
@@ -328,10 +312,16 @@ def bucket_update(opt, w, g, state, t, *, seg=None, axis_name=None,
     nseg = None
     if seg is not None:
         nseg = int(seg[1])
-    if supported(opt, w.dtype, nseg=nseg) is not None:
+    reason = supported(opt, w.dtype, nseg=nseg)
+    if reason is not None:
+        kernel_target.declined("fused_bucket_opt", reason, "jnp",
+                               shape=tuple(w.shape))
         return None
     if interpret is None:
-        interpret = _default_interpret()
+        # compiled on a TPU; interpret mode elsewhere (same kernel
+        # code, reference semantics, slow) so the CPU tests and a CPU
+        # race still run the kernel's own path
+        interpret = not kernel_target.on_tpu()
     rescale = float(opt.rescale_grad)
     clip = None if opt.clip_gradient is None else \
         float(opt.clip_gradient)
@@ -401,6 +391,9 @@ def _lars_bucket(opt, w, g, state, seg, axis_name, rescale, clip,
     if seg is None:
         # whole-tensor bucket: LARS.fused_bucket_update degenerates to
         # the per-param rule; no kernel form for that path
+        kernel_target.declined(
+            "fused_bucket_opt", "lars bucket carries no segment ids "
+            "(whole-tensor bucket)", "jnp", shape=tuple(w.shape))
         return None
     (mom,) = state
     ids, nseg = seg
@@ -408,7 +401,12 @@ def _lars_bucket(opt, w, g, state, seg, axis_name, rescale, clip,
     ids = jnp.asarray(ids, jnp.int32)
     v2w, v2g, v2s = _view2d(w), _view2d(g), _view2d(ids)
     rows, width = v2w.shape
-    bm, nb = _grid_plan(v2w, 5)
+    # the norms kernel unrolls one masked reduction per segment and
+    # the compiler keeps part of each alive on the VMEM stack (about a
+    # third of a block per segment, read off the v5e compiler's scoped
+    # allocation: 17 MB at 32 segments x 2048 rows) — plan the block
+    # as if every three segments were one more streamed operand
+    bm, nb = _grid_plan(v2w, 5 + int(nseg) // 3)
     blk = pl.BlockSpec((bm, width), lambda i: (i, 0))
     vec = pl.BlockSpec((1, segp), lambda i: (0, 0))
     wss, gss = pl.pallas_call(

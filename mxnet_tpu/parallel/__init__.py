@@ -40,17 +40,12 @@ __all__ = ["get_mesh", "functionalize", "make_train_step",
 
 
 def compat_shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` with one signature across jax releases: it
-    graduated from ``jax.experimental.shard_map`` (kwarg ``check_rep``)
-    to top-level ``jax.shard_map`` (kwarg ``check_vma``) — 0.4.x wheels
-    only carry the former."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
+    """``jax.shard_map`` with the package's default of no replication
+    check (the per-device bodies here return values the checker cannot
+    prove replicated, e.g. gathered buckets)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
 
 #: parameter-name suffixes that stay fp32 under mixed precision (the AMP
 #: policy the reference encodes in contrib/amp/lists: norm affine+stats)

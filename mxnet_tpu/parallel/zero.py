@@ -444,6 +444,56 @@ def analytic_exchange_bytes(plan, n_shards, stage):
     return {"reduce-scatter": rs, "all-gather": ag, "all-reduce": ar}
 
 
+# ------------------------------------------- compiled-HLO collective lines
+_COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
+                "collective-permute", "all-to-all")
+
+_DT_BYTES = {"f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4,
+             "f64": 8, "s64": 8, "u64": 8, "s16": 2, "u16": 2,
+             "s8": 1, "u8": 1, "pred": 1, "f8e4m3fn": 1, "f8e5m2": 1}
+
+# ``%name = <result shape> <kind>[-start](operands...``: the result
+# shape is whatever sits between the first " = " and the op kind, so
+# nothing in it has to be spelled out — XLA combines collectives into
+# one tuple-shaped op whose shape text carries ``/*index=5*/`` comments,
+# and the TPU compiler adds tiling such as ``{0:T(1024)S(1)}``.  An op
+# kind is preceded by a blank; a *reference* to a collective
+# (``get-tuple-element(%all-reduce.3)``) by ``%``, and ``-done`` ops
+# do not match.
+_COLLECTIVE_LINE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(.*?)\s("
+    + "|".join(_COLLECTIVES) + r")(-start)?\(")
+_COLLECTIVE_DONE = re.compile(
+    r"\s(" + "|".join(_COLLECTIVES) + r")-done\(")
+_SHAPE = re.compile(
+    "(" + "|".join(sorted(_DT_BYTES, key=len, reverse=True))
+    + r")\[([\d,]*)\]")
+
+
+def _collective_shapes(line):
+    """``(kind, is_async_start, [(dtype, elements), ...])`` for an HLO
+    instruction line that launches a collective, else None.  The
+    shapes are the launch's results: an async ``all-gather-start`` or
+    ``collective-permute-start`` returns (operands..., results...,
+    scalar contexts), of which only the results are kept; an
+    ``all-reduce-start`` returns its results alone."""
+    m = _COLLECTIVE_LINE.match(line)
+    if not m:
+        return None
+    shapes_text, kind, start = m.groups()
+    shapes = []
+    for sm in _SHAPE.finditer(shapes_text):
+        n = 1
+        for d in sm.group(2).split(","):
+            if d:
+                n *= int(d)
+        shapes.append((sm.group(1), n))
+    if start and kind != "all-reduce":
+        shapes = [sh for sh in shapes if sh[1] > 1] or shapes
+        shapes = shapes[len(shapes) // 2:]
+    return kind, bool(start), shapes
+
+
 # -------------------------------------------- overlap proof (Perfetto)
 def overlap_report(hlo_text, plan, n_shards):
     """Structural overlap evidence for the stage-3 prefetch, read off
@@ -464,37 +514,27 @@ def overlap_report(hlo_text, plan, n_shards):
     for i, b in enumerate(plan):
         sizes.setdefault(b.padded, []).append(i)
     lines = [ln for ln in hlo_text.splitlines() if " = " in ln]
-    shape_pat = re.compile(
-        r"(f32|bf16|f16|s32|u32|f64|s64|s8|u8|pred)\[([\d,]*)\]")
-    ag_pat = re.compile(r"=\s*[\w\[\],{}: /()]*all-gather"
-                        r"(-start)?[.\d]*\(")
-    done_pat = re.compile(r"all-gather-done")
+    launches = [_collective_shapes(ln) for ln in lines]
+    dones = [_COLLECTIVE_DONE.search(ln) for ln in lines]
     gathers = []
-    for pos, ln in enumerate(lines):
-        m = ag_pat.search(ln)
-        if not m:
+    for pos, parsed in enumerate(launches):
+        if parsed is None or parsed[0] != "all-gather":
             continue
-        sm = shape_pat.search(ln)
-        if not sm:
-            continue
-        n = 1
-        for d in sm.group(2).split(","):
-            if d:
-                n *= int(d)
-        if m.group(1):  # -start carries (operand, result) pairs
-            n //= 2
-        bucket = sizes.get(n)
-        if not bucket:
-            continue
-        gathers.append({"bucket": bucket[0], "pos": pos,
-                        "async": bool(m.group(1)), "done_pos": None,
-                        "compute_between": 0})
-    is_collective = [bool(re.search("|".join(_COLLECTIVES), ln))
-                     for ln in lines]
+        _, is_async, shapes = parsed
+        # a combined gather carries several buckets in one tuple
+        for _, n in shapes:
+            bucket = sizes.get(n)
+            if not bucket:
+                continue
+            gathers.append({"bucket": bucket[0], "pos": pos,
+                            "async": is_async, "done_pos": None,
+                            "compute_between": 0})
+    is_collective = [a is not None or d is not None
+                     for a, d in zip(launches, dones)]
     for gi, g in enumerate(gathers):
         if g["async"]:
             for pos in range(g["pos"] + 1, len(lines)):
-                if done_pat.search(lines[pos]):
+                if dones[pos] and dones[pos].group(1) == "all-gather":
                     g["done_pos"] = pos
                     break
             end = g["done_pos"] if g["done_pos"] is not None \
@@ -610,47 +650,28 @@ def sharding_rule_reasons(optimizer):
 
 
 # ------------------------------------------------- HLO collective counter
-_COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
-                "collective-permute", "all-to-all")
-
-_DT_BYTES = {"f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4,
-             "f64": 8, "s64": 8, "s8": 1, "u8": 1, "pred": 1}
-
-
 def collective_bytes(hlo_text):
-    """Per-collective output bytes + launch counts in a compiled HLO —
-    the per-step cross-chip traffic the sharded program will put on
-    ICI/DCN.  (Moved from ``__graft_entry__._collective_bytes`` so
-    bench.py's collectives phase and the tier-1 budget tests share
-    one parser.)"""
+    """Per-collective output bytes, launch counts and tensor counts in
+    a compiled HLO — the per-step cross-chip traffic the sharded
+    program will put on ICI/DCN.  ``counts`` are launches; XLA combines
+    neighbouring collectives into one tuple-shaped launch, so
+    ``tensors`` counts the arrays those launches carry (seven gradient
+    all-reduces combined into one op: 1 launch, 7 tensors).  (Moved
+    from ``__graft_entry__._collective_bytes`` so bench.py's
+    collectives phase and the tier-1 budget tests share one parser.)"""
     out = {k: 0 for k in _COLLECTIVES}
     counts = {k: 0 for k in _COLLECTIVES}
-    pat = re.compile(
-        r"= (\(?[\w\[\],{}: /]*\)?) ("
-        + "|".join(_COLLECTIVES) + r")(?:-start)?[.\d]*\(")
-    shape_pat = re.compile(
-        r"(f32|bf16|f16|s32|u32|f64|s64|s8|u8|pred)\[([\d,]*)\]")
+    tensors = {k: 0 for k in _COLLECTIVES}
     for line in hlo_text.splitlines():
         # async collectives lower to -start/-done pairs: count starts
-        m = pat.search(line)
-        if not m:
+        parsed = _collective_shapes(line)
+        if parsed is None:
             continue
-        shapes, kind = m.groups()
-        total = 0
-        for sm in shape_pat.finditer(shapes):
-            n = 1
-            for d in sm.group(2).split(","):
-                if d:
-                    n *= int(d)
-            total += n * _DT_BYTES[sm.group(1)]
-        if "-start" in line[m.start():m.end()]:
-            # async -start results carry (operand..., result...) pairs
-            # (plus tiny u32 contexts): halve to approximate the real
-            # wire bytes instead of double-counting
-            total //= 2
-        out[kind] += total
+        kind, _, shapes = parsed
+        out[kind] += sum(n * _DT_BYTES[dt] for dt, n in shapes)
         counts[kind] += 1
-    return {"bytes": out, "counts": counts,
+        tensors[kind] += len(shapes)
+    return {"bytes": out, "counts": counts, "tensors": tensors,
             "total_bytes": sum(out.values())}
 
 
@@ -828,13 +849,13 @@ class ShardedBucketUpdater:
         override and no cache: jnp (the interpret-mode kernel can only
         lose; racing it would cost minutes to learn that)."""
         from .. import autotune as _at
-        from ..ops import pallas_opt
+        from ..ops import kernel_target
 
         decided = resolve_bucket_variant(self.optimizer, self.plan,
                                          self.mesh)
         if decided is not None:
             return decided
-        if not pallas_opt._on_tpu():
+        if not kernel_target.on_tpu():
             return False
         shape, dtype, mesh_d = self._variant_key()
 

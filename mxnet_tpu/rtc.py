@@ -49,10 +49,10 @@ class PallasModule:
             jax.ShapeDtypeStruct(tuple(s), d) for s, d in out_shapes]
         self._grid = grid
         if interpret is None:
-            try:
-                interpret = jax.default_backend() != "tpu"
-            except Exception:
-                interpret = True
+            # compiled by Mosaic on a TPU, interpret mode elsewhere
+            from .ops import kernel_target
+
+            interpret = not kernel_target.on_tpu()
         self._interpret = interpret
         kwargs = {"grid": grid} if grid else {}
         single = len(self._out_shapes) == 1

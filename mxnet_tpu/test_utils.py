@@ -26,18 +26,12 @@ __all__ = [
 
 
 def enable_x64():
-    """Context manager enabling 64-bit jax types, on any jax release:
-    ``jax.enable_x64`` became a top-level context manager only in
-    recent jax; 0.4.x wheels carry the identical manager under
-    ``jax.experimental``.  Used by the f64 reference rungs of the
-    dtype ladder and the FD gradient sweeps."""
+    """Context manager enabling 64-bit jax types.  Used by the f64
+    reference rungs of the dtype ladder and the FD gradient sweeps."""
     import jax
 
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64(True)
-    from jax.experimental import enable_x64 as _ex64
+    return jax.enable_x64(True)
 
-    return _ex64()
 
 _DEFAULT_RTOL = {
     onp.dtype(onp.float16): 1e-2,

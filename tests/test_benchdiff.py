@@ -1,11 +1,14 @@
-"""tools/benchdiff.py: the committed bench artifacts become a trend.
+"""tools/benchdiff.py: a history of bench records becomes a trend.
 
-The acceptance row: run over the repo's own BENCH_r01–r05 /
-OPPERF_r03–r04 artifacts, the differ must flag r05's missing metric as
-a REGRESSION (not crash on the ``parsed: null`` file) and exit nonzero
-under ``--fail-on-regression`` — that is the ``benchdiff_smoke`` CI
-cell.  Synthetic artifacts cover the p50/p99 tail-latency columns and
-the threshold arithmetic both ways.
+The acceptance row: over a five-round history whose last round lost its
+metric (``rc=124``, ``parsed: null`` — the shape a killed run leaves),
+the differ must flag that round as a REGRESSION (not crash on the file)
+and exit nonzero under ``--fail-on-regression`` — that is the
+``benchdiff_smoke`` CI cell.  Every record is synthetic and written to
+``tmp_path``: the repo commits no bench records (speed lives in
+PERF.md and the driver's ledger).  Further synthetic artifacts cover
+the p50/p99 tail-latency columns and the threshold arithmetic both
+ways.
 """
 import importlib.util
 import json
@@ -31,9 +34,28 @@ def _load():
 bd = _load()
 
 
-# ------------------------------------------------- committed artifacts
-def test_committed_artifacts_flag_r05_as_regression(capsys):
-    rc = bd.main([])
+# ------------------------------------- a history that ends in a lost round
+def _lost_round_history(tmp_path):
+    """Five headline rounds, the last killed before it printed a
+    metric, plus two per-op rounds; returns the two globs."""
+    for n, rc, parsed in [
+            (1, 0, {"value": 2500.0}), (2, 0, {"value": 2600.0}),
+            (3, 0, {"value": 2812.5}), (4, 0, {"value": 2849.29}),
+            (5, 124, None)]:
+        (tmp_path / f"BENCH_r{n:02d}.json").write_text(json.dumps(
+            {"n": n, "cmd": "bench", "rc": rc, "parsed": parsed}))
+    for n, ms in [(3, 1.0), (4, 1.05)]:
+        (tmp_path / f"OPPERF_r{n:02d}.jsonl").write_text("".join(
+            json.dumps({"op": op, "avg_time_ms": ms * k, "runs": 5})
+            + "\n" for k, op in enumerate(("BatchNorm", "Convolution",
+                                           "FullyConnected"), 1)))
+    return (str(tmp_path / "BENCH_r0[1-5].json"),
+            str(tmp_path / "OPPERF_r0[1-5].jsonl"))
+
+
+def test_lost_round_is_flagged_as_regression(tmp_path, capsys):
+    bench, opperf = _lost_round_history(tmp_path)
+    rc = bd.main(["--bench", bench, "--opperf", opperf])
     out = capsys.readouterr().out
     assert rc == 0  # reporting mode never fails the build
     assert "r05" in out
@@ -45,22 +67,17 @@ def test_committed_artifacts_flag_r05_as_regression(capsys):
     assert "opperf trend" in out
 
 
-def test_committed_artifacts_fail_on_regression_exits_nonzero():
-    # pinned to the r01–r05 window: r05's missing metric is the latest
-    # round INSIDE it forever, so a good future r06 commit cannot flip
-    # this assertion (the unpinned run above still covers new rounds)
-    rc = bd.main(["--bench", os.path.join(_REPO, "BENCH_r0[1-5].json"),
-                  "--opperf", os.path.join(_REPO, "OPPERF_r0[1-5].jsonl"),
+def test_lost_round_fails_on_regression_exits_nonzero(tmp_path):
+    bench, opperf = _lost_round_history(tmp_path)
+    rc = bd.main(["--bench", bench, "--opperf", opperf,
                   "--fail-on-regression"])
     assert rc == 2
 
 
-def test_cli_entrypoint_runs():
-    # --bench pinned to r01–r05 so the failures list (latest-round
-    # scoped) keeps naming r05 after future rounds are committed
+def test_cli_entrypoint_runs(tmp_path):
+    bench, _ = _lost_round_history(tmp_path)
     r = subprocess.run(
-        [sys.executable, _TOOL, "--json",
-         "--bench", os.path.join(_REPO, "BENCH_r0[1-5].json")],
+        [sys.executable, _TOOL, "--json", "--bench", bench],
         capture_output=True, text=True, cwd=_REPO)
     assert r.returncode == 0, r.stderr[-500:]
     doc = json.loads(r.stdout)

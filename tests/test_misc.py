@@ -172,3 +172,43 @@ def test_attach_grad_add_accumulates():
             y = (x * 3).sum()
         y.backward()
     onp.testing.assert_allclose(x.grad.asnumpy(), [6.0, 6.0, 6.0])
+
+
+# ------------------------------------- one process per chip (tools/launch.py)
+def _load_launcher():
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "launch.py")
+    spec = importlib.util.spec_from_file_location("_launch_under_test",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("workers,cpu,chips,refused", [
+    (2, False, 1, True),    # the second worker would die on the lock
+    (4, False, 4, True),    # no chip is assigned to a worker either
+    (1, False, 1, False),   # one worker owns the chip
+    (2, True, 1, False),    # --cpu keeps the workers off the chip
+    (2, False, 0, False),   # no chip on this host
+])
+def test_local_launcher_refuses_workers_sharing_a_chip(
+        monkeypatch, workers, cpu, chips, refused):
+    import argparse
+    import sys
+
+    launch = _load_launcher()
+    monkeypatch.setattr(launch, "_local_tpu_chips", lambda: chips)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    args = argparse.Namespace(
+        num_workers=workers, cpu=cpu, env=[], max_restarts=0,
+        command=[sys.executable, "-c", "pass"])
+    if refused:
+        with pytest.raises(SystemExit, match="device lock"):
+            launch._launch_local(args)
+        return
+    procs = launch._launch_local(args)
+    assert len(procs) == workers
+    assert [p.wait() for p in procs] == [0] * workers

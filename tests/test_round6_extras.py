@@ -8,6 +8,8 @@
   only, matching the reference).
 * config registry carries the round's perf knobs.
 """
+import os
+
 import numpy as onp
 
 import jax.numpy as jnp
@@ -182,17 +184,36 @@ def test_round6_env_knobs_registered():
     assert config.get_env("MXNET_CONV_1X1_DOT") is False
 
 
-def test_setup_compilation_cache(tmp_path, monkeypatch):
-    from mxnet_tpu import config
-
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
-                       str(tmp_path / "cc"))
-    # force re-activation even if an earlier test set the same dir
-    config._CC_STATE["dir"] = None
-    assert config.setup_compilation_cache() == str(tmp_path / "cc")
+@pytest.mark.parametrize("placed_from_outside", [True, False])
+def test_setup_compilation_cache(tmp_path, monkeypatch,
+                                 placed_from_outside):
+    """One rule: JAX_COMPILATION_CACHE_DIR if set, and no other;
+    else the fixed git-ignored <checkout>/.cache/xla — never a
+    temporary, per-pid or per-run name."""
     import jax
 
-    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "cc")
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    from mxnet_tpu import autotune, config
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.delenv("MXNET_AUTOTUNE_CACHE_DIR", raising=False)
+    if placed_from_outside:
+        want = str(tmp_path / "cc")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    else:
+        want = os.path.join(repo, ".cache", "xla")
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    # force re-activation even if an earlier test set the same dir
     config._CC_STATE["dir"] = None
-    assert config.setup_compilation_cache() is None
+    try:
+        assert config.compilation_cache_dir() == want
+        assert config.setup_compilation_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert os.path.isdir(want)
+        # autotune.json decides which program is compiled: same place
+        assert autotune.cache_path() == os.path.join(want,
+                                                     "autotune.json")
+    finally:
+        # back to the suite's own cache for the tests that follow
+        monkeypatch.undo()
+        config._CC_STATE["dir"] = None
+        config.setup_compilation_cache()

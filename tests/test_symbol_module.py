@@ -79,15 +79,24 @@ def test_symbol_json_roundtrip(tmp_path):
 
 
 def test_legacy_json_upgrade():
-    """Load the reference's checked-in v0.8-era JSON fixture (param-style
-    schema, legacy_json_util.cc upgrade path)."""
-    with open("/root/reference/tests/python/unittest/save_000800.json") as f:
+    """Load a v0.8-era JSON graph (param-style schema with
+    ``backward_source_id``, two-element inputs and a BatchNorm that
+    lists no moving-stat inputs: the legacy_json_util.cc upgrade path)
+    from the fixture committed beside the tests."""
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "data", "legacy_v0_8_mlp.json")
+    with open(path) as f:
         legacy = mx.sym.load_json(f.read())
     args = legacy.list_arguments()
     assert args[0] == "data"
     assert "fc1_weight" in args
+    # the upgrade grew the aux inputs the old schema left out
+    assert legacy.list_auxiliary_states() == ["bn1_moving_mean",
+                                              "bn1_moving_var"]
     a, o, _ = legacy.infer_shape(data=(4, 100))
-    assert o is not None
+    assert o == [(4, 10)]
 
 
 def test_batchnorm_symbol_aux():

@@ -218,8 +218,11 @@ def _collective_counts(**kw):
 def test_collective_structure_on_8dev_mesh():
     rep = _collective_counts()
     shd = _collective_counts(optimizer_sharding="ps", bucket_bound=300)
-    # replicated: one all-reduce per gradient tensor (6 params + loss)
-    assert rep["counts"]["all-reduce"] >= 6
+    # replicated: every gradient tensor is all-reduced (6 params +
+    # loss).  XLA combines them into one tuple-shaped launch, so the
+    # tensors are counted, and the launches only bounded
+    assert rep["tensors"]["all-reduce"] >= 6
+    assert 1 <= rep["counts"]["all-reduce"] <= rep["tensors"]["all-reduce"]
     assert rep["counts"]["reduce-scatter"] == 0
     # sharded: exactly one reduce-scatter + one all-gather per bucket
     # (3 at bound=300 for this MLP) and only the loss pmean all-reduce
@@ -232,6 +235,59 @@ def test_collective_structure_on_8dev_mesh():
     assert shd["bytes"]["reduce-scatter"] > 0
     one = _collective_counts(optimizer_sharding="ps")  # default bound:
     assert one["counts"]["reduce-scatter"] == 1        # one flat bucket
+
+
+# the HLO text today's XLA prints: neighbouring collectives combined into
+# one tuple-shaped op whose shape carries /*index=N*/ comments (CPU and
+# TPU), TPU tiling annotations, async -start/-done pairs, and references
+# to a collective from other instructions
+_HLO_LINES = {
+    "cpu_combined_all_reduce": (
+        "  %all-reduce.1 = (f32[], f32[32]{0}, f32[4,8]{1,0}, f32[16]{0}, "
+        "f32[16,8]{1,0}, /*index=5*/f32[4]{0}, f32[4,16]{1,0}) "
+        "all-reduce(%a, %b, %c, %d, %e, %f, %g), channel_id=1, "
+        "replica_groups={{0,1,2,3,4,5,6,7}}, to_apply=%region_0.1",
+        ("all-reduce", 1, 7, 4 * (1 + 32 + 32 + 16 + 128 + 4 + 64))),
+    "tpu_tiled_tuple_all_reduce": (
+        "  %all-reduce.34 = (f32[]{:T(128)}, f32[957888]{0:T(1024)S(1)}, "
+        "f32[1000]{0:T(1024)}, f32[2048000]{0:T(1024)}, "
+        "f32[629248]{0:T(1024)}, /*index=5*/f32[529408]{0:T(1024)}) "
+        "all-reduce(%x0, %x1, %x2, %x3, %x4, %x5), channel_id=2, "
+        "replica_groups={{0,1,2,3}}, use_global_device_ids=true",
+        ("all-reduce", 1, 6,
+         4 * (1 + 957888 + 1000 + 2048000 + 629248 + 529408))),
+    "tpu_all_gather": (
+        "  %all-gather.141 = f32[8192]{0:T(1024)S(1)} all-gather("
+        "%get-tuple-element.1767), channel_id=1, replica_groups="
+        "{{0,1,2,3}}, dimensions={0}, frontend_attributes={"
+        "async_collective_name=\"all-gather-start.3\"}",
+        ("all-gather", 1, 1, 4 * 8192)),
+    "async_all_gather_start": (
+        "  %all-gather-start.2 = (bf16[128]{0}, bf16[1024]{0}) "
+        "all-gather-start(%p), channel_id=3, dimensions={0}",
+        ("all-gather", 1, 1, 2 * 1024)),
+    "async_done_is_not_a_launch": (
+        "  %all-gather-done.2 = bf16[1024]{0} all-gather-done("
+        "%all-gather-start.2)", None),
+    "reference_is_not_a_launch": (
+        "  %get-tuple-element.9 = f32[2048]{0:T(1024)S(1)} "
+        "get-tuple-element(%all-reduce.34), index=21", None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_HLO_LINES))
+def test_collective_bytes_reads_todays_hlo_text(case):
+    line, want = _HLO_LINES[case]
+    got = zero.collective_bytes("HloModule m\n" + line + "\n")
+    if want is None:
+        assert sum(got["counts"].values()) == 0
+        assert got["total_bytes"] == 0
+        return
+    kind, launches, tensors, nbytes = want
+    assert got["counts"][kind] == launches
+    assert got["tensors"][kind] == tensors
+    assert got["bytes"][kind] == nbytes
+    assert got["total_bytes"] == nbytes
 
 
 def test_dp16_resnet_collective_budget_acceptance():

