@@ -1,0 +1,87 @@
+"""Operations and least bytes of one training step, from a configuration's
+layer table (``layers`` in ``chipbench/configs/<name>.json``).
+
+A row is one convolution or dense layer (a dense layer is a 1x1
+convolution on a 1x1 image): kernel, stride, groups, channels in and out,
+square input and output sides, how many times it occurs, and whether the
+backward pass needs the gradient of its input (the first layer's does
+not).  A training step runs three products per layer: the forward one,
+the gradient of the input and the gradient of the weights; nothing is
+counted twice for recomputation, and the elementwise work of batch
+normalisation, activations, loss and optimizer is not counted at all:
+these are the operations the model requires of a matrix unit.
+
+The least bytes are counted per layer and pass, each array once: the
+forward pass reads the input and the weights and writes the output; the
+backward pass reads the input, the output's gradient and the weights and
+writes the weights' gradient and, where it is needed, the input's, in the
+compute type.  (Counted per product, the output's gradient would be read
+twice, and a kernel that makes both gradients in one pass would read over
+100%.)  The roofline time of a pass is the larger of operations / peak
+operations per second and bytes / peak bytes per second; a layer's is the
+sum over its two passes.
+"""
+import json
+import os
+
+_HERE = os.path.dirname(os.path.abspath(__file__))
+_DTYPE_BYTES = {"bfloat16": 2, "float16": 2, "float32": 4}
+
+
+def peaks(device_kind):
+    """The published peaks of ``device_kind``; a device that is not in
+    ``peaks.json`` is an error."""
+    with open(os.path.join(_HERE, "peaks.json")) as f:
+        table = json.load(f)
+    if device_kind not in table or device_kind.startswith("_"):
+        raise KeyError(f"no peaks for device_kind {device_kind!r} in "
+                       "chipbench/peaks.json")
+    return table[device_kind]
+
+
+def _rows(config):
+    cols = config["layer_columns"]
+    return [dict(zip(cols, row)) for row in config["layers"]]
+
+
+def passes(config, batch):
+    """[(layer, pass, count, flops, bytes)] of one step at ``batch`` rows:
+    ``flops`` and ``bytes`` are of ONE occurrence of the layer."""
+    width = _DTYPE_BYTES[config["compute_dtype"]]
+    out = []
+    for r in _rows(config):
+        macs = (batch * r["h_out"] ** 2 * r["cout"]
+                * (r["cin"] // r["groups"]) * r["kernel"] ** 2)
+        x = batch * r["h_in"] ** 2 * r["cin"] * width
+        y = batch * r["h_out"] ** 2 * r["cout"] * width
+        w = r["cout"] * (r["cin"] // r["groups"]) * r["kernel"] ** 2 * width
+        grads = 2 if r["needs_input_grad"] else 1
+        out.append((r["name"], "forward", r["count"], 2 * macs, x + w + y))
+        out.append((r["name"], "backward", r["count"], 2 * macs * grads,
+                    x + y + w + w + (x if grads == 2 else 0)))
+    return out
+
+
+def forward_macs_per_image(config):
+    return sum(c * f // 2 for _, p, c, f, _ in passes(config, 1)
+               if p == "forward")
+
+
+def step_flops(config, batch):
+    """Model FLOPs of one training step (forward and backward of every
+    convolution and dense layer)."""
+    return sum(c * f for _, _, c, f, _ in passes(config, batch))
+
+
+def step_roofline_s(config, batch, peak):
+    """``(seconds, seconds bound by operations, seconds bound by bytes)``:
+    the least time the chip could take for the step's passes."""
+    by_flops = by_bytes = 0.0
+    for _, _, c, f, b in passes(config, batch):
+        tf = f / peak["bf16_flops_per_s"]
+        tb = b / peak["hbm_bytes_per_s"]
+        if tf >= tb:
+            by_flops += c * tf
+        else:
+            by_bytes += c * tb
+    return by_flops + by_bytes, by_flops, by_bytes
