@@ -556,6 +556,13 @@ def setup_compilation_cache():
     jax.config.update("jax_compilation_cache_dir", p)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # the scopes a program names its operations by (mx_forward, a
+    # block's name, ...) are metadata, which jax leaves out of the
+    # cache key unless told: an executable cached before a scope was
+    # added or renamed would be loaded with its old names, and every
+    # trace of it would show those
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      True)
     _CC_STATE["dir"] = p
     return p
 

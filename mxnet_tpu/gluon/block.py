@@ -293,6 +293,18 @@ class Block:
 
     # ------------------------------------------------------------- forward
     def __call__(self, *args):
+        # while a program is being traced (the first argument holds a
+        # tracer) the block opens a scope with its own name, so that
+        # the compiled step's operations, and their backward, carry it
+        # (profiler.dumps() reads it back); an eager call pays this
+        # one check
+        if args and self.name and isinstance(
+                getattr(args[0], "_data", None), jax.core.Tracer):
+            with jax.named_scope(self.name):
+                return self._call(*args)
+        return self._call(*args)
+
+    def _call(self, *args):
         if _suppress_hooks.flag:
             return self.forward(*args)
         for hook in self._forward_pre_hooks.values():

@@ -19,12 +19,14 @@ iterators (lists of NDArrays), or plain generators of numpy arrays.
 """
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 import time
 
 from .. import ndarray as nd
 from ..base import MXNetError
+from ..telemetry import tracing
 from .io import DataBatch, DataIter
 
 __all__ = ["DeviceFeedIter", "as_device_batch", "batch_nbytes",
@@ -48,40 +50,87 @@ def _q_put(q, stop, item):
     return False
 
 
-def _produce(base, q, stop, stats, sharding, device, n_shards):
+def _watch(ready, stats):
+    """Stamp when each batch is on the device, off the producing path:
+    ``device_put`` returns before the layout change and the copy are
+    done, and a producer that waited for them itself would keep the
+    copy from overlapping its next ``next(source)``.  ``ready`` gives
+    ``(when the host batch was in hand, its device arrays)`` in the
+    producer's order and ``None`` at its end; busy is the union of
+    those intervals, since the next copy may start before this one
+    ends."""
+    last = 0.0
+    for t1, arrays in iter(ready.get, None):
+        for a in arrays:
+            try:
+                a.block_until_ready()
+            except RuntimeError:
+                pass  # consumed and donated already: it was there
+        del arrays  # hold no batch while waiting for the next
+        now = time.perf_counter()
+        stats["producer_busy_s"] += now - max(t1, last)
+        last = now
+
+
+def _produce(base, q, stop, stats, sharding, device, n_shards, ctx):
     """Producer loop (module-level on purpose: it must not hold a
     reference to the DeviceFeedIter, or an abandoned iterator could
-    never be garbage-collected and its finalizer never fire)."""
+    never be garbage-collected and its finalizer never fire).  ``ctx``
+    is the trace context of the thread that started the feed: the
+    producer's spans parent onto it."""
     from ..resilience import faultsim
     from ..resilience.retry import retry_call
 
-    try:
-        src = iter(base)
-        while not stop.is_set():
-            try:
-                item = next(src)
-            except StopIteration:
-                _q_put(q, stop, _END)
-                return
-            t0 = time.perf_counter()
+    ready = queue.SimpleQueue()
+    threading.Thread(target=_watch, args=(ready, stats),
+                     name="DeviceFeedIter-ready", daemon=True).start()
+    bound = tracing.use(ctx) if ctx is not None \
+        else contextlib.nullcontext()
+    with bound:
+        try:
+            src = iter(base)
+            # the how-manieth batch, in the consumer's count: a RunLog
+            # copies the spans of its sampled ones
+            n = stats["batches"]
+            while not stop.is_set():
+                t0 = time.perf_counter()
+                with tracing.region("mx_feed_source", nth=n):
+                    item = next(src, _END)
+                if item is _END:
+                    _q_put(q, stop, _END)
+                    return
+                t1 = time.perf_counter()
+                stats["source_wait_s"] += t1 - t0
+                attempts = [0]
 
-            def put_batch(it=item):
-                # feed.h2d: the injection point for transfer faults;
-                # transient failures (injected or OS-level) retry with
-                # bounded backoff instead of killing the epoch
-                faultsim.inject("feed.h2d")
-                return as_device_batch(it, sharding, device, n_shards)
+                def put_batch(it=item):
+                    # feed.h2d: the injection point for transfer faults;
+                    # transient failures (injected or OS-level) retry with
+                    # bounded backoff instead of killing the epoch
+                    attempts[0] += 1
+                    faultsim.inject("feed.h2d")
+                    return as_device_batch(it, sharding, device, n_shards)
 
-            out = retry_call(
-                put_batch,
-                retry_on=(faultsim.FaultInjected, OSError),
-                attempts=3, base_delay=0.02, max_delay=0.5)
-            stats["producer_busy_s"] += time.perf_counter() - t0
-            stats["h2d_bytes"] += batch_nbytes(out)
-            if not _q_put(q, stop, out):
-                return
-    except BaseException as e:  # noqa: BLE001 — surfaced on next()
-        _q_put(q, stop, _Err(e))
+                with tracing.region("mx_feed_h2d", nth=n) as r:
+                    out = retry_call(
+                        put_batch,
+                        retry_on=(faultsim.FaultInjected, OSError),
+                        attempts=3, base_delay=0.02, max_delay=0.5)
+                    nbytes = batch_nbytes(out)
+                    meta = {"bytes": nbytes}
+                    if attempts[0] > 1:
+                        meta["attempt"] = attempts[0]
+                    r.set_metadata(**meta)
+                ready.put((t1, [a for a in _arrays(out)
+                                if hasattr(a, "block_until_ready")]))
+                stats["h2d_bytes"] += nbytes
+                n += 1
+                if not _q_put(q, stop, out):
+                    return
+        except BaseException as e:  # noqa: BLE001 — surfaced on next()
+            _q_put(q, stop, _Err(e))
+        finally:
+            ready.put(None)
 
 
 def device_feed_enabled():
@@ -141,19 +190,26 @@ def as_device_batch(item, sharding=None, device=None, n_shards=1):
     return item
 
 
+def _arrays(item):
+    """The arrays of a batch, unwrapped (an NDArray's ``_data``)."""
+    if item is None:
+        return
+    if isinstance(item, DataBatch):
+        yield from _arrays(item.data)
+        yield from _arrays(item.label)
+    elif isinstance(item, (list, tuple)):
+        for x in item:
+            yield from _arrays(x)
+    else:
+        yield item._data if isinstance(item, nd.NDArray) else item
+
+
 def batch_nbytes(item):
     """Total array bytes in a (device) batch — the per-batch H2D
     transfer volume ``stats()['h2d_bytes']`` accumulates and telemetry
     step records report as deltas."""
-    if item is None:
-        return 0
-    if isinstance(item, DataBatch):
-        return batch_nbytes(item.data) + batch_nbytes(item.label)
-    if isinstance(item, (list, tuple)):
-        return sum(batch_nbytes(x) for x in item)
-    data = item._data if isinstance(item, nd.NDArray) else item
-    nbytes = getattr(data, "nbytes", None)
-    return int(nbytes) if nbytes is not None else 0
+    return sum(int(getattr(a, "nbytes", None) or 0)
+               for a in _arrays(item))
 
 
 class DeviceFeedIter(DataIter):
@@ -163,9 +219,14 @@ class DeviceFeedIter(DataIter):
 
     ``reset()`` restarts the producer and resets the wrapped source, so
     the wrapper drops into ``Module.fit``'s epoch loop in place of the
-    raw iterator.  ``stats()`` reports how long the consumer actually
-    waited vs how long the producer spent assembling+transferring — the
-    feed/compute overlap evidence bench.py puts in its JSON.
+    raw iterator.  ``stats()`` reports, all as plain numbers that only
+    grow: ``consumer_wait_s`` (``next()`` waiting for a batch),
+    ``source_wait_s`` (the producer inside ``next(source)``),
+    ``producer_busy_s`` (from the host batch in hand to the batch on
+    the device, stamped by a watching thread a moment after it is
+    there), ``depth_sum`` (batches queued at each ``next()``: mean
+    depth is ``depth_sum / batches``), ``h2d_bytes``, ``batches``,
+    ``epochs``.
     """
 
     def __init__(self, base, depth=None, mesh=None, data_axis="data",
@@ -181,7 +242,8 @@ class DeviceFeedIter(DataIter):
         self._device = device
         self._stats = {"batches": 0, "epochs": 0,
                        "consumer_wait_s": 0.0, "producer_busy_s": 0.0,
-                       "h2d_bytes": 0}
+                       "h2d_bytes": 0, "source_wait_s": 0.0,
+                       "depth_sum": 0}
         self._thread = None
         self._done = False
         self._closed = False
@@ -201,7 +263,8 @@ class DeviceFeedIter(DataIter):
         self._thread = threading.Thread(
             target=_produce,
             args=(self._base, self._q, self._stop, self._stats,
-                  self._sharding, self._device, self._n_shards),
+                  self._sharding, self._device, self._n_shards,
+                  tracing.current_context()),
             name="DeviceFeedIter", daemon=True)
         self._finalizer = weakref.finalize(self, self._stop.set)
         self._thread.start()
@@ -253,15 +316,18 @@ class DeviceFeedIter(DataIter):
         if self._done:  # exhausted: don't block on a dead producer
             raise StopIteration
         t0 = time.perf_counter()
-        while True:
-            try:
-                item = self._q.get(timeout=0.5)
-                break
-            except queue.Empty:
-                if not self._thread.is_alive():
-                    raise MXNetError(
-                        "DeviceFeedIter: producer thread died without "
-                        "a sentinel")
+        depth = self._q.qsize()
+        with tracing.region("mx_feed_wait", nth=self._stats["batches"],
+                            depth=depth):
+            while True:
+                try:
+                    item = self._q.get(timeout=0.5)
+                    break
+                except queue.Empty:
+                    if not self._thread.is_alive():
+                        raise MXNetError(
+                            "DeviceFeedIter: producer thread died "
+                            "without a sentinel")
         self._stats["consumer_wait_s"] += time.perf_counter() - t0
         if item is _END:
             self._done = True
@@ -270,6 +336,7 @@ class DeviceFeedIter(DataIter):
             self._done = True
             raise item.exc
         self._stats["batches"] += 1
+        self._stats["depth_sum"] += depth
         return item
 
     def reset(self):

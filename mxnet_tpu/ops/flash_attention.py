@@ -172,6 +172,7 @@ def _flash_forward_pallas(q, k, v, causal, sm_scale, block_q=_BLOCK_Q,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b_, i: (b_, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q3, k3, v3)
     return out.reshape(b, h, sq, d)
 

@@ -191,6 +191,8 @@ def _bwd_pass1_pallas(dy, u, w2, g, b, mu, inv, interpret=None):
                         pltpu.VMEM((1, Ci), jnp.float32),
                         pltpu.VMEM((1, Ci), jnp.float32)],
         interpret=interpret,
+        # the name a trace and chipbench/conv_kernels.json know it by
+        name="bnreluconv_bwd",
     )(dy, u, w2, g, b, mu, inv)
 
 

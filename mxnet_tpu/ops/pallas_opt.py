@@ -262,6 +262,7 @@ def _elementwise_call(kernel, n_in, n_out, operands, out_dtypes,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="bucket_opt_update",
     )(*scalars, *v2ds)
     n = operands[0].shape[0]
     nf = None
@@ -421,12 +422,14 @@ def _lars_bucket(opt, w, g, state, seg, axis_name, rescale, clip,
         scratch_shapes=[pltpu.VMEM((1, segp), jnp.float32),
                         pltpu.VMEM((1, segp), jnp.float32)],
         interpret=interpret,
+        name="lars_norms",
     )(v2w, v2g, v2s)
     w_ss = wss.reshape(-1)[:int(nseg)]
     g_ss = gss.reshape(-1)[:int(nseg)]
     if axis_name is not None:
-        w_ss = jax.lax.psum(w_ss, axis_name)
-        g_ss = jax.lax.psum(g_ss, axis_name)
+        with jax.named_scope("mx_exchange"):
+            w_ss = jax.lax.psum(w_ss, axis_name)
+            g_ss = jax.lax.psum(g_ss, axis_name)
     # _lars_bucket_step's trust math, on the nseg-length vectors
     w_norm = jnp.sqrt(w_ss)
     g_norm = jnp.sqrt(g_ss)
@@ -448,6 +451,7 @@ def _lars_bucket(opt, w, g, state, seg, axis_name, rescale, clip,
         out_shape=[jax.ShapeDtypeStruct((rows, width), w.dtype),
                    jax.ShapeDtypeStruct((rows, width), mom.dtype)],
         interpret=interpret,
+        name="lars_update",
     )(v2w, v2g, _view2d(mom), v2s, slr)
     n = w.shape[0]
     nf = None

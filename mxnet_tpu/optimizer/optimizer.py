@@ -915,8 +915,9 @@ def _lars_bucket_step(w, mom, g, seg_ids, lr, wd, momentum, eta, eps,
     g_ss = jax.ops.segment_sum(g * g, seg_ids,
                                num_segments=num_segments)
     if axis_name is not None:
-        w_ss = jax.lax.psum(w_ss, axis_name)
-        g_ss = jax.lax.psum(g_ss, axis_name)
+        with jax.named_scope("mx_exchange"):
+            w_ss = jax.lax.psum(w_ss, axis_name)
+            g_ss = jax.lax.psum(g_ss, axis_name)
     w_norm = jnp.sqrt(w_ss)
     g_norm = jnp.sqrt(g_ss)
     trust = jnp.where((w_norm > 0) & (g_norm > 0),

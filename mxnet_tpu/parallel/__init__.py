@@ -357,15 +357,21 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
                 for n, v in param_dict.items()}
             if jnp.issubdtype(x.dtype, jnp.floating):
                 x = _po.fp8_qdq(x, fp8["x"][0], gscale)
-        if cdt is not None:
-            # AMP policy (reference contrib/amp list semantics): matmul/
-            # conv weights in bf16, norm affine+stats in fp32
-            param_dict = amp_cast_params(param_dict, cdt)
-            x = x.astype(cdt)
-        out = apply_fn(param_dict, x, key=key)
-        loss_nd = loss_fn(nd.NDArray(out.astype(jnp.float32)),
-                          nd.NDArray(y))
-        return jnp.mean(loss_nd._data)
+        # the scopes are metadata on the traced operations and nothing
+        # else: backward reads transpose(jvp(mx_forward)) without
+        # further code, and every gluon block names itself inside
+        # (gluon.Block.__call__)
+        with jax.named_scope("mx_forward"):
+            if cdt is not None:
+                # AMP policy (reference contrib/amp list semantics):
+                # matmul/conv weights in bf16, norm affine+stats in fp32
+                param_dict = amp_cast_params(param_dict, cdt)
+                x = x.astype(cdt)
+            out = apply_fn(param_dict, x, key=key)
+        with jax.named_scope("mx_loss"):
+            loss_nd = loss_fn(nd.NDArray(out.astype(jnp.float32)),
+                              nd.NDArray(y))
+            return jnp.mean(loss_nd._data)
 
     dynamic_scaling = loss_scale == "dynamic"
     static_scale = float(loss_scale) if (
@@ -562,6 +568,7 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
     # call this ONE copy (sharded-vs-replicated parity contract)
     _scale_bookkeeping = _po.scale_bookkeeping
 
+    @jax.named_scope("mx_optimizer")
     def _apply_updates(params_, opt_state_, grads, t, key):
         new_p, new_s = {}, {}
         for i, n in enumerate(names):
@@ -571,6 +578,27 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
             sub = jax.random.fold_in(key, i) if opt.needs_key else None
             new_p[n], new_s[n] = opt.fused_update(
                 params_[n], grads[n], opt_state_[n], t, key=sub)
+        return new_p, new_s
+
+    @jax.named_scope("mx_guard")
+    def _all_finite(grads, loss=None):
+        finite = jnp.array(True) if loss is None else jnp.isfinite(loss)
+        for g in jax.tree_util.tree_leaves(grads):
+            finite = finite & jnp.isfinite(g).all()
+        return finite
+
+    @jax.named_scope("mx_guard")
+    def _keep_if_finite(finite, up_p, up_s, params_, opt_state_):
+        """Skip-the-update selection: a non-finite step leaves every
+        param and state leaf as it came."""
+        new_p = {n: jnp.where(finite, up_p[n], params_[n])
+                 for n in names}
+        new_s = {
+            n: jax.tree_util.tree_map(
+                lambda u, o: jnp.where(finite, u, o),
+                up_s[n], opt_state_[n])
+            for n in names
+        }
         return new_p, new_s
 
     def step(params_, opt_state_, x, y, key, t):
@@ -597,30 +625,27 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
             scale, good = opt_state_["_loss_scale"]
 
             def scaled_loss(p, x_, y_, k_):
-                return lo(p, x_, y_, k_) * scale
+                lv = lo(p, x_, y_, k_)
+                with jax.named_scope("mx_guard"):
+                    return lv * scale
 
             sloss, sgrads = jax.value_and_grad(scaled_loss)(
                 params_, x, y, key)
-            inv = 1.0 / scale
-            grads = jax.tree_util.tree_map(lambda g: g * inv, sgrads)
-            finite = jnp.array(True)
-            for g in jax.tree_util.tree_leaves(grads):
-                finite = finite & jnp.isfinite(g).all()
+            with jax.named_scope("mx_guard"):
+                inv = 1.0 / scale
+                grads = jax.tree_util.tree_map(lambda g: g * inv,
+                                               sgrads)
+            finite = _all_finite(grads)
             up_p, up_s = _apply_updates(
                 {n: params_[n] for n in names},
                 {n: opt_state_[n] for n in names}, grads, t, key)
             # overflow: skip the update, halve the scale; after 2000
             # consecutive finite steps, double it (reference amp scaler)
-            new_p = {n: jnp.where(finite, up_p[n], params_[n])
-                     for n in names}
-            new_s = {
-                n: jax.tree_util.tree_map(
-                    lambda u, o: jnp.where(finite, u, o),
-                    up_s[n], opt_state_[n])
-                for n in names
-            }
-            new_s["_loss_scale"] = _scale_bookkeeping(finite, scale,
-                                                      good)
+            new_p, new_s = _keep_if_finite(finite, up_p, up_s, params_,
+                                           opt_state_)
+            with jax.named_scope("mx_guard"):
+                new_s["_loss_scale"] = _scale_bookkeeping(finite, scale,
+                                                          good)
             # the fp8 histories update even on a skipped step — the
             # overflow observation is exactly what backs the scale off
             new_s = _fp8_carry(new_s, grads)
@@ -629,17 +654,21 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
             # unscale with the scale the loss was COMPUTED with, not the
             # adjusted one, or the reported loss jumps 2x on every
             # scale-change step
-            return sloss / scale, new_p, new_s
+            with jax.named_scope("mx_guard"):
+                return sloss / scale, new_p, new_s
 
         if static_scale != 1.0:
             def scaled_loss(p, x_, y_, k_):
-                return lo(p, x_, y_, k_) * static_scale
+                lv = lo(p, x_, y_, k_)
+                with jax.named_scope("mx_guard"):
+                    return lv * static_scale
 
             loss, grads = jax.value_and_grad(scaled_loss)(params_, x, y,
                                                           key)
-            loss = loss / static_scale
-            grads = jax.tree_util.tree_map(
-                lambda g: g / static_scale, grads)
+            with jax.named_scope("mx_guard"):
+                loss = loss / static_scale
+                grads = jax.tree_util.tree_map(
+                    lambda g: g / static_scale, grads)
         else:
             loss, grads = jax.value_and_grad(lo)(params_, x, y, key)
         if nan_guard:
@@ -647,22 +676,15 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
             # untouched and bumps the consecutive-bad counter; any
             # finite step resets it (MXNET_BAD_STEP_LIMIT policy is
             # enforced by the host reading _bad_steps)
-            finite = jnp.isfinite(loss)
-            for g in jax.tree_util.tree_leaves(grads):
-                finite = finite & jnp.isfinite(g).all()
+            finite = _all_finite(grads, loss)
             up_p, up_s = _apply_updates(
                 params_, {n: opt_state_[n] for n in names}, grads, t,
                 key)
-            new_p = {n: jnp.where(finite, up_p[n], params_[n])
-                     for n in names}
-            new_s = {
-                n: jax.tree_util.tree_map(
-                    lambda u, o: jnp.where(finite, u, o),
-                    up_s[n], opt_state_[n])
-                for n in names
-            }
-            new_s["_bad_steps"] = jnp.where(
-                finite, jnp.int32(0), opt_state_["_bad_steps"] + 1)
+            new_p, new_s = _keep_if_finite(finite, up_p, up_s, params_,
+                                           opt_state_)
+            with jax.named_scope("mx_guard"):
+                new_s["_bad_steps"] = jnp.where(
+                    finite, jnp.int32(0), opt_state_["_bad_steps"] + 1)
             new_s = _fp8_carry(new_s, grads)
             if numerics_on:
                 # stats of the step AS IT HAPPENED, guard or no guard:
@@ -711,14 +733,16 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
                     # executes instead of serializing all gathers at
                     # the step head
                     named = {}
-                    for bk_, b_ in zip(bucket_keys, plan):
-                        named.update(_zero.unflatten_bucket(
-                            b_, jax.lax.all_gather(
-                                p[bk_], data_axis, tiled=True)))
+                    with jax.named_scope("mx_exchange"):
+                        for bk_, b_ in zip(bucket_keys, plan):
+                            named.update(_zero.unflatten_bucket(
+                                b_, jax.lax.all_gather(
+                                    p[bk_], data_axis, tiled=True)))
                     p = named
                 lv = loss_of(p, x_, y_, k_)
                 if dynamic_scaling or static_scale != 1.0:
-                    lv = lv * scale
+                    with jax.named_scope("mx_guard"):
+                        lv = lv * scale
                 return lv
 
             lval, lgrads = jax.value_and_grad(local_loss)(
@@ -736,7 +760,8 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
             # guard additionally checks the loss, as replicated does
             finite = None
             if nan_guard:
-                finite = jnp.isfinite(lval)
+                with jax.named_scope("mx_guard"):
+                    finite = jnp.isfinite(lval)
             elif dynamic_scaling:
                 finite = jnp.array(True)
             staged = []
@@ -754,17 +779,21 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
                     # classic ZeRO-1 for the stage ladder: the whole
                     # reduced bucket lands on every device (one
                     # all-reduce) and the owned shard is sliced off it
-                    g_sh = _zero.shard_slice(
-                        jax.lax.psum(_zero.flatten_bucket(b, lgrads),
-                                     data_axis), n_sh, idx)
+                    with jax.named_scope("mx_exchange"):
+                        g_sh = _zero.shard_slice(
+                            jax.lax.psum(
+                                _zero.flatten_bucket(b, lgrads),
+                                data_axis), n_sh, idx)
                 else:
                     # THE stage-2 exchange: one reduce-scatter for the
                     # whole bucket replaces len(b.names) per-tensor
                     # all-reduces
-                    g_sh = jax.lax.psum_scatter(
-                        _zero.flatten_bucket(b, lgrads), data_axis,
-                        scatter_dimension=0, tiled=True)
-                g32 = g_sh.astype(jnp.float32) * inv
+                    with jax.named_scope("mx_exchange"):
+                        g_sh = jax.lax.psum_scatter(
+                            _zero.flatten_bucket(b, lgrads), data_axis,
+                            scatter_dimension=0, tiled=True)
+                with jax.named_scope("mx_exchange"):
+                    g32 = g_sh.astype(jnp.float32) * inv
                 new_resid = None
                 if comp_threshold is not None:
                     from ..kvstore import quantize_2bit
@@ -774,9 +803,12 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
                     # (the kernel's fused verdict would see the
                     # quantized values)
                     if check_finite:
-                        finite = finite & jnp.isfinite(g32).all()
-                    acc = g32 + opt_state_[f"_residual{i}"]
-                    g32, new_resid = quantize_2bit(acc, comp_threshold)
+                        with jax.named_scope("mx_guard"):
+                            finite = finite & jnp.isfinite(g32).all()
+                    with jax.named_scope("mx_exchange"):
+                        acc = g32 + opt_state_[f"_residual{i}"]
+                        g32, new_resid = quantize_2bit(acc,
+                                                       comp_threshold)
                 sub = jax.random.fold_in(
                     jax.random.fold_in(key, i), idx) \
                     if opt.needs_key else None
@@ -798,29 +830,33 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
                     # device sees params/N elements; psum below makes
                     # the verdict global) — fused when the kernel ran,
                     # bit-identical jnp check otherwise
-                    finite = finite & (
-                        bfin if bfin is not None
-                        else jnp.isfinite(g32).all())
+                    with jax.named_scope("mx_guard"):
+                        finite = finite & (
+                            bfin if bfin is not None
+                            else jnp.isfinite(g32).all())
                 else:
                     w_sh, uw, us = res
                 staged.append((i, bk, b, w_sh, uw, us, new_resid))
             new_p, new_s = {}, {}
             if check_finite:
-                bad = jax.lax.psum(1 - finite.astype(jnp.int32),
-                                   data_axis)
-                finite = bad == 0
+                with jax.named_scope("mx_guard"), \
+                        jax.named_scope("mx_exchange"):
+                    bad = jax.lax.psum(1 - finite.astype(jnp.int32),
+                                       data_axis)
+                    finite = bad == 0
             for i, bk, b, w_sh, uw, us, new_resid in staged:
                 if check_finite:
                     # skip-the-update selection (dynamic scaling / nan
                     # guard): shard, state AND residual all hold
-                    uw = jnp.where(finite, uw, w_sh)
-                    us = jax.tree_util.tree_map(
-                        lambda u, o: jnp.where(finite, u, o), us,
-                        opt_state_[bk])
-                    if new_resid is not None:
-                        new_resid = jnp.where(
-                            finite, new_resid,
-                            opt_state_[f"_residual{i}"])
+                    with jax.named_scope("mx_guard"):
+                        uw = jnp.where(finite, uw, w_sh)
+                        us = jax.tree_util.tree_map(
+                            lambda u, o: jnp.where(finite, u, o), us,
+                            opt_state_[bk])
+                        if new_resid is not None:
+                            new_resid = jnp.where(
+                                finite, new_resid,
+                                opt_state_[f"_residual{i}"])
                 new_s[bk] = us
                 if new_resid is not None:
                     new_s[f"_residual{i}"] = new_resid
@@ -831,16 +867,19 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
                     new_p[bk] = uw
                 else:
                     new_p.update(_zero.gather_bucket(b, uw, data_axis))
-            loss = jax.lax.pmean(lval, data_axis)
-            if dynamic_scaling:
-                new_s["_loss_scale"] = _scale_bookkeeping(finite, scale,
-                                                          good)
-                loss = loss / scale
-            elif static_scale != 1.0:
-                loss = loss / static_scale
-            if nan_guard:
-                new_s["_bad_steps"] = jnp.where(
-                    finite, jnp.int32(0), opt_state_["_bad_steps"] + 1)
+            with jax.named_scope("mx_exchange"):
+                loss = jax.lax.pmean(lval, data_axis)
+            with jax.named_scope("mx_guard"):
+                if dynamic_scaling:
+                    new_s["_loss_scale"] = _scale_bookkeeping(
+                        finite, scale, good)
+                    loss = loss / scale
+                elif static_scale != 1.0:
+                    loss = loss / static_scale
+                if nan_guard:
+                    new_s["_bad_steps"] = jnp.where(
+                        finite, jnp.int32(0),
+                        opt_state_["_bad_steps"] + 1)
             return loss, new_p, new_s
 
         if stage == 3:
@@ -951,6 +990,7 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
     # after a change is a cache "hit" (XLA's jit cache still holds it).
     # MXNET_RUNLOG unset => current() is None => zero per-step work
     # beyond one call + dict lookup.
+    from .. import profiler as _profiler
     from .. import telemetry as _tm
 
     _jitted_step = step_fn
@@ -966,6 +1006,7 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
     _tm_last = [None]
     _nm_period = _nm.sample_period() if numerics_on else 0
     _nm_step = [0]
+    _calls = [0]
 
     def step_fn(p, o, x, y, key, t, _inner=_jitted_step):
         rl = _tm.current()
@@ -996,7 +1037,27 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
                     pass  # telemetry must never kill the step
                 _tm_seen.add(sig)
                 _tm_last[0] = sig
-        result = _inner(p, o, x, y, key, t)
+        if _profiler._jax_trace_active:
+            # under mx.profiler's device trace (one attribute read
+            # outside it): dumps() reads the scopes of the traced
+            # operations from this program's compiled text
+            noted = ("train_step", id(_inner), jnp.shape(x))
+            if noted not in _profiler._programs:
+                # shapes now (the step donates its arrays), the text
+                # when asked
+                args = jax.tree_util.tree_map(
+                    lambda a: jax.ShapeDtypeStruct(
+                        a.shape, a.dtype, sharding=a.sharding)
+                    if isinstance(a, jax.Array) else a,
+                    (p, o, x, y, key, t))
+                _profiler.note_program(
+                    noted,
+                    lambda: _inner.lower(*args).compile().as_text())
+        # the host span that causes this step's device work, on the
+        # profiler's clock (inactive outside a profiler session)
+        with _tm.tracing.region("mx_step", step_num=_calls[0]):
+            result = _inner(p, o, x, y, key, t)
+        _calls[0] += 1
         if numerics_on and rl is not None:
             # sampled readback of the in-graph summaries: the ONLY
             # steps that pay a device sync for the monitor.  Inside an

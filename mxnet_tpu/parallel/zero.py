@@ -39,6 +39,7 @@ from __future__ import annotations
 import dataclasses
 import re
 
+import jax
 import numpy as onp
 
 from ..base import MXNetError
@@ -50,7 +51,7 @@ __all__ = ["Bucket", "plan_buckets", "flatten_bucket", "unflatten_bucket",
            "resolve_bucket_variant", "analytic_exchange_bytes",
            "stage3_param_keys", "shard_stage3_params",
            "gather_stage3_params", "overlap_report",
-           "export_overlap_trace", "ShardedBucketUpdater"]
+           "ShardedBucketUpdater"]
 
 
 # ------------------------------------------------------------ bucket plan
@@ -184,6 +185,7 @@ def shard_slice(flat, n_shards, idx):
     return flat.reshape(n_shards, -1)[idx]
 
 
+@jax.named_scope("mx_optimizer")
 def bucket_shard_update(bucket, opt, params, g_sh, state, t, *, n_shards,
                         idx, axis, seg=None, key=None, pallas=None,
                         want_finite=False, w_sh=None):
@@ -210,7 +212,11 @@ def bucket_shard_update(bucket, opt, params, g_sh, state, t, *, n_shards,
     ``isfinite(g_sh).all()`` of the RAW (pre-dtype-cast) gradient —
     fused into the kernel's pass on the pallas arm, or None on the
     jnp arm (the caller keeps its own jnp check, bit-identical to
-    today's)."""
+    today's).
+
+    Traced under the ``mx_optimizer`` scope (the collectives of a
+    non-elementwise rule, LARS' norms, under ``mx_exchange`` inside
+    it)."""
     import jax.numpy as jnp
 
     if w_sh is None:
@@ -255,12 +261,11 @@ def bucket_shard_update(bucket, opt, params, g_sh, state, t, *, n_shards,
     return w_sh, uw, us
 
 
+@jax.named_scope("mx_exchange")
 def gather_bucket(bucket, w_sh, axis):
     """All-gather an updated shard back to the replicated flat bucket
     and split it per param (tiled, matching :func:`shard_slice`'s
     row-major layout)."""
-    import jax
-
     return unflatten_bucket(
         bucket, jax.lax.all_gather(w_sh, axis, tiled=True))
 
@@ -548,52 +553,6 @@ def overlap_report(hlo_text, plan, n_shards):
     return {"gathers": gathers, "total_instructions": len(lines),
             "overlapped": any(g["compute_between"] > 0
                               for g in gathers[:-1] or gathers)}
-
-
-def export_overlap_trace(report, path, step_ms=1.0, label="zero3"):
-    """Render an :func:`overlap_report` onto the Perfetto timeline
-    (profiler.py trace-event JSON): a ``collectives`` lane carries one
-    span per bucket all-gather and a ``compute`` lane carries the
-    schedule segments that run while each gather is in flight —
-    schedule positions scaled into a ``step_ms`` window, so lane
-    geometry mirrors the compiled schedule even where wall-clock
-    per-instruction timing does not exist (inside one jitted program).
-    Returns the trace dict after writing it to ``path``."""
-    import json
-
-    total = max(1, report["total_instructions"])
-    scale = (step_ms * 1000.0) / total  # us per schedule slot
-    events = [
-        {"name": "process_name", "ph": "M", "pid": 1, "tid": 0,
-         "args": {"name": f"{label} step (schedule-scaled)"}},
-        {"name": "thread_name", "ph": "M", "pid": 1, "tid": 1,
-         "args": {"name": "collectives (bucket all-gather)"}},
-        {"name": "thread_name", "ph": "M", "pid": 1, "tid": 2,
-         "args": {"name": "compute (hides the next gather)"}},
-    ]
-    gathers = report["gathers"]
-    for gi, g in enumerate(gathers):
-        start = g["pos"] * scale
-        end_pos = g["done_pos"] if g["done_pos"] is not None else (
-            gathers[gi + 1]["pos"] if gi + 1 < len(gathers)
-            else total)
-        events.append({
-            "name": f"all_gather:bucket{g['bucket']}", "ph": "X",
-            "cat": "collective", "pid": 1, "tid": 1, "ts": start,
-            "dur": max(scale, (end_pos - g["pos"]) * scale),
-            "args": {"bucket": g["bucket"], "async": g["async"],
-                     "compute_between": g["compute_between"]}})
-        if g["compute_between"]:
-            events.append({
-                "name": f"compute under bucket{g['bucket']} gather",
-                "ph": "X", "cat": "compute", "pid": 1, "tid": 2,
-                "ts": start + scale,
-                "dur": g["compute_between"] * scale,
-                "args": {"instructions": g["compute_between"]}})
-    trace = {"traceEvents": events, "displayTimeUnit": "ms"}
-    with open(path, "w") as f:
-        json.dump(trace, f)
-    return trace
 
 
 def check_bucket_rule(optimizer):

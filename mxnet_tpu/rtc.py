@@ -60,7 +60,8 @@ class PallasModule:
             kernel_fn,
             out_shape=(self._out_shapes[0] if single
                        else self._out_shapes),
-            interpret=self._interpret, **kwargs)(*xs))
+            interpret=self._interpret,
+            name=getattr(kernel_fn, "__name__", None), **kwargs)(*xs))
 
     def __call__(self, *inputs):
         arrs = [i._data if isinstance(i, NDArray) else i for i in inputs]

@@ -17,6 +17,13 @@ files.  This module is the cross-process stitch:
 * span emission (:func:`emit_span`, :func:`span`) into the active
   RunLog as ``span`` records — merged across processes by
   ``tools/tracemerge.py`` into a single Perfetto timeline.
+* :func:`region` — the train path's one host-span primitive: a
+  ``jax.profiler.TraceAnnotation``, so under any profiler session the
+  span lands in the same ``.xplane.pb`` as the device's operations, on
+  one clock (``mx_feed_source``/``mx_feed_h2d``/``mx_feed_wait`` in
+  ``io/device_feed.py``, ``mx_step`` in ``parallel.make_train_step``);
+  on an armed RunLog's sampled steps it is a :class:`span` record as
+  well.
 
 Zero-cost contract (the PR-5 bound): with ``MXNET_RUNLOG`` unset,
 :func:`enabled` is the runlog ``current()`` fast path (two dict
@@ -30,13 +37,15 @@ import os
 import threading
 import time
 
+import jax
+
 from . import runlog as _runlog
 
 __all__ = [
     "TraceContext", "TRACEPARENT_HEADER", "TRACE_ENV", "ROLE_ENV",
     "RANK_ENV", "mint", "from_header", "process_context",
     "current_context", "use", "span", "emit_span", "enabled",
-    "stamp_env", "new_span_id",
+    "region", "stamp_env", "new_span_id",
 ]
 
 #: HTTP header name for the cross-process hop (W3C Trace Context).
@@ -222,6 +231,7 @@ class span:
     """
 
     __slots__ = ("name", "kind", "attrs", "ctx", "_t0", "_use")
+    flush = True  # the record's own; False queues it behind the next
 
     def __init__(self, name, kind="internal", ctx=None, **attrs):
         self.name = name
@@ -250,8 +260,67 @@ class span:
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
         emit_span(self.name, self._t0, t1, self.ctx, kind=self.kind,
-                  **self.attrs)
+                  flush=self.flush, **self.attrs)
         return False
+
+
+class _LoggedRegion(span):
+    """:func:`region` on a RunLog's sampled span: the profiler's
+    annotation and the RunLog's span, entered and left together.  The
+    record queues behind the next flushing one (the sampled ``step``
+    record of a fit), so the step path pays no syscall for it."""
+
+    __slots__ = ("_ann",)
+    flush = False
+
+    def __init__(self, ann, name, attrs):
+        super().__init__(name, **attrs)
+        self._ann = ann
+
+    def __enter__(self):
+        self._ann.__enter__()
+        super().__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        super().__exit__(exc_type, exc, tb)
+        self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+    def set_metadata(self, **attrs):
+        self._ann.set_metadata(**attrs)
+        self.attrs.update(attrs)
+
+
+def region(name, step_num=None, nth=None, **attrs):
+    """One host span of the train path, on the device trace's clock::
+
+        with tracing.region("mx_feed_h2d", nth=n) as r:
+            ...
+            r.set_metadata(bytes=nbytes)  # what is known only at the end
+
+    Returns a ``jax.profiler.TraceAnnotation`` (with ``step_num`` a
+    ``StepTraceAnnotation``, the span that causes a step's device
+    work): inactive outside a profiler session, and inside one an
+    event on the calling thread's line of the ``.xplane.pb``, with
+    ``attrs`` as its stats.  Where a RunLog is armed the span is also
+    written there (:class:`span`: a child of
+    :func:`current_context`), on the RunLog's sampled steps alone:
+    ``nth`` (``step_num`` where that is given) says the how-manieth of
+    its kind the span is, and ``RunLog.should_sync`` which of them are
+    kept; without either, every one.  With no RunLog nothing is minted
+    and no record is built."""
+    if step_num is None:
+        ann = jax.profiler.TraceAnnotation(name, **attrs)
+    else:
+        attrs["step_num"] = step_num
+        ann = jax.profiler.StepTraceAnnotation(name, **attrs)
+        if nth is None:
+            nth = step_num
+    rl = _runlog.current()
+    if rl is None or not (nth is None or rl.should_sync(nth)):
+        return ann
+    return _LoggedRegion(ann, name, attrs)
 
 
 # ------------------------------------------------------------------ spawn

@@ -50,11 +50,13 @@ def test_aggregate_stats_table():
     table = profiler.dumps(format="table", sort_by="count")
     assert "_mul_scalar" in table
     stats = json.loads(profiler.dumps(reset=True, format="json"))
-    entry = [s for s in stats if s["name"] == "_mul_scalar"][0]
+    assert stats["device"] is None  # no profile_device run to read
+    entry = [s for s in stats["ops"] if s["name"] == "_mul_scalar"][0]
     assert entry["count"] == 3
     assert entry["total_us"] >= entry["max_us"] >= entry["min_us"] > 0
     # reset cleared
-    assert profiler.dumps(format="json") == "[]"
+    assert json.loads(profiler.dumps(format="json")) == {
+        "ops": [], "device": None}
 
 
 def test_pause_resume():

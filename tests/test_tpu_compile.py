@@ -177,3 +177,59 @@ def test_bn_relu_conv_backward_declines_stage4_width(one_chip):
     assert "tpu_custom_call" not in _conv_bwd_text(one_chip, 6272, 512,
                                                    2048)
     assert kernel_target.declined_counts()["pallas_bnreluconv"] > before
+
+
+# ------------------------------------------------------- kernels by name
+def _kernel_names(text):
+    """The names under which a compiled text's Pallas kernels appear:
+    each custom call to Mosaic is the instruction ``%<name=>[.N]``, and
+    its ``op_name`` ends ``/<name=>/pallas_call``."""
+    import re
+
+    named = set()
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        m = re.match(r"\s*(?:ROOT\s+)?%([\w\-]+?)(?:\.\d+)? = ", line)
+        assert m, line[:200]
+        assert f"/{m.group(1)}/pallas_call" in line
+        named.add(m.group(1))
+    return named
+
+
+def _lars_update(w, g, m, ids):
+    return pallas_opt.bucket_update(
+        LARS(momentum=0.9, learning_rate=0.1), w, g, (m,), 2.0,
+        seg=(ids, 95))
+
+
+def _sgd_update(w, g, m):
+    return pallas_opt.bucket_update(
+        SGD(momentum=0.9, learning_rate=0.1), w, g, (m,), 2.0)
+
+
+_F32_BUCKET = ((1 << 20,), jnp.float32)
+_NAMED = {
+    "bnreluconv_bwd": None,  # through _conv_bwd_text
+    "bucket_opt_update": (_sgd_update, [_F32_BUCKET] * 3),
+    "lars": (_lars_update, [_F32_BUCKET] * 3 + [((1 << 20,), jnp.int32)]),
+    "flash_attention_fwd": (
+        functools.partial(fa.flash_attention, causal=True,
+                          variant="pallas"),
+        [((1, 8, 512, 128), jnp.float32)] * 3),
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(_NAMED))
+def test_kernel_is_named_in_its_custom_call(one_chip, tpu_target, kernel):
+    """A trace and ``mx.profiler.dumps()`` find a kernel by the
+    ``name=`` its ``pallas_call`` passes, whatever the kernel's Python
+    function is called."""
+    if kernel == "bnreluconv_bwd":
+        text = _conv_bwd_text(one_chip, 25088, 256, 1024)
+    else:
+        fn, specs = _NAMED[kernel]
+        text = _compiled_text(fn, one_chip, *specs)
+    expected = {"lars_norms", "lars_update"} if kernel == "lars" \
+        else {kernel}
+    assert _kernel_names(text) == expected

@@ -99,13 +99,29 @@ def test_thread_stack_and_process_stamp(monkeypatch):
 
 
 @pytest.mark.unit
-def test_unarmed_zero_cost_ab(tmp_path):
+def test_unarmed_zero_cost_ab(tmp_path, monkeypatch):
     """A/B: unarmed (no runlog) => no minting, no spans, stamp_env
     scrubs; armed => same call sites produce the records."""
     # ---- A: unarmed
     assert not tracing.enabled()
     with tracing.span("nothing") as ctx:
         assert ctx is None
+    # the train path's host span is then the profiler's annotation and
+    # no more: no id minted, no wrapper built
+    minted = []
+    monkeypatch.setattr(tracing, "_gen_span_id",
+                        lambda: minted.append(1) or "0" * 16)
+    monkeypatch.setattr(tracing, "_gen_trace_id",
+                        lambda: minted.append(1) or "0" * 32)
+    for r in (tracing.region("mx_nothing", depth=2),
+              tracing.region("mx_nothing", step_num=3)):
+        assert isinstance(r, jax.profiler.TraceAnnotation)
+        with r as inside:
+            inside.set_metadata(bytes=1)
+    assert type(tracing.region("mx_nothing", step_num=3)) \
+        is jax.profiler.StepTraceAnnotation
+    assert not minted
+    monkeypatch.undo()
     env = {tracing.TRACE_ENV: "stale"}
     assert tracing.stamp_env(env, "replica", rank=0) is None
     assert tracing.TRACE_ENV not in env  # scrubbed, never inherited
@@ -115,6 +131,17 @@ def test_unarmed_zero_cost_ab(tmp_path):
     telemetry.reset(path)
     with tracing.span("something", kind="server", k=1) as ctx:
         assert ctx is not None
+        with tracing.region("mx_something", depth=2) as r:
+            r.set_metadata(bytes=7)
+        # queued behind the next flushing record: no syscall of its own
+        with open(path) as f:
+            assert "mx_something" not in f.read()
+        # the RunLog's sampled ones alone, where the caller counts them
+        rl = telemetry.current()
+        kept = [n for n in range(2 * rl.sample + 1)
+                if type(tracing.region("mx_counted", nth=n))
+                is not jax.profiler.TraceAnnotation]
+        assert kept == [0, rl.sample, 2 * rl.sample]
     env2 = {}
     child = tracing.stamp_env(env2, "replica", rank=1)
     assert child is not None
@@ -125,9 +152,11 @@ def test_unarmed_zero_cost_ab(tmp_path):
         recs, problems = schema.validate_lines(f)
     assert not problems, problems[:5]
     spans = [r for r in recs if r["type"] == "span"]
-    assert [s["name"] for s in spans] == ["something"]
-    assert spans[0]["kind"] == "server"
-    assert spans[0]["attrs"]["k"] == 1
+    assert [s["name"] for s in spans] == ["mx_something", "something"]
+    assert spans[1]["kind"] == "server"
+    assert spans[1]["attrs"]["k"] == 1
+    assert spans[0]["attrs"] == {"depth": 2, "bytes": 7}
+    assert spans[0]["parent_span_id"] == spans[1]["span_id"]
 
 
 @pytest.mark.unit
