@@ -11,13 +11,23 @@ OIHW: num_filter, C/group, *k) and the channel-last NWC/NHWC/NDHWC
 family (weights O*kI: num_filter, *k, C/group — the reference's NHWC
 weight convention, convolution.cc layout param).  Channel-last is the
 TPU-native layout: the channel dim lands on the 128-lane minor axis, so
-XLA feeds the MXU without inserting transposes.
+XLA feeds the MXU without inserting transposes.  The lanes are full
+from 128 channels up.  Below that the chip's compiler pads every map to
+128 lanes (a 64-channel map takes twice its bytes and the MXU sees half
+its width both ways) unless the map is paired: on a TPU a 3x3 stride-1
+convolution and a 2x2/2 max-pooling of at most 64 channels, at most 96
+images and at least 128x128 pixels (``_wpack_fits``) run on
+``[N, H, W/2, 2C]``, two W-neighbours side by side on the lanes
+(``_wpack_conv3x3``, ``_wpack_maxpool2x2``).  Both enter and leave the
+paired form by a row-major reshape inside the op, and XLA cancels the
+reshapes between neighbouring paired ops.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from . import kernel_target
 from .registry import register_op
 
 _CHANNEL_LAST = frozenset(("NWC", "NHWC", "NDHWC"))
@@ -116,6 +126,64 @@ def _stem_space_to_depth(data, weight, jnp_pad=jnp.pad):
         dimension_numbers=_dimnums(2, False), feature_group_count=1)
 
 
+#: widest map the W-paired arms take: two of them fill the 128 lanes
+_WPACK_MAX_CHANNELS = 64
+#: largest batch: the chip's layout has the batch on the sublanes, and
+#: from 128 images up the plain convolution fills the MXU from the
+#: batch (paired form measured 1.2-2.1x faster at 1..96 images, 0.8x
+#: at 128 and 256: PERF.md, PR 26)
+_WPACK_MAX_BATCH = 96
+#: smallest map.  The paired form pays a relayout wherever its
+#: neighbour is not paired, and an op cannot see its neighbours: alone
+#: between 1x1 convolutions it loses a quarter of the block's time at
+#: any size (PERF.md, PR 26).  Maps this large with this few channels
+#: are first stages built of 3x3 convolutions (VGG, U-Net); the
+#: 64-channel stages behind a strided stem (ResNet's 56x56) bypass.
+_WPACK_MIN_PIXELS = 128 * 128
+
+
+def _wpack_fits(shape):
+    """Is an NHWC map one that the W-paired arms take on a TPU?"""
+    n, h, w_, c = shape
+    return (c <= _WPACK_MAX_CHANNELS and n <= _WPACK_MAX_BATCH
+            and w_ % 2 == 0 and h * w_ >= _WPACK_MIN_PIXELS
+            and kernel_target.on_tpu())
+
+
+def _wpack_conv3x3(data, weight, pad_h):
+    """NHWC 3x3/stride-1/pad-W-1 convolution on W-pairs: data
+    [N, H, W, Ci] (W even), weight [Co, 3, 3, Ci] -> [N, H', W, Co].
+
+    ``xp = data.reshape(N, H, W/2, 2*Ci)`` holds columns 2j and 2j+1 of
+    the map in one row of lanes (halves r = 0, 1).  Output column 2j+q
+    reads input columns 2j+q+k-1, k the kernel's tap in W; that column
+    is pair j+t-1, half r, where ``k = 2*(t-1) + r - q + 1``.  So the
+    paired kernel ``wp[q*Co+o, kh, t, r*Ci+i] = w[o, kh, k, i]``, zero
+    where k falls outside the kernel (6 of its 12 blocks are filled),
+    gives exactly the products of the plain convolution plus exact
+    zeros: twice the multiply-adds on four times the MXU.  Autodiff
+    goes through the rewrite: both backward convolutions run on the
+    paired maps, and dw comes back through the transpose of the block
+    placement.
+    """
+    n, h, w_, ci = data.shape
+    co = weight.shape[0]
+    xp = data.reshape(n, h, w_ // 2, 2 * ci)
+    k0, k1, k2 = (weight[:, :, k:k + 1] for k in range(3))
+    z = jnp.zeros_like(k0)
+    # block (q, r) over its three paired taps t
+    taps = {(0, 0): (z, k1, z), (0, 1): (k0, k2, z),
+            (1, 0): (z, k0, k2), (1, 1): (z, k1, z)}
+    wp = jnp.concatenate([
+        jnp.concatenate([jnp.concatenate(taps[q, r], axis=2)
+                         for r in (0, 1)], axis=3)
+        for q in (0, 1)], axis=0)
+    out = jax.lax.conv_general_dilated(
+        xp, wp, window_strides=(1, 1), padding=[(pad_h, pad_h), (1, 1)],
+        dimension_numbers=_dimnums(2, True))
+    return out.reshape(n, out.shape[1], w_, co)
+
+
 @register_op("Convolution", aliases=("Convolution_v1",))
 def convolution(data, weight, bias=None, *, kernel, num_filter, stride=None,
                 dilate=None, pad=None, num_group=1, no_bias=False,
@@ -136,6 +204,12 @@ def convolution(data, weight, bias=None, *, kernel, num_filter, stride=None,
           and dilate == (1,) * nd and num_group == 1
           and (out := _conv1x1_dot(data, weight, stride, cl)) is not None):
         pass  # NHWC 1x1 fast path (see _conv1x1_dot)
+    elif (nd == 2 and cl and kernel == (3, 3) and stride == (1, 1)
+          and dilate == (1, 1) and num_group == 1 and pad[1] == 1
+          and weight.shape[0] <= _WPACK_MAX_CHANNELS
+          and _wpack_fits(data.shape)):
+        kernel_target.packed("Convolution")
+        out = _wpack_conv3x3(data, weight, pad[0])
     else:
         dn = _dimnums(nd, cl)
         out = jax.lax.conv_general_dilated(
@@ -210,6 +284,67 @@ def deconvolution(data, weight, bias=None, *, kernel, num_filter,
     return out
 
 
+@jax.custom_vjp
+def _wpack_maxpool2x2(data):
+    """NHWC 2x2/stride-2 max-pooling on W-pairs: [N, H, W, C] (H, W
+    even, floating) -> [N, H/2, W/2, C].  On ``data.reshape(N, H/2, 2,
+    W/2, 2C)`` a window's four members are the two lane halves of its
+    two rows, so no ``reduce_window`` is left: one reduction over the
+    pair of rows, then the two halves elementwise.  The gradient
+    (``_wpack_maxpool2x2_bwd``) is ``select_and_scatter``'s: the whole
+    cotangent to the first member equal to the maximum."""
+    return _wpack_maxpool2x2_fwd(data)[0]
+
+
+def _first_max(a, b):
+    """Of two (value, place) pairs the greater value, and of equal
+    values the earlier place (what ``select_and_scatter``'s ``ge``
+    keeps).  The value is ``maximum``'s, so a NaN stays a NaN."""
+    (va, ia), (vb, ib) = a, b
+    later = (vb > va) | ((vb == va) & (ib < ia))
+    return jnp.maximum(va, vb), jax.lax.select(later, ib, ia)
+
+
+def _wpack_maxpool2x2_fwd(data):
+    n, h, w_, c = data.shape
+    rows = data.reshape(n, h // 2, 2, w_ // 2, 2 * c)
+    # each column's maximum over its two rows, and the row it sits in
+    top, row = jax.lax.reduce(
+        (rows, jax.lax.broadcasted_iota(jnp.int8, rows.shape, 2)),
+        (jnp.array(-jnp.inf, data.dtype), jnp.array(2, jnp.int8)),
+        _first_max, (2,))
+    tl, tr, rl, rr = top[..., :c], top[..., c:], row[..., :c], row[..., c:]
+    # reduce_window's order: row 0 left, row 0 right, row 1 left, ...
+    left = (tl > tr) | ((tl == tr) & (rl <= rr))
+    member = jax.lax.select(left, 2 * rl, 2 * rr + 1)
+    # the backward pass reads it beside the paired cotangent
+    return jnp.maximum(tl, tr), jnp.concatenate([member, member], axis=-1)
+
+
+def _wpack_maxpool2x2_bwd(member, ct):
+    """``member`` [N, H/2, W/2, 2C] int8: which of its window's four
+    members (0..3, in ``reduce_window``'s order) was the first equal to
+    the maximum, once per lane half.  The cotangent goes there whole,
+    elementwise, assembled in the paired form.  (A ``jnp.maximum`` chain
+    under autodiff would halve it at ties.)"""
+    n, h2, w2, c2 = member.shape
+    shape = (n, h2, 2, w2, c2)
+    here = 2 * jax.lax.broadcasted_iota(jnp.int32, shape, 2) \
+        + (jax.lax.broadcasted_iota(jnp.int32, shape, 4) >= c2 // 2)
+    over_rows = lambda a: jnp.broadcast_to(a[:, :, None], shape)
+    ct2 = jnp.concatenate([ct, ct], axis=-1)
+    grad = jax.lax.select(over_rows(member) == here.astype(jnp.int8),
+                          over_rows(ct2), jnp.zeros(shape, ct.dtype))
+    # Without the barrier XLA:TPU moves the reshape below up to the two
+    # broadcasts over the pair of rows and writes both out at full size
+    # (822 + 411 MB at VGG's first stage) before the select reads them.
+    grad = jax.lax.optimization_barrier(grad)
+    return (grad.reshape(n, 2 * h2, 2 * w2, c2 // 2),)
+
+
+_wpack_maxpool2x2.defvjp(_wpack_maxpool2x2_fwd, _wpack_maxpool2x2_bwd)
+
+
 @register_op("Pooling", aliases=("Pooling_v1",))
 def pooling(data, *, kernel=(), pool_type="max", global_pool=False,
             stride=None, pad=None, pooling_convention="valid",
@@ -226,6 +361,14 @@ def pooling(data, *, kernel=(), pool_type="max", global_pool=False,
     stride = _tup(stride, nd)
     pad = _tup(pad, nd, 0)
     kernel = _tup(kernel, nd)
+    # even H and W: the "valid" and "full" conventions agree
+    if (pool_type == "max" and nd == 2 and cl and kernel == (2, 2)
+            and stride == (2, 2) and pad == (0, 0)
+            and data.shape[1] % 2 == 0
+            and jnp.issubdtype(data.dtype, jnp.floating)
+            and _wpack_fits(data.shape)):
+        kernel_target.packed("Pooling")
+        return _wpack_maxpool2x2(data)
     if cl:
         dims = (1,) + kernel + (1,)
         strides = (1,) + stride + (1,)

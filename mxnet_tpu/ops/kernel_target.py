@@ -1,6 +1,7 @@
 """What the three Pallas kernel families share about their target: the
-one probe that says whether this process compiles for a TPU, and the
-one record of a kernel that declined a shape before lowering.
+one probe that says whether this process compiles for a TPU, the one
+record of a kernel that declined a shape before lowering, and the one
+record of an op that took its lane-dense, W-paired arm (ops/conv.py).
 
 A kernel runs compiled on a TPU and in interpret mode elsewhere; only
 a caller's ``interpret=`` argument (tests) changes that.  A shape a
@@ -17,6 +18,7 @@ import jax
 
 _lock = threading.Lock()
 _DECLINED = collections.Counter()
+_PACKED = collections.Counter()
 _SEEN = set()
 
 
@@ -54,3 +56,22 @@ def declined_counts():
     """``{op: times a kernel declined}`` since the process started."""
     with _lock:
         return dict(_DECLINED)
+
+
+def packed(op):
+    """Count one call site of ``op`` that took its W-paired arm.  The
+    choice is made while a program is traced, so this counts call
+    sites of traced programs (and calls, run eagerly); where a run log
+    is armed it counts there too."""
+    from .. import telemetry
+
+    with _lock:
+        _PACKED[op] += 1
+    telemetry.count(f"kernel_packed.{op}")
+
+
+def packed_counts():
+    """``{op: call sites that ran W-paired}`` since the process
+    started."""
+    with _lock:
+        return dict(_PACKED)

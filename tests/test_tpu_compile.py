@@ -233,3 +233,88 @@ def test_kernel_is_named_in_its_custom_call(one_chip, tpu_target, kernel):
     expected = {"lars_norms", "lars_update"} if kernel == "lars" \
         else {kernel}
     assert _kernel_names(text) == expected
+
+
+# ------------------------------------------- the W-paired 64-channel stage
+def _vgg_stage1(x, w0, b0, w1, b1, w2, b2, ct):
+    """VGG-16's first stage and the convolution after it, forward and
+    backward, through the registered ops (what ``vgg16_train`` runs at
+    224x224: conv 3->64, conv 64->64, 2x2 pooling, conv 64->128)."""
+    from mxnet_tpu.ops.registry import get_op
+
+    conv, pool = get_op("Convolution").fn, get_op("Pooling").fn
+
+    def forward(w0, b0, w1, b1, w2, b2):
+        h = x
+        for w, b in ((w0, b0), (w1, b1)):
+            h = jax.nn.relu(conv(h, w, b, kernel=(3, 3), pad=(1, 1),
+                                 num_filter=64, layout="NHWC"))
+        h = pool(h, kernel=(2, 2), stride=(2, 2), pool_type="max",
+                 layout="NHWC")
+        return jax.nn.relu(conv(h, w2, b2, kernel=(3, 3), pad=(1, 1),
+                                num_filter=128, layout="NHWC"))
+
+    out, vjp = jax.vjp(forward, w0, b0, w1, b1, w2, b2)
+    return (out,) + vjp(ct)
+
+
+def _instructions(text):
+    """``(opcode, result dims, operands' dims)`` of every instruction
+    with an array result; an operand's dims are looked up among the
+    instructions of the same computation."""
+    import re
+
+    pat = re.compile(r"^\s*(?:ROOT\s+)?%([\w\-.]+) = \w+\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(([^)]*)\)")
+    dims_of = {}
+    for line in text.splitlines():
+        if line.rstrip().endswith("{"):  # a computation begins
+            dims_of = {}
+        m = pat.match(line)
+        if m:
+            name, dims, code, operands = m.groups()
+            dims_of[name] = tuple(int(d) for d in dims.split(",") if d)
+            yield code, dims_of[name], [
+                dims_of.get(o.strip().lstrip("%")) for o in
+                operands.split(",")]
+
+
+def test_vgg_stage1_runs_paired_without_a_relayout(one_chip, tpu_target):
+    """At the cell's own shapes (batch 64, 224x224, bf16): the chip's
+    compiler keeps the 64-channel maps as ``[64,224,112,128]``, lanes
+    full, from the first convolution to the pooling's gradient.  A
+    change that brings back ``reduce_window`` / ``select_and_scatter``,
+    a 64-featured convolution in the 64->64 layer, or one relayout of a
+    stage-sized map between two paired ops fails here."""
+    import math
+
+    bf = jnp.bfloat16
+    specs = [((64, 224, 224, 3), bf), ((64, 3, 3, 3), bf), ((64,), bf),
+             ((64, 3, 3, 64), bf), ((64,), bf), ((128, 3, 3, 64), bf),
+             ((128,), bf), ((64, 112, 112, 128), bf)]
+    before = kernel_target.packed_counts()
+    text = _compiled_text(_vgg_stage1, one_chip, *specs)
+    after = kernel_target.packed_counts()
+    assert {k: after[k] - before.get(k, 0) for k in after} \
+        == {"Convolution": 2, "Pooling": 1}
+    found = list(_instructions(text))
+    codes = {code for code, _, _ in found}
+    assert "select-and-scatter" not in codes
+    assert "reduce-window" not in codes
+    # the 64->64 layer: forward, input gradient, weight gradient, each
+    # between two maps or a map and a kernel of 128 features
+    convs = sorted((out, *ins) for code, out, ins in found
+                   if code == "convolution" and 6 not in out + ins[0] + ins[1]
+                   and (224 in out or 224 in ins[0]))
+    paired_map, paired_kernel = (64, 224, 112, 128), (128, 3, 3, 128)
+    assert convs == [
+        (paired_map, paired_map, paired_kernel),
+        (paired_map, paired_map, paired_kernel),
+        (paired_kernel, paired_map, paired_map)], convs
+    # no copy or transpose of a map of the stage's size (64*224*224*64
+    # numbers); the image's own relayout to 6 channels is 3% of that
+    stage = 64 * 224 * 224 * 64
+    relayouts = [(code, out) for code, out, _ in found
+                 if code in ("copy", "transpose")
+                 and math.prod(out) >= stage]
+    assert relayouts == []

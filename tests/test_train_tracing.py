@@ -126,6 +126,42 @@ def test_products_and_pooling_sit_under_a_block(compiled):
         assert block is not None and block.startswith("tiny0_"), scope
 
 
+def test_paired_arms_sit_under_their_block(monkeypatch):
+    """The same tiny net as a TPU lowers it (ISSUE 26): the W-paired
+    convolution and the pooling arm's reduction, ``max`` and
+    ``select_n`` open no scope of their own, so each sits under its
+    ``tiny0_*`` block, forward and backward.  (The probe is answered
+    here, and the arms' least map size set aside: the net is 8x8.)"""
+    from mxnet_tpu.ops import conv, kernel_target
+
+    monkeypatch.setattr(kernel_target, "on_tpu", lambda: True)
+    monkeypatch.setattr(conv, "_WPACK_MIN_PIXELS", 0)
+    before = kernel_target.packed_counts()
+    step, p, o = _step()
+    x, y = _batch()
+    lowered = step.lower(p, o, x, y, jax.random.key(0), 1.0).as_text(
+        debug_info=True)
+    after = kernel_target.packed_counts()
+    assert after["Convolution"] > before.get("Convolution", 0)
+    assert after["Pooling"] > before.get("Pooling", 0)
+    assert "reduce_window" not in lowered
+    assert "select_and_scatter" not in lowered
+    assert "tensor<8x8x4x6xbf16>" in lowered  # the paired image
+    seen = set()
+    for scope in re.findall(r'= loc\("([^"]+)"', lowered):
+        phase, block = profiler._phase_and_block(scope)
+        if block in ("tiny0_conv2d0", "tiny0_pool0"):
+            seen.add((phase, block, scope.rsplit("/", 1)[-1]))
+        elif "/" in scope:  # nothing of the arms outside their blocks
+            assert scope.rsplit("/", 1)[-1] != "conv_general_dilated", scope
+    for expected in [("forward", "tiny0_conv2d0", "conv_general_dilated"),
+                     ("backward", "tiny0_conv2d0", "conv_general_dilated"),
+                     ("forward", "tiny0_pool0", "reduce"),
+                     ("forward", "tiny0_pool0", "max"),
+                     ("backward", "tiny0_pool0", "select_n")]:
+        assert expected in seen, (expected, sorted(seen))
+
+
 def test_every_collective_is_under_mx_exchange(compiled):
     arm, _, text = compiled
     found = [(code, scope) for code, scope in _instructions(text)
