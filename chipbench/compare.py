@@ -4,9 +4,19 @@ Two sides took the same first steps from the same weights on the same
 batches: the timed path (the program's compiled step, through the
 window's own call and feed) and the plain reference.  Each side hands in
 its loss at every step, the weights after the first step and the weights
-after the last; the first gradient as the optimizer got it follows from
-the first step's change, since SGD's momentum starts at nought:
-``g1 = -(w1 - w0) / lr``.
+after the last, and the first gradient as the optimizer got it.  Under
+SGD that follows from the first step's change, since the momentum starts
+at nought (``g1 = -(w1 - w0) / lr``; with weight decay it holds ``wd w0``
+too, on both sides alike), and :func:`side` derives it where it is given
+none.  Under Adam the first step's change is ``-lr sign(g)`` whatever the
+gradient, so a side hands in ``grad=``: the first moment after the first
+step over ``1 - beta1``, leaf by leaf.
+
+What the change over all the steps (``w_last - w0``) can show under Adam:
+that every leaf moved, once and by the rate (a state left unchanged reads
+1; a leaf moved double reads 1), and the signs and relative sizes of the
+gradients from the second step on.  It cannot show a gradient that is
+wrong by a factor: Adam divides it out.  That shows in ``grad_*``.
 
 A leaf's gap is the gap between the two sides' NORMS of that leaf (not
 the norm of their difference), against the reference's norm of that leaf
@@ -54,12 +64,22 @@ def leaf_gaps(prog, ref):
     return np.abs(prog - ref) / np.maximum(ref, np.median(ref))
 
 
-def side(losses, w0, w1, w_last, lr):
+#: the numbers :func:`numbers` can yield, beside ``loss_gap_<k>``
+NAMES = ("grad_gap_median", "grad_gap_quartile", "change_gap_median",
+         "grad_gap_weights", "grad_gap_zero", "grad_gap_worst",
+         "change_gap_worst", "grad_gap_weights_worst",
+         "change_gap_weights_worst")
+
+
+def side(losses, w0, w1, w_last, lr, grad=None):
     """One side's readings: losses, per-leaf norms of the first gradient
-    and of the change over all the steps, and which leaves are a
-    product's weights."""
-    grad = leaf_norms([(np.asarray(a, np.float64) - np.asarray(b, np.float64))
-                       / lr for a, b in zip(w1, w0)])
+    (of ``grad``, the leaves of the gradient itself, where the optimizer's
+    state holds it; else derived from the first step's change) and of the
+    change over all the steps, and which leaves are a product's weights."""
+    if grad is None:
+        grad = [(np.asarray(a, np.float64) - np.asarray(b, np.float64))
+                / lr for a, b in zip(w1, w0)]
+    grad = leaf_norms(grad)
     change = leaf_norms([np.asarray(a, np.float64) - np.asarray(b, np.float64)
                          for a, b in zip(w_last, w0)])
     return {"losses": [float(v) for v in losses], "grad": grad,

@@ -1,25 +1,33 @@
 """Operations and least bytes of one training step, from a configuration's
 layer table (``layers`` in ``chipbench/configs/<name>.json``).
 
-A row is one convolution or dense layer (a dense layer is a 1x1
-convolution on a 1x1 image): kernel, stride, groups, channels in and out,
-square input and output sides, how many times it occurs, and whether the
-backward pass needs the gradient of its input (the first layer's does
-not).  A training step runs three products per layer: the forward one,
-the gradient of the input and the gradient of the weights; nothing is
-counted twice for recomputation, and the elementwise work of batch
+A row is one convolution or dense layer, a list under ``layer_columns`` or
+an object with the same keys: ``name``, ``kernel``, ``stride``,
+``groups``, ``cin``, ``cout``, the square sides ``h_in`` and ``h_out`` (a
+dense layer is a 1x1 convolution on a 1x1 image) or, over a sequence,
+``positions`` in their place, ``count`` (how often it occurs) and
+``needs_input_grad`` (the first layer's backward pass makes no gradient
+of its input).  A row of the batch is an image or a sequence.
+Multiply-adds of one product = rows x positions out x cout x cin /
+groups x kernel^2.  A training step runs three products per layer: the
+forward one, the gradient of the input and the gradient of the weights;
+nothing is counted twice for recomputation, and the elementwise work of
 normalisation, activations, loss and optimizer is not counted at all:
 these are the operations the model requires of a matrix unit.
 
-The least bytes are counted per layer and pass, each array once: the
-forward pass reads the input and the weights and writes the output; the
-backward pass reads the input, the output's gradient and the weights and
-writes the weights' gradient and, where it is needed, the input's, in the
-compute type.  (Counted per product, the output's gradient would be read
-twice, and a kernel that makes both gradients in one pass would read over
-100%.)  The roofline time of a pass is the larger of operations / peak
-operations per second and bytes / peak bytes per second; a layer's is the
-sum over its two passes.
+The least bytes are counted per layer and pass, each array once, in the
+compute type: the forward pass reads the input and the weights and
+writes the output; the backward pass reads the input, the output's
+gradient and the weights and writes the weights' gradient and, where it
+is needed, the input's.  (Counted per product, the output's gradient
+would be read twice, and a kernel that makes both gradients in one pass
+would read over 100%.)  The roofline time of a pass is the larger of
+operations / peak operations per second and bytes / peak bytes per
+second; a layer's is the sum over its two passes.
+
+Work that is no such product (attention's scores, a share of routed
+experts) has no row yet: the configuration that runs one brings its
+form with the cell that runs it (PERF.md, Open questions).
 """
 import json
 import os
@@ -39,9 +47,16 @@ def peaks(device_kind):
     return table[device_kind]
 
 
-def _rows(config):
-    cols = config["layer_columns"]
-    return [dict(zip(cols, row)) for row in config["layers"]]
+def rows(config):
+    """Every row of the table as an object."""
+    cols = config.get("layer_columns", [])
+    return [dict(r) if isinstance(r, dict) else dict(zip(cols, r))
+            for r in config["layers"]]
+
+
+def row_weights(r):
+    """Elements of the weights of one occurrence of the row ``r``."""
+    return r["cout"] * (r["cin"] // r["groups"]) * r["kernel"] ** 2
 
 
 def passes(config, batch):
@@ -49,12 +64,15 @@ def passes(config, batch):
     ``flops`` and ``bytes`` are of ONE occurrence of the layer."""
     width = _DTYPE_BYTES[config["compute_dtype"]]
     out = []
-    for r in _rows(config):
-        macs = (batch * r["h_out"] ** 2 * r["cout"]
-                * (r["cin"] // r["groups"]) * r["kernel"] ** 2)
-        x = batch * r["h_in"] ** 2 * r["cin"] * width
-        y = batch * r["h_out"] ** 2 * r["cout"] * width
-        w = r["cout"] * (r["cin"] // r["groups"]) * r["kernel"] ** 2 * width
+    for r in rows(config):
+        if r.get("positions") is not None:
+            p_in = p_out = r["positions"]
+        else:
+            p_in, p_out = r["h_in"] ** 2, r["h_out"] ** 2
+        macs = batch * p_out * row_weights(r)
+        x = batch * p_in * r["cin"] * width
+        y = batch * p_out * r["cout"] * width
+        w = row_weights(r) * width
         grads = 2 if r["needs_input_grad"] else 1
         out.append((r["name"], "forward", r["count"], 2 * macs, x + w + y))
         out.append((r["name"], "backward", r["count"], 2 * macs * grads,

@@ -5,7 +5,29 @@ One process drives ``DeviceFeedIter`` -> the function that
 logs its loss does: batches come in turn from a pool of host arrays made
 from the seed, and the loop reads the loss of step i - ``inflight`` before
 it dispatches step i.  Everything that differs between cells is in the
-traffic file and the configuration file; this module names neither.
+traffic file and the configuration file; this module names neither.  It
+drives any gluon net that ``make_train_step`` takes.
+
+What a configuration declares (``chipbench/configs/<config>.json``):
+``input.kind``, ``image`` (the default: ``height``, ``width``,
+``channels``, ``dtype``; labels are class ids below ``classes``, one a
+row) or ``tokens`` (``seq``, ``vocab``, ``dtype``, ``ids``: ``zipf1``,
+which is ``floor(vocab ** u)`` for uniform ``u``; a row is ``seq + 1``
+ids drawn so, the input its first ``seq`` and the labels its last: the
+next token; the loss is the mean over all positions).  A row of
+the batch is an image or a sequence: ``window["images"]`` counts rows, so
+``images_per_s`` reads sequences a second under tokens, and the result
+carries ``tokens`` (a step's) beside ``batch``.  ``optimizer.name`` is
+``sgd`` (``momentum``) or ``adamw`` (``beta1``, ``beta2``, ``epsilon``),
+each with ``learning_rate`` and ``wd``.  Under ``adamw`` both sides read
+the first gradient from the first moment after the first step
+(``opt_state[name][0] / (1 - beta1)``; ``chipbench/compare.py`` says
+why), which needs a state kept by the leaf's name: not yet with
+``optimizer_sharding``, whose state is kept by bucket.
+
+What the tests call, and what a configuration of another shape has to
+find here: :func:`build_net`, :func:`sample_input`, :func:`make_pool`,
+:func:`specs_of`, :func:`reference_side`, :func:`compared_names`.
 
 Traffic parameters (``chipbench/traffic/<traffic>.json``):
 ``batch_per_chip``, ``pool`` (host batches), ``inflight`` (steps not yet
@@ -46,12 +68,12 @@ def build_net(config, batch):
     else:
         net = fn(*c.get("args", []), **c.get("kwargs", {}))
     ctx = mx.tpu(0)
-    i = config["input"]
+    tokens = input_kind(config) == "tokens"
     net.initialize(init=mx.init.Zero(), ctx=ctx)
     jax.eval_shape(lambda x: net(mx.nd.NDArray(x))._data,
                    jax.ShapeDtypeStruct(
-                       (batch, i["height"], i["width"], i["channels"]),
-                       jnp.float32))
+                       input_shape(config, batch),
+                       jnp.int32 if tokens else jnp.float32))
     # the abstract forward left tracers in the deferred parameters
     net.initialize(init=mx.init.Zero(), ctx=ctx, force_reinit=True)
     return net
@@ -73,22 +95,45 @@ def place_weights(params, names, arrays):
     return out
 
 
-def make_pool(config, batch, n, seed):
-    """``n`` host batches (images in the input type, labels as float
-    class ids), drawn on the device from the seed and copied back."""
+def input_kind(config):
+    return config["input"].get("kind", "image")
+
+
+def input_shape(config, batch):
+    i = config["input"]
+    if input_kind(config) == "tokens":
+        return (batch, i["seq"])
+    return (batch, i["height"], i["width"], i["channels"])
+
+
+def sample_input(config, batch, key):
+    """One batch ``(x, y)`` as the program is fed it, drawn from ``key``:
+    images in the input type with float class ids, or token ids with the
+    next token at every position as float labels."""
     import jax
     import jax.numpy as jnp
 
     i = config["input"]
-    shape = (batch, i["height"], i["width"], i["channels"])
-
-    @jax.jit
-    def draw(key):
-        kx, ky = jax.random.split(key)
-        x = jax.random.normal(kx, shape, jnp.float32).astype(i["dtype"])
+    kx, ky = jax.random.split(key)
+    if input_kind(config) == "image":
+        x = jax.random.normal(kx, input_shape(config, batch),
+                              jnp.float32).astype(i["dtype"])
         y = jax.random.randint(ky, (batch,), 0, config["classes"])
         return x, y.astype(jnp.float32)
+    if i["ids"] != "zipf1":
+        raise ValueError(f"unknown distribution of ids {i['ids']!r}")
+    u = jax.random.uniform(kx, (batch, i["seq"] + 1), jnp.float32)
+    ids = jnp.minimum(jnp.floor(i["vocab"] ** u).astype(jnp.int32),
+                      i["vocab"] - 1)
+    return ids[:, :-1].astype(i["dtype"]), ids[:, 1:].astype(jnp.float32)
 
+
+def make_pool(config, batch, n, seed):
+    """``n`` host batches as :func:`sample_input` draws them, on the
+    device from the seed, copied back."""
+    import jax
+
+    draw = jax.jit(lambda key: sample_input(config, batch, key))
     key = jax.random.fold_in(weights.seed_key(seed), 1)
     pool = []
     for k in range(n):
@@ -169,7 +214,8 @@ def reference_steps(ref, config, specs, w0, batches, groups,
                     precision="float32", rows=None, only_group=None,
                     frozen=()):
     """The plain reference through the same first steps: losses, the
-    state after the first step and after the last.  The optimizer moves
+    state after the first step and after the last, and the first gradient
+    where the optimizer's state holds it (else None).  The optimizer moves
     every leaf but those of the kinds that the forward pass moves itself
     (``weights.MOVED_BY_FORWARD``).  ``groups`` is how many equal parts of
     a batch are normalised each by its own statistics (one per chip where
@@ -185,12 +231,13 @@ def reference_steps(ref, config, specs, w0, batches, groups,
     from chipbench import refmath
 
     fn = refmath.loss_and_grad(ref.forward, config["arch"], precision)
-    opt = config["optimizer"]
-    kinds = [kind for kind, _ in specs]
+    slots, update, first_gradient = refmath.optimizer_rule(
+        config["optimizer"])
+    kinds = [spec[0] for spec in specs]
     w = [jnp.asarray(a) for a in w0]
-    mom = [jnp.zeros_like(a) for a in w]
-    losses, w1 = [], None
-    for x, y in batches:
+    state = [(jnp.zeros_like(a),) * slots for a in w]
+    losses, w1, grad1 = [], None, None
+    for t, (x, y) in enumerate(batches, 1):
         n = x.shape[0] // groups
         parts = range(groups) if only_group is None else [only_group]
         loss, moved = 0.0, {}
@@ -208,13 +255,13 @@ def reference_steps(ref, config, specs, w0, batches, groups,
             if kind in weights.MOVED_BY_FORWARD:
                 w[i] = moved[i]
             else:
-                w[i], mom[i] = refmath.sgd_momentum(
-                    w[i], mom[i], grads[i], opt["learning_rate"],
-                    opt["momentum"], opt["wd"])
+                w[i], state[i] = update(w[i], state[i], grads[i], float(t))
         losses.append(loss)
         if w1 is None:
             w1 = [np.asarray(a) for a in w]
-    return losses, w1, [np.asarray(a) for a in w]
+            if first_gradient:
+                grad1 = [np.asarray(first_gradient(s)) for s in state]
+    return losses, w1, [np.asarray(a) for a in w], grad1
 
 
 # -------------------------------------------------------------------- run
@@ -236,9 +283,11 @@ def build(cell):
     opt = config["optimizer"]
     step, params, opt_state = parallel.make_train_step(
         net, gluon.loss.SoftmaxCrossEntropyLoss(), optimizer=opt["name"],
-        learning_rate=opt["learning_rate"], momentum=opt["momentum"],
-        wd=opt["wd"], compute_dtype=config["compute_dtype"], mesh=mesh,
-        optimizer_sharding=traffic.get("optimizer_sharding"), donate=True)
+        learning_rate=opt["learning_rate"], wd=opt["wd"],
+        compute_dtype=config["compute_dtype"], mesh=mesh,
+        optimizer_sharding=traffic.get("optimizer_sharding"), donate=True,
+        **{k: opt[k] for k in ("momentum", "beta1", "beta2", "epsilon")
+           if k in opt})
     say("autotune winners loaded: "
         + str({k: v.get("winner") for k, v in
                autotune.last_report().items()} or "none"))
@@ -252,9 +301,22 @@ def build(cell):
 
 
 def specs_of(cell):
+    """The reference's ``param_specs(arch, width of the input, classes)``:
+    the channels and classes of an image configuration, the vocabulary
+    twice of a token one."""
     config = cell["config"]
+    if input_kind(config) == "tokens":
+        vocab = config["input"]["vocab"]
+        return cell["reference"].param_specs(config["arch"], vocab, vocab)
     return cell["reference"].param_specs(
         config["arch"], config["input"]["channels"], config["classes"])
+
+
+def compared_names(traffic):
+    """The names a traffic file's ``limits`` has to hold: every number
+    this kind's comparison can yield."""
+    return set(compare.NAMES) | {
+        f"loss_gap_{k}" for k in range(1, traffic["check_steps"] + 1)}
 
 
 def start(cell, built, seed, params, opt_state, pool_size):
@@ -278,15 +340,23 @@ def start(cell, built, seed, params, opt_state, pool_size):
 def first_steps(cell, built, loop, w0):
     """The first steps through the loop's own call and feed, each awaited:
     the program's side of the comparison."""
-    w1 = None
+    from chipbench import refmath
+
+    opt = cell["config"]["optimizer"]
+    first_gradient = refmath.optimizer_rule(opt)[2]
+    w1 = grad1 = None
     for k in range(cell["traffic"]["check_steps"]):
         loop.one()
         loop.drain()
         if k == 0:
             w1 = host_leaves(loop.params, built["names"])
+            if first_gradient:
+                grad1 = [np.asarray(first_gradient(
+                    [np.asarray(s, np.float32)
+                     for s in loop.opt_state[n]])) for n in built["names"]]
     w_last = host_leaves(loop.params, built["names"])
-    return compare.side(loop.losses, w0, w1, w_last,
-                        cell["config"]["optimizer"]["learning_rate"])
+    return compare.side(loop.losses, w0, w1, w_last, opt["learning_rate"],
+                        grad=grad1)
 
 
 def reference_side(cell, built, w0, pool, **fault):
@@ -294,11 +364,12 @@ def reference_side(cell, built, w0, pool, **fault):
 
     n = cell["traffic"]["check_steps"]
     with jax.default_device(built["devices"][0]):
-        losses, w1, w_last = reference_steps(
+        losses, w1, w_last, grad1 = reference_steps(
             cell["reference"], cell["config"], specs_of(cell), w0,
             pool[:n], built["groups"], **fault)
     return compare.side(losses, w0, w1, w_last,
-                        cell["config"]["optimizer"]["learning_rate"])
+                        cell["config"]["optimizer"]["learning_rate"],
+                        grad=grad1)
 
 
 def run(cell):
@@ -371,27 +442,51 @@ def run(cell):
         "setup_s": setup_s, "window": window, "trace": trace,
         "hlo_text": hlo_text, "memory_peak_bytes": int(memory_peak),
         "reported": reported, "compared": table, "batch": batch,
+        "tokens": batch * cell["config"]["input"]["seq"]
+        if input_kind(cell["config"]) == "tokens" else None,
     }
 
 
-def readings(cell, seeds, n_controls):
+def readings(cell, seeds, n_controls, flush=None, program_until=None,
+             until=None):
     """The readings a limit is set from, in one process: for every seed
-    the program's first steps against the reference, and for the first
-    ``n_controls`` seeds the control (the reference in the nearest
-    precision below the configuration's) and the planted faults (half of
-    each batch part left out; with several parts, all parts but the first
-    left out, which is the exchange between chips left out) against the
-    reference.  Returns ``{seed: {who: {number: value}, "leaves": every
-    side's losses and leaf norms}, "names": the leaves' names}``."""
+    the program's first steps against the reference, and then for the
+    first ``n_controls`` seeds the planted faults (with several parts, all
+    parts but the first left out, which is the exchange between chips
+    left out; half of each batch part left out) and the control (the
+    reference in the nearest precision below the configuration's) against
+    the reference.  Returns ``{seed: {who: {number: value}, "correct":
+    {who: the verdict under the cell's limits}, "leaves": every side's
+    losses and leaf norms}, "names": the leaves' names}`` and hands the
+    same to ``flush`` after every reading, so that a call cut short keeps
+    what it read.  No seed's program starts later than ``program_until``
+    seconds after the clock's origin, no reading later than ``until``: a
+    call on the chip is paid by the second, and a cold one compiles for
+    minutes."""
     import jax
 
-    traffic, say = cell["traffic"], cell["say"]
+    config, traffic, say = cell["config"], cell["traffic"], cell["say"]
     built = build(cell)
+    # bfloat16's; the tests' float32 twin of a cell takes the same
+    below = {"bfloat16": "float8", "float32": "float8"}[
+        config["compute_dtype"]]
+    faults = {"control_" + below: {"precision": below},
+              "fault_half_batch": {"rows": traffic["batch_per_chip"] // 2}}
+    if built["groups"] > 1:
+        faults = dict(fault_no_exchange={"only_group": 0}, **faults)
+
+    def late(limit):
+        return limit is not None \
+            and time.perf_counter() - cell["t0"] > limit
+
     template = (built.pop("params"), built.pop("opt_state"))
     copy = jax.jit(lambda tree: jax.tree_util.tree_map(lambda a: a + 0,
                                                        tree))
     kept = {}
     for seed in seeds:
+        if kept and late(program_until):
+            say(f"no time for the program on seed {seed} and after")
+            break
         params, opt_state = copy(template)
         loop, feed, w0, pool = start(cell, built, seed, params, opt_state,
                                      traffic["check_steps"])
@@ -404,27 +499,42 @@ def readings(cell, seeds, n_controls):
         loop.params = loop.opt_state = None
     built.pop("step")
     del template
-    below = {"bfloat16": "float8"}[cell["config"]["compute_dtype"]]
     out = {"names": built["names"]}
-    for k, (seed, (prog, w0, pool)) in enumerate(kept.items()):
-        ref = reference_side(cell, built, w0, pool)
-        sides = {"program": prog}
-        if k < n_controls:
-            rows = traffic["batch_per_chip"] // 2
-            others = {"control_" + below: {"precision": below},
-                      "fault_half_batch": {"rows": rows}}
-            if built["groups"] > 1:
-                others["fault_no_exchange"] = {"only_group": 0}
-            for who, fault in others.items():
-                sides[who] = reference_side(cell, built, w0, pool, **fault)
-        out[seed] = {who: compare.numbers(side, ref)[0]
-                     for who, side in sides.items()}
-        say(f"seed {seed}: " + json.dumps(out[seed]))
-        # every side's leaf norms too, for the look behind a limit
-        out[seed]["leaves"] = {
-            who: {k2: np.asarray(v).tolist() for k2, v in side.items()}
-            for who, side in dict(sides, reference=ref).items()}
+
+    def read(seed, who, side, ref):
+        values = compare.numbers(side, ref)[0]
+        entry = out.setdefault(seed, {"correct": {}, "leaves": {
+            "reference": _plain(ref)}})
+        entry[who] = values
+        entry["correct"][who] = compare.verdict(values, traffic["limits"])[0]
+        entry["leaves"][who] = _plain(side)
+        say(f"seed {seed} {who}: correct {entry['correct'][who]} "
+            + json.dumps(values))
+        if flush:
+            flush(out)
+
+    refs = {}
+    for seed, (prog, w0, pool) in kept.items():
+        if refs and late(until):
+            say(f"no time for the reference on seed {seed} and after")
+            break
+        refs[seed] = reference_side(cell, built, w0, pool)
+        read(seed, "program", prog, refs[seed])
+    for who, fault in faults.items():
+        for seed in list(refs)[:n_controls]:
+            if late(until):
+                say(f"no time for {who} on seed {seed} and after")
+                break
+            _, w0, pool = kept[seed]
+            read(seed, who, reference_side(cell, built, w0, pool, **fault),
+                 refs[seed])
     return out
+
+
+def _plain(side):
+    """A side's losses and leaf norms as lists, for the look behind a
+    limit."""
+    return {k: np.asarray(v).tolist() for k, v in side.items()}
 
 
 def _peak_bytes(device):

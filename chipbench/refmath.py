@@ -1,7 +1,8 @@
 """Plain float32 ``jax.numpy`` building blocks shared by the references
 under ``chipbench/reference/``: convolution, dense, training-mode batch
-normalisation, pooling, the loss, SGD with momentum, and the fake
-quantisation the lower-precision control uses.
+normalisation, pooling, the loss, the optimizers' rules (SGD with
+momentum, AdamW), and the fake quantisation the lower-precision control
+uses.
 
 Nothing here imports the program (``mxnet_tpu``).  Layouts are fixed:
 activations NHWC, convolution weights OHWI (out, kh, kw, in / groups),
@@ -96,10 +97,11 @@ def maxpool(x, kernel, stride, pad):
 
 
 def softmax_cross_entropy(logits, labels):
-    """Mean over the rows of -log softmax(logits)[label]."""
+    """Mean of -log softmax(logits)[label] over the rows and, where the
+    logits have them, over all positions of every row."""
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     picked = jnp.take_along_axis(
-        logp, labels.astype(jnp.int32)[:, None], axis=-1)
+        logp, labels.astype(jnp.int32)[..., None], axis=-1)
     return -jnp.mean(picked)
 
 
@@ -110,17 +112,62 @@ def sgd_momentum(w, mom, g, lr, momentum, wd):
     return w + mom, mom
 
 
+def adamw(w, m, v, g, t, lr, beta1, beta2, epsilon, wd):
+    """Adam with decoupled weight decay (Loshchilov & Hutter,
+    arXiv:1711.05101, Algorithm 2, schedule multiplier 1) at step ``t``
+    (from 1): ``m = beta1 m + (1 - beta1) g``, ``v = beta2 v + (1 - beta2)
+    g^2``, ``w -= lr_t m / (sqrt(v) + epsilon) + wd w`` with ``lr_t = lr
+    sqrt(1 - beta2^t) / (1 - beta1^t)``.  The bias correction is written
+    as the program's ``_adamw_step`` and Kingma & Ba's section 2 have it,
+    folded into the rate; Algorithm 2 divides ``m`` and ``v`` themselves,
+    which is the same but for ``epsilon``, which there is added to
+    ``sqrt(v / (1 - beta2^t))`` and here to ``sqrt(v)``: a factor of
+    ``sqrt(1 - beta2^t)`` on ``epsilon``, 0.03 at the first step, that
+    shows only where a gradient is as small as ``epsilon``.  The decay
+    ``wd w`` is not multiplied by the rate, in the algorithm and in the
+    program alike.  Returns ``(w, m, v)``."""
+    m = beta1 * m + (1.0 - beta1) * g
+    v = beta2 * v + (1.0 - beta2) * g * g
+    lr_t = lr * jnp.sqrt(1.0 - beta2 ** t) / (1.0 - beta1 ** t)
+    return w - (lr_t * m / (jnp.sqrt(v) + epsilon) + wd * w), m, v
+
+
+def optimizer_rule(opt):
+    """A configuration's ``optimizer`` group as ``(slots, update,
+    first_gradient)``: ``update(w, state, g, t) -> (w, state)`` over a
+    state of ``slots`` arrays that start at nought, and
+    ``first_gradient(state after step 1)``, the gradient of step 1 as the
+    rule got it, or None where the state does not hold it (under SGD it
+    follows from the weights: ``chipbench/compare.py``)."""
+    if opt["name"] == "sgd":
+        def update(w, state, g, t):
+            w, mom = sgd_momentum(w, state[0], g, opt["learning_rate"],
+                                  opt["momentum"], opt["wd"])
+            return w, (mom,)
+        return 1, update, None
+    if opt["name"] == "adamw":
+        def update(w, state, g, t):
+            w, m, v = adamw(w, state[0], state[1], g, t,
+                            opt["learning_rate"], opt["beta1"],
+                            opt["beta2"], opt["epsilon"], opt["wd"])
+            return w, (m, v)
+        return 2, update, lambda state: state[0] / (1.0 - opt["beta1"])
+    raise ValueError(f"no plain rule for optimizer {opt['name']!r}")
+
+
 def loss_and_grad(forward, arch, precision):
     """The jitted ``(params, x, y) -> ((loss, moved), grads)`` of one
     reference: ``forward(params, x, arch, precision)`` gives the logits
     and ``moved``, the new value of every leaf that the forward pass
     itself moves (batch normalisation's running statistics) by its index
     in ``params``; ``arch`` is the configuration file's ``arch`` group,
-    ``x`` may come in bf16 (it is widened, which is exact) and ``y`` holds
-    class ids."""
+    ``x`` may come in bf16 (it is widened, which is exact) or hold token
+    ids (left as they are) and ``y`` holds class ids: one a row, or one
+    a position."""
     def loss(params, x, y):
-        logits, moved = forward(params, x.astype(jnp.float32), arch,
-                                precision)
+        if jnp.issubdtype(x.dtype, jnp.floating):
+            x = x.astype(jnp.float32)
+        logits, moved = forward(params, x, arch, precision)
         return softmax_cross_entropy(logits, y), moved
 
     return jax.jit(jax.value_and_grad(loss, has_aux=True))

@@ -20,12 +20,32 @@ instruction's opcode gives the class, and for a ``fusion`` the body it
 calls is looked up in the compiled step's text
 (``compiled.as_text()``): a fusion whose body holds a ``convolution``
 or a ``dot`` is a ``conv_dot`` event.  A ``custom-call`` is a
-``conv_dot`` event where its kernel's name is in
-``chipbench/conv_kernels.json`` (the convolution kernels of
-``mxnet_tpu/ops/pallas_conv.py``), so that an autotune arm that swaps
-such a kernel in leaves something that bounds the share.  Classes:
-``conv_dot``, ``collective``, ``copy``, ``custom_call``, ``other``.
+``conv_dot`` event where its target, its ``kernel_name`` or the
+instruction's own name without its number (``%bnreluconv_bwd.1``, which
+is how a ``pl.pallas_call(name=...)`` shows on the chip) is listed by a
+file of ``chipbench/kernels/`` (every ``*.json`` there, each
+``{"kernels": [...]}``: a configuration that brings a kernel brings a
+file), so that a kernel swapped in for a convolution or a dot leaves
+something that bounds the share.  Classes: ``conv_dot``, ``collective``,
+``copy``, ``custom_call``, ``other``.
+
+Where the time lies.  The compiled text's ``metadata={op_name=...}``
+carries the scopes the program opened (``jax.named_scope``):
+``mx_forward``, ``mx_loss``, ``mx_guard``, ``mx_exchange``,
+``mx_optimizer``; the backward pass is ``transpose(jvp(mx_forward))``.
+``by_phase_s`` counts every event of the busiest device under ONE phase,
+that of its instruction or, for a fusion without a scope of its own, of
+its body's root.  The phases add up to the operations' seconds; a
+phase's time per step is read from here (``forward_ms.train``,
+``backward_ms.train``).  XLA fuses across scopes (a weight's update rides
+in the fusion that makes its gradient), so a phase holds what rides with
+it.  :func:`phase_of` and :func:`phase_table` are the benchmark's own
+copy, on purpose (the yardstick lies where the program's PRs cannot move
+it), of the part of ``mxnet_tpu/profiler.py::_phase_and_block`` and
+``_scope_table`` that this takes; ``tests/chipbench/test_units.py`` holds
+the two to the same table on the program's own recording.
 """
+import functools
 import glob
 import gzip
 import json
@@ -38,6 +58,8 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 OPS_LINE = "XLA Ops"
 ASYNC_LINE = "Async XLA Ops"
 HOST_PREFIX = "cb_"
+PHASES = ("forward", "backward", "loss", "guard", "exchange", "optimizer",
+          "unscoped")
 #: device gaps shorter than this are the core's own turn-around between
 #: two operations and are not attributed to the host
 MIN_GAP_S = 2e-6
@@ -45,7 +67,11 @@ MIN_GAP_S = 2e-6
 _NAME = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
 _OPCODE = re.compile(r"\s([a-z][a-z\-]*)\(")
 _CALLS = re.compile(r"calls=%?([\w.\-]+)")
-_COMPUTATION = re.compile(r"^\s*%?([\w.\-]+)\s+\(.*\)\s*->.*\{\s*$")
+_COMPUTATION = re.compile(
+    r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s*->.*\{\s*$")
+_ROOT = re.compile(r"^\s*ROOT\s")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_PHASE_PART = re.compile(r"mx_(forward|loss|guard|exchange|optimizer)\b")
 _TARGET = re.compile(r'custom_call_target="([^"]+)"')
 _KERNEL = re.compile(r'kernel_name["\\:= ]+([\w.\-]+)')
 
@@ -110,9 +136,14 @@ def opcode(instruction):
     return m.group(1) if m else ""
 
 
+@functools.lru_cache(maxsize=None)
 def conv_kernels():
-    with open(os.path.join(_HERE, "conv_kernels.json")) as f:
-        return set(json.load(f)["kernels"])
+    """The names listed by every file of ``chipbench/kernels/``."""
+    names = set()
+    for path in sorted(glob.glob(os.path.join(_HERE, "kernels", "*.json"))):
+        with open(path) as f:
+            names.update(json.load(f)["kernels"])
+    return frozenset(names)
 
 
 def class_table(hlo_text):
@@ -163,7 +194,8 @@ def _class_of(line, code, bodies, kernels):
         m = _CALLS.search(line)
         return "conv_dot" if m and bodies.get(m.group(1)) else "other"
     if base == "custom-call":
-        names = _TARGET.findall(line) + _KERNEL.findall(line)
+        names = _TARGET.findall(line) + _KERNEL.findall(line) \
+            + [re.sub(r"\.\d+$", "", op_name(line))]
         return "conv_dot" if kernels.intersection(names) else "custom_call"
     return "other"
 
@@ -174,7 +206,58 @@ def classify(event_name, table):
     name = op_name(event_name)
     if name in table:
         return table[name]
-    return _class_of(event_name, opcode(event_name), {}, set())
+    return _class_of(event_name, opcode(event_name), {}, conv_kernels())
+
+
+# ----------------------------------------------------------------- phases
+@functools.lru_cache(maxsize=None)
+def phase_of(scope):
+    """The phase of an ``op_name``: its innermost ``mx_*`` scope (the
+    transpose of forward or loss is backward); ``unscoped`` where it
+    holds none."""
+    phase = "unscoped"
+    for part in scope.split(";")[0].split("/"):
+        m = _PHASE_PART.search(part)
+        if m:
+            phase = m.group(1)
+            if part.startswith("transpose(") and phase in ("forward",
+                                                           "loss"):
+                phase = "backward"
+    return phase
+
+
+def phase_table(hlo_text):
+    """``{instruction: op_name}`` from a compiled step's text.  An
+    instruction without metadata of its own that calls a computation (a
+    fusion) takes the ``op_name`` of that computation's root."""
+    own, calls, roots, current = {}, {}, {}, None
+    for line in hlo_text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            current = m.group(1)
+            continue
+        m = _NAME.match(line)
+        if not m:
+            continue
+        name = m.group(1)
+        scope = _OP_NAME.search(line)
+        if scope:
+            own[name] = scope.group(1)
+        called = _CALLS.search(line)
+        if called:
+            calls[name] = called.group(1)
+        if _ROOT.match(line) and current is not None:
+            roots[current] = name
+    table = dict(own)
+    for name, body in calls.items():
+        table[name] = own.get(name) or own.get(roots.get(body), "")
+    return table
+
+
+def _phase_of_event(event_name, scopes):
+    in_text = _OP_NAME.search(event_name)
+    return phase_of(in_text.group(1) if in_text
+                    else scopes.get(op_name(event_name), ""))
 
 
 def union(intervals):
@@ -215,9 +298,10 @@ def reduce(events, hlo_text=""):
     the devices: seconds busy (the union of the ``XLA Ops`` events), the
     window (first operation's start to the last one's end), seconds by
     class, and the collective seconds during which no operation of
-    another class ran on that device.  ``device_ops`` and ``idle_gaps``
-    are of the busiest device."""
+    another class ran on that device.  ``device_ops``, ``idle_gaps`` and
+    ``by_phase_s`` are of the busiest device."""
     table = class_table(hlo_text) if hlo_text else {}
+    scopes = phase_table(hlo_text) if hlo_text else {}
     host = [(n, s, s + d) for n, s, d in events["host"]
             if n != "cb_traced_window"]
     per_device = {}
@@ -228,11 +312,13 @@ def reduce(events, hlo_text=""):
                   for n, s, d in lines["async"]]
         busy = union((s, e) for _, s, e, _ in ops)
         window = (busy[0][0], busy[-1][1])
-        by_class, by_op = {}, {}
+        by_class, by_op, by_phase = {}, {}, {}
         for n, s, e, c in ops:
             by_class[c] = by_class.get(c, 0.0) + (e - s)
             key = f"{c}:{op_name(n)}"
             by_op[key] = by_op.get(key, 0.0) + (e - s)
+            phase = _phase_of_event(n, scopes)
+            by_phase[phase] = by_phase.get(phase, 0.0) + (e - s)
         coll = union((s, e) for _, s, e, c in ops + asyncs
                      if c == "collective")
         rest = union((s, e) for _, s, e, c in ops if c != "collective")
@@ -240,6 +326,7 @@ def reduce(events, hlo_text=""):
         per_device[plane] = {
             "busy_s": length(busy), "window_s": window[1] - window[0],
             "by_class_s": by_class, "by_op_s": by_op,
+            "by_phase_s": by_phase,
             "collective_s": length(coll),
             "collective_exposed_s": length(subtract(coll, rest)),
             "gaps": gaps,
@@ -263,6 +350,7 @@ def reduce(events, hlo_text=""):
                             for d in per_device.values()) / n,
         "collective_exposed_s": sum(d["collective_exposed_s"]
                                     for d in per_device.values()) / n,
+        "by_phase_s": busiest["by_phase_s"],
         "device_ops": _top_ops(busiest),
         "idle_gaps": _gaps_by_host_span(busiest["gaps"], host),
     }

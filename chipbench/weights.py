@@ -1,11 +1,14 @@
 """Weights from the seed, made on the device in one jitted call.
 
 The benchmark makes the weights, not the program: the same arrays go to
-the system under test and to the plain reference.  Each array is drawn by
-its kind (see a reference's ``param_specs``):
+the system under test and to the plain reference.  A spec is ``(kind,
+shape)``; fan-in is the product of all axes but the first.  Each array is
+drawn by its kind (see a reference's ``param_specs``):
 
 - ``conv``: normal, variance 2 / fan-in (He et al., arXiv:1502.01852);
 - ``dense``: normal, variance 1 / fan-in; ``bias``: normal, deviation 0.01;
+- ``embedding`` (rows, width): normal, variance 1 / width: a row's norm
+  is about 1 whatever the width;
 - ``gamma``: 1 + 0.1 normal; ``beta``: 0.1 normal, so that no two leaves
   and no two channels are alike and a mixed-up leaf shows;
 - ``gamma_last`` (the batch normalisation that closes a residual branch):
@@ -35,6 +38,8 @@ def _draw(kind, shape, key):
         return normal * math.sqrt(2.0 / fan_in)
     if kind == "dense":
         return normal * math.sqrt(1.0 / fan_in)
+    if kind == "embedding":
+        return normal * math.sqrt(1.0 / shape[-1])
     if kind == "bias":
         return 0.01 * normal
     if kind in ("gamma", "running_var"):
@@ -53,7 +58,7 @@ def seed_key(seed):
 
 
 def make(specs, seed):
-    """The list of float32 arrays for ``specs`` = [(kind, shape)]."""
+    """The list of float32 arrays for ``specs``."""
     specs = tuple((kind, tuple(shape)) for kind, shape in specs)
 
     @jax.jit

@@ -7,6 +7,14 @@ known figures on a recorded trace; ``run.py`` measures nothing on a CPU;
 each planted fault comes out not correct under the cells' own limits, and
 running statistics left unchanged do too; the lower-precision control
 reads wider than rounding (it does not fail the limits: PERF.md).
+
+Nothing here knows what a configuration's input is.  A test builds a
+configuration at the size its file declares under ``tiny`` and takes the
+net, the input, the pool of batches, the specs and the reference's side
+from the functions of the cell's kind (``chipbench/kinds/<kind>.py``).
+``ROOT`` is wherever this file lies, so that the suite runs as well in a
+copy of the tree to which a configuration's files were added
+(``test_witness.py``).
 """
 import copy
 import json
@@ -44,6 +52,20 @@ def _chipbench(*parts):
     return os.path.join(ROOT, "chipbench", *parts)
 
 
+def _kind_of(config_name):
+    """The module of the kind that drives ``config_name``'s cells; of
+    ``train_closed`` for a configuration that is kept without a cell."""
+    for w in BENCH["workloads"]:
+        if w["config"] == config_name:
+            return cb.load_module("kinds", cb.load_json(
+                "traffic", w["traffic"] + ".json")["kind"])
+    return cb.load_module("kinds", "train_closed")
+
+
+def _specs(kind, config, ref):
+    return kind.specs_of({"config": config, "reference": ref})
+
+
 # ------------------------------------------------------- BENCHMARK.json
 def test_benchmark_json_has_exactly_the_contract_keys():
     assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
@@ -73,13 +95,8 @@ def test_cell_resolves_to_its_files(cell):
     # every number the comparison can yield is named in the traffic file,
     # with its limit or with null (read, not compared)
     limits = loaded["traffic"]["limits"]
-    assert set(limits) == {
-        "grad_gap_median", "grad_gap_quartile", "change_gap_median",
-        "grad_gap_weights", "grad_gap_zero", "grad_gap_worst",
-        "change_gap_worst",
-        "grad_gap_weights_worst", "change_gap_weights_worst"} | {
-        f"loss_gap_{k}" for k in
-        range(1, loaded["traffic"]["check_steps"] + 1)}
+    kind = cb.load_module("kinds", loaded["traffic"]["kind"])
+    assert set(limits) == kind.compared_names(loaded["traffic"])
     assert sum(v is not None for v in limits.values()) >= 3
     # the worst leaf is held, not only a median
     assert limits["grad_gap_worst"] is not None
@@ -133,8 +150,8 @@ def test_resnet50_flops_are_the_published_count():
 def test_vgg16_has_the_published_parameters():
     config = cb.load_json("configs", "vgg16.json")
     ref = cb.load_module("reference", "vgg16")
-    specs = ref.param_specs(config["arch"], 3, config["classes"])
-    elements = sum(int(np.prod(s)) for _, s in specs)
+    specs = _specs(_kind_of("vgg16"), config, ref)
+    elements = sum(int(np.prod(s[1])) for s in specs)
     assert elements == config["trainable_elements"]
     assert abs(elements - 138e6) / 138e6 < 0.01  # arXiv:1409.1556 Table 2
     # 13 convolutions of 15.35 GMAC and three dense layers of 0.12
@@ -145,13 +162,11 @@ def test_vgg16_has_the_published_parameters():
 def test_layer_table_holds_the_reference_weights(config_name):
     config = cb.load_json("configs", config_name + ".json")
     ref = cb.load_module("reference", config_name)
-    specs = ref.param_specs(config["arch"], config["input"]["channels"],
-                            config["classes"])
-    in_specs = sum(int(np.prod(s)) for k, s in specs
-                   if k in ("conv", "dense"))
-    rows = [dict(zip(config["layer_columns"], r)) for r in config["layers"]]
-    in_table = sum(r["count"] * r["cout"] * (r["cin"] // r["groups"])
-                   * r["kernel"] ** 2 for r in rows)
+    specs = _specs(_kind_of(config_name), config, ref)
+    in_specs = sum(int(np.prod(s[1])) for s in specs
+                   if s[0] in ("conv", "dense"))
+    in_table = sum(r["count"] * flops.row_weights(r)
+                   for r in flops.rows(config))
     assert in_specs == in_table
     peak = flops.peaks("TPU v5 lite")
     least, by_flops, by_bytes = flops.step_roofline_s(config, 128, peak)
@@ -249,30 +264,38 @@ def test_run_refuses_to_measure_on_a_cpu(capsys):
 
 
 # ------------------------------------------- references, control, faults
+def _merge(into, overrides):
+    for k, v in overrides.items():
+        if isinstance(v, dict) and isinstance(into.get(k), dict):
+            _merge(into[k], v)
+        else:
+            into[k] = v
+    return into
+
+
+def _tiny_config(config_name):
+    """The configuration under the overrides its own file declares for
+    the CPU (``tiny``: a small image, or small widths, depth, vocabulary
+    and sequence)."""
+    config = cb.load_json("configs", config_name + ".json")
+    return _merge(config, config.get("tiny", {}))
+
+
 def _tiny(cell_name, chips=1, **traffic):
-    """The cell at a size a test run can hold: 32x32 images, 8 rows a
-    chip, float32 compute (so that the program agrees with the reference
-    to rounding and only a fault can fail the cell's own limits)."""
+    """The cell at a size a test run can hold: the configuration's
+    ``tiny``, 8 rows a chip, float32 compute (so that the program agrees
+    with the reference to rounding and only a fault can fail the cell's
+    own limits)."""
     cell = cb.load_cell(cell_name)
     tiny = copy.deepcopy({k: v for k, v in cell.items()
                           if k != "reference"})
     tiny["reference"] = cell["reference"]
-    tiny["config"]["input"].update(height=32, width=32)
-    if "input_side" in tiny["config"]["arch"]:
-        tiny["config"]["arch"]["input_side"] = 32
+    _merge(tiny["config"], tiny["config"].get("tiny", {}))
     tiny["config"]["compute_dtype"] = "float32"
     tiny["traffic"].update(batch_per_chip=8, pool=3, warm_steps=1,
                            span_steps=1, **traffic)
     tiny["chips"] = chips
     return tiny
-
-
-def _tiny_config(config_name):
-    config = cb.load_json("configs", config_name + ".json")
-    config["input"].update(height=32, width=32)
-    if "input_side" in config["arch"]:
-        config["arch"]["input_side"] = 32
-    return config
 
 
 @pytest.mark.parametrize("config_name", CONFIGS)
@@ -286,26 +309,28 @@ def test_reference_agrees_with_the_zoo_forward(config_name):
     from chipbench import weights
     from mxnet_tpu import autograd, parallel
 
-    kind = cb.load_module("kinds", "train_closed")
+    kind = _kind_of(config_name)
     config = _tiny_config(config_name)
     ref = cb.load_module("reference", config_name)
     net = kind.build_net(config, 4)
     params, apply_fn = parallel.functionalize(net, train=True)
     names = list(params)
-    specs = ref.param_specs(config["arch"], 3, config["classes"])
+    specs = _specs(kind, config, ref)
     assert [tuple(params[n].shape) for n in names] == \
-        [tuple(s) for _, s in specs]
+        [tuple(s[1]) for s in specs]
     w = weights.make(specs, 2 ** 31 + 12345)
     params = dict(zip(names, w))
-    x = jax.random.normal(jax.random.key(0), (4, 32, 32, 3), jnp.float32)
+    x, _ = kind.sample_input(config, 4, jax.random.key(0))
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        x = x.astype(jnp.float32)
     with jax.default_matmul_precision("highest"):
         ours = jax.jit(apply_fn)(params, x)
     theirs, moved = jax.jit(
         lambda p, v: ref.forward(p, v, config["arch"]))(w, x)
     scale = float(jnp.max(jnp.abs(theirs)))
     assert float(jnp.max(jnp.abs(ours - theirs))) / scale < 1e-3
-    assert sorted(moved) == [i for i, (k, _) in enumerate(specs)
-                             if k in weights.MOVED_BY_FORWARD]
+    assert sorted(moved) == [i for i, s in enumerate(specs)
+                             if s[0] in weights.MOVED_BY_FORWARD]
     if not moved:
         return
     held = {p.name: p for p in net.collect_params().values()}
@@ -331,13 +356,15 @@ def _state_unchanged(step):
 
 
 def _half_batch(step):
+    import jax
     import jax.numpy as jnp
 
     def broken(p, o, x, y, key, t):
         h = x.shape[0] // 2  # the second half left out, the mean taken
         # over the rest: twice the first half has its statistics and mean
-        return step(p, o, jnp.concatenate([x[:h], x[:h]]),
-                    jnp.concatenate([y[:h], y[:h]]), key, t)
+        xs = jax.device_put(jnp.concatenate([x[:h], x[:h]]), x.sharding)
+        ys = jax.device_put(jnp.concatenate([y[:h], y[:h]]), y.sharding)
+        return step(p, o, xs, ys, key, t)
     return broken
 
 
@@ -361,8 +388,20 @@ def _drive(cell, wrap_step=None):
                       t0=time.perf_counter(), wrap_step=wrap_step)
 
 
-def test_sound_run_is_correct_and_each_fault_is_not():
-    cell = _tiny(CELLS[0])
+def _chips(cell_name):
+    return next(w["chips"] for w in BENCH["workloads"]
+                if w["name"] == cell_name)
+
+
+@pytest.mark.parametrize("cell_name", CELLS)
+def test_sound_run_is_correct_and_each_fault_is_not(cell_name):
+    """Under the cell's own limits and on as many (virtual) devices as it
+    has chips; the exchange left out has the test below."""
+    import jax
+
+    if len(jax.devices()) < _chips(cell_name):
+        pytest.skip("needs as many (virtual) devices as the cell has chips")
+    cell = _tiny(cell_name, chips=_chips(cell_name))
     sound = _drive(cell)
     assert sound["correct"], sound["compared"]
     assert sound["attempted"] > 0 and sound["failed"] == 0
@@ -378,14 +417,54 @@ def test_sound_run_is_correct_and_each_fault_is_not():
             assert got["grad_gap_median"]["value"] > 0.9
 
 
-def test_exchange_left_out_is_not_correct():
+@pytest.mark.parametrize("cell_name", [
+    c for c in CELLS if _chips(c) == 4] or CELLS[:1])
+def test_exchange_left_out_is_not_correct(cell_name):
+    """Under the limits of the benchmark's own four-chip cell; where it
+    has none, under the first cell's with the exchange switched on."""
     import jax
+    from chipbench import refmath
 
     if len(jax.devices()) < 4:
         pytest.skip("needs four (virtual) devices")
-    cell = _tiny(CELLS[0], chips=4, optimizer_sharding="ps")
+    cell = _tiny(cell_name, chips=4, optimizer_sharding="ps")
+    if refmath.optimizer_rule(cell["config"]["optimizer"])[2]:
+        pytest.skip("the sharded exchange keeps its state by bucket, "
+                    "where no leaf's first moment can be read by name")
     assert _drive(cell)["correct"]
     assert not _drive(cell, _no_exchange)["correct"]
+
+
+@pytest.mark.parametrize("cell_name", [
+    c for c in CELLS if _chips(c) == 4] or CELLS[:1])
+def test_readings_give_every_side_the_harness_s_own_verdict(cell_name):
+    """``readings`` (what ``readings.py`` drives on the chip) at a tiny
+    size on four virtual devices: the program is correct under the cell's
+    own limits, the exchange left out and half a batch left out are not,
+    every reading is handed on as it comes, and no seed's program starts
+    after its time is up."""
+    import jax
+    from chipbench import refmath
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four (virtual) devices")
+    cell = _tiny(cell_name, chips=4, optimizer_sharding="ps")
+    if refmath.optimizer_rule(cell["config"]["optimizer"])[2]:
+        pytest.skip("the sharded exchange keeps its state by bucket")
+    kind = cb.load_module("kinds", cell["traffic"]["kind"])
+    flushed = []
+    out = kind.readings(
+        dict(cell, devices=jax.devices(), say=lambda m: None,
+             t0=time.perf_counter()), [11, 12, 13], 1,
+        flush=lambda o: flushed.append(sum(len(v) for v in o.values())),
+        program_until=0.0)
+    assert set(out) == {"names", 11}  # the first seed always runs
+    sides = out[11]["correct"]
+    assert sides["program"], out[11]["program"]
+    assert not sides["fault_no_exchange"], out[11]["fault_no_exchange"]
+    assert not sides["fault_half_batch"], out[11]["fault_half_batch"]
+    assert set(sides) == set(out[11]["leaves"]) - {"reference"}
+    assert len(flushed) == len(sides) and flushed == sorted(flushed)
 
 
 @pytest.mark.parametrize("config_name", [
@@ -426,7 +505,7 @@ def test_lower_precision_control_reads_wider_than_the_reference(cell_name):
     fail is in the tests above: a state left unchanged, half a batch left
     out, the exchange left out, running statistics left unchanged."""
     import jax
-    from chipbench import weights
+    from chipbench import refmath, weights
 
     cell = _tiny(cell_name)
     kind = cb.load_module("kinds", cell["traffic"]["kind"])
@@ -438,6 +517,79 @@ def test_lower_precision_control_reads_wider_than_the_reference(cell_name):
                                   precision="float8")
     values = compare.numbers(control, ref)[0]
     assert set(values) <= set(cell["traffic"]["limits"])
-    for name in ("grad_gap_median", "grad_gap_quartile",
-                 "change_gap_median", "grad_gap_worst"):
+    names = ("grad_gap_median", "grad_gap_quartile", "grad_gap_worst")
+    if refmath.optimizer_rule(cell["config"]["optimizer"])[2] is None:
+        # where the state holds the gradient (Adam) the change's norm is
+        # the rate's whatever the precision (chipbench/compare.py)
+        names += ("change_gap_median",)
+    for name in names:
         assert 1e-3 < values[name] < 0.5, (name, values)
+
+
+# -------------------------------- readers, kernels: whatever the configuration
+@pytest.mark.parametrize("cell_name", CELLS)
+def test_every_reader_reads_a_made_up_run_of_the_cell(cell_name):
+    """Each per-layer reader of the cell, on a run made up from the cell's
+    own configuration and a two-event trace: a number or nothing, never
+    an error (a table of another form, a configuration without a side)."""
+    cell = cb.load_cell(cell_name)
+    ms = 1e-3
+    hlo = "\n".join([
+        "%fused_computation.1 (p: f32[8]) -> f32[8] {",
+        "  ROOT %c = f32[8] convolution(%p, %p), metadata={op_name="
+        '"jit(step)/mx_forward/block0/conv"}',
+        "}",
+        "ENTRY %main (a: f32[8]) -> f32[8] {",
+        "  %fusion.1 = f32[8] fusion(%a), calls=%fused_computation.1",
+        "  %all-reduce.1 = f32[8] all-reduce(%fusion.1), metadata={op_name="
+        '"jit(step)/mx_exchange/psum"}',
+        "}"])
+    ops = [["%fusion.1 = f32[8] fusion(%a), kind=kOutput", 0.0, 40 * ms],
+           ["%all-reduce.1 = f32[8] all-reduce(%fusion.1)", 40 * ms, ms]]
+    trace = trace_reduce.reduce(
+        {"devices": {"/device:TPU:0": {"ops": ops, "async": []}},
+         "host": []}, hlo)
+    trace["steps"] = 1
+    batch = cell["traffic"]["batch_per_chip"] * cell["chips"]
+    spans = np.array([[0.0, 1 * ms]])
+    run = {"config": cell["config"], "traffic": cell["traffic"],
+           "chips": cell["chips"], "batch": batch, "setup_s": 1.0,
+           "peak": flops.peaks("TPU v5 lite"), "trace": trace,
+           "collectives": hlo_collectives.collective_bytes(hlo),
+           "window": {"steps": 10, "images": 10 * batch, "seconds": 1.0,
+                      "done_at": np.arange(10) * 0.1,
+                      "spans": {k: spans for k in
+                                ("feed_wait", "dispatch", "loss_read")},
+                      "feed": {"consumer_wait_s": 0.01}}}
+    got = cb.read_metrics(cell["per_layer"] + cell["end_to_end"], run)
+    assert {"forward_ms.train", "step_mfu.train"} <= set(got) or \
+        "forward_ms.train" not in [m["name"] for m in cell["per_layer"]]
+    assert all(np.isfinite(v["value"]) for v in got.values())
+    if "forward_ms.train" in got:
+        assert got["forward_ms.train"]["value"] == pytest.approx(40.0)
+        assert "backward_ms.train" not in got  # nothing to read: left out
+
+
+def test_every_listed_kernel_is_a_conv_dot_event():
+    """A custom call whose instruction carries a name listed by a file of
+    ``chipbench/kernels/`` (``%<name>.N``, which is how a
+    ``pl.pallas_call(name=...)`` shows on the chip), and one that names
+    it as its ``kernel_name``."""
+    names = sorted(trace_reduce.conv_kernels())
+    assert names
+    for name in names:
+        by_name = (f"  %{name}.3 = f32[8] custom-call(%a), "
+                   'custom_call_target="tpu_custom_call"')
+        by_key = ("  %custom-call.7 = f32[8] custom-call(%a), "
+                  'custom_call_target="tpu_custom_call", backend_config='
+                  f'{{"kernel_name": "{name}"}}')
+        table = trace_reduce.class_table(
+            "\n".join(["ENTRY %main (a: f32[8]) -> f32[8] {", by_name,
+                       by_key, "}"]))
+        assert table[f"{name}.3"] == "conv_dot"
+        assert table["custom-call.7"] == "conv_dot"
+        # the traced event alone, without the compiled text
+        assert trace_reduce.classify(by_name, {}) == "conv_dot"
+    other = ('  %other_kernel.1 = f32[8] custom-call(%a), '
+             'custom_call_target="tpu_custom_call"')
+    assert trace_reduce.classify(other, {}) == "custom_call"
