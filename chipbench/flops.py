@@ -25,9 +25,24 @@ would read over 100%.)  The roofline time of a pass is the larger of
 operations / peak operations per second and bytes / peak bytes per
 second; a layer's is the sum over its two passes.
 
-Work that is no such product (attention's scores, a share of routed
-experts) has no row yet: the configuration that runs one brings its
-form with the cell that runs it (PERF.md, Open questions).
+Work that is no such product (pooling, attention's scores) is a row
+whose work is given outright: ``{"name", "count", "blocks": [...],
+"per_row": {"forward": {"macs", "bytes"}, "backward": {"macs",
+"bytes"}}}``.  ``per_row`` holds the multiply-adds and the least bytes of
+ONE occurrence for ONE row of the batch, counted by whoever writes the
+configuration and held to a hand count by a test of that configuration;
+``blocks`` names the gluon blocks or kernels whose traced events do the
+work (``by_block_s``, ``by_kernel_s`` of ``chipbench/trace_reduce.py``).
+Such a row holds no weights.  :func:`passes` yields it like the others
+and :func:`step_flops` counts its multiply-adds; :func:`step_roofline_s`
+keeps to the products (it is what ``conv_roofline.train`` sets against
+the time of the events that hold a convolution or a dot), and
+:func:`rows_roofline_s` gives the least time of the rows it is asked for,
+of either form.  Work whose amount the data decides (the tokens routed
+to the experts a chip holds) is entered at its expectation, a product
+row with the expected ``positions`` or a ``per_row`` row, and listed under
+the configuration's ``assumed``; a row scaled by a counter of the run
+waits for a program that hands such a counter out of its step.
 """
 import json
 import os
@@ -54,17 +69,34 @@ def rows(config):
             for r in config["layers"]]
 
 
+def is_product(r):
+    """Whether the row ``r`` is a convolution or dense layer, and not one
+    whose work is given outright."""
+    return "per_row" not in r
+
+
 def row_weights(r):
     """Elements of the weights of one occurrence of the row ``r``."""
+    if not is_product(r):
+        return 0
     return r["cout"] * (r["cin"] // r["groups"]) * r["kernel"] ** 2
 
 
-def passes(config, batch):
+def passes(config, batch, keep=lambda r: True):
     """[(layer, pass, count, flops, bytes)] of one step at ``batch`` rows:
-    ``flops`` and ``bytes`` are of ONE occurrence of the layer."""
+    ``flops`` and ``bytes`` are of ONE occurrence of the layer.  ``keep``
+    chooses among the rows."""
     width = _DTYPE_BYTES[config["compute_dtype"]]
     out = []
     for r in rows(config):
+        if not keep(r):
+            continue
+        if not is_product(r):
+            out += [(r["name"], p, r["count"],
+                     2 * batch * r["per_row"][p]["macs"],
+                     batch * r["per_row"][p]["bytes"])
+                    for p in ("forward", "backward")]
+            continue
         if r.get("positions") is not None:
             p_in = p_out = r["positions"]
         else:
@@ -87,15 +119,13 @@ def forward_macs_per_image(config):
 
 def step_flops(config, batch):
     """Model FLOPs of one training step (forward and backward of every
-    convolution and dense layer)."""
+    row of the table)."""
     return sum(c * f for _, _, c, f, _ in passes(config, batch))
 
 
-def step_roofline_s(config, batch, peak):
-    """``(seconds, seconds bound by operations, seconds bound by bytes)``:
-    the least time the chip could take for the step's passes."""
+def _roofline_s(some_passes, peak):
     by_flops = by_bytes = 0.0
-    for _, _, c, f, b in passes(config, batch):
+    for _, _, c, f, b in some_passes:
         tf = f / peak["bf16_flops_per_s"]
         tb = b / peak["hbm_bytes_per_s"]
         if tf >= tb:
@@ -103,3 +133,16 @@ def step_roofline_s(config, batch, peak):
         else:
             by_bytes += c * tb
     return by_flops + by_bytes, by_flops, by_bytes
+
+
+def step_roofline_s(config, batch, peak):
+    """``(seconds, seconds bound by operations, seconds bound by bytes)``:
+    the least time the chip could take for the passes of the step's
+    convolutions and dense layers."""
+    return _roofline_s(passes(config, batch, is_product), peak)
+
+
+def rows_roofline_s(config, batch, peak, names):
+    """The same three for the rows called ``names``, of either form."""
+    return _roofline_s(
+        passes(config, batch, lambda r: r["name"] in names), peak)

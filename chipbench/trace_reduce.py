@@ -39,11 +39,29 @@ its body's root.  The phases add up to the operations' seconds; a
 phase's time per step is read from here (``forward_ms.train``,
 ``backward_ms.train``).  XLA fuses across scopes (a weight's update rides
 in the fusion that makes its gradient), so a phase holds what rides with
-it.  :func:`phase_of` and :func:`phase_table` are the benchmark's own
-copy, on purpose (the yardstick lies where the program's PRs cannot move
-it), of the part of ``mxnet_tpu/profiler.py::_phase_and_block`` and
-``_scope_table`` that this takes; ``tests/chipbench/test_units.py`` holds
-the two to the same table on the program's own recording.
+it.  ``by_block_s`` splits every phase by block, ``{phase: {block:
+seconds}}``: within forward and backward the innermost scope after the
+phase's that is no wrapper (``jvp(``, ``transpose(``, ``jit(``) and not
+the operation itself is the gluon block, which the program opens under
+the block's own ``name``; an event that names none goes under ``""``, so
+a phase's blocks add up to the phase (:func:`block_seconds` reads it;
+``pool_roofline.train``).  ``by_phase_class_s`` splits every phase by
+class in the same form: XLA rewrites collectives and drops their scope
+as it does (on four chips the gradients' combined ``all-reduce`` carries
+no ``op_name``), so ``exchange_ms.train`` takes the collectives of every
+phase with what else ``mx_exchange`` holds.  :func:`phase_and_block` and
+:func:`phase_table` are the benchmark's own copy, on purpose (the
+yardstick lies where the program's PRs cannot move it), of
+``mxnet_tpu/profiler.py::_phase_and_block`` and ``_scope_table``;
+``tests/chipbench/test_units.py`` holds the two to the same table on the
+program's own recording.
+
+By kernel.  ``by_kernel_s`` holds the seconds of every ``custom-call``
+event of the busiest device under the instruction's own name without its
+number (``%flash_attention_fwd.3`` -> ``flash_attention_fwd``; XLA's own,
+such as ``%custom-call.9``, go under ``custom-call``), whether or not a
+file of ``kernels/`` lists it: a metric that reads one kernel's time
+reads it from here.
 """
 import functools
 import glob
@@ -73,6 +91,7 @@ _ROOT = re.compile(r"^\s*ROOT\s")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _PHASE_PART = re.compile(r"mx_(forward|loss|guard|exchange|optimizer)\b")
 _TARGET = re.compile(r'custom_call_target="([^"]+)"')
+_NUMBER = re.compile(r"\.\d+$")
 _KERNEL = re.compile(r'kernel_name["\\:= ]+([\w.\-]+)')
 
 
@@ -125,6 +144,12 @@ def load_events(path):
 def op_name(event_name):
     m = _NAME.match(event_name)
     return m.group(1) if m else event_name.strip().lstrip("%")
+
+
+def unnumbered(name):
+    """An instruction's name without its number: ``bnreluconv_bwd.1`` ->
+    ``bnreluconv_bwd``, which is a ``pl.pallas_call``'s ``name=``."""
+    return _NUMBER.sub("", name)
 
 
 def opcode(instruction):
@@ -195,7 +220,7 @@ def _class_of(line, code, bodies, kernels):
         return "conv_dot" if m and bodies.get(m.group(1)) else "other"
     if base == "custom-call":
         names = _TARGET.findall(line) + _KERNEL.findall(line) \
-            + [re.sub(r"\.\d+$", "", op_name(line))]
+            + [unnumbered(op_name(line))]
         return "conv_dot" if kernels.intersection(names) else "custom_call"
     return "other"
 
@@ -211,19 +236,32 @@ def classify(event_name, table):
 
 # ----------------------------------------------------------------- phases
 @functools.lru_cache(maxsize=None)
-def phase_of(scope):
-    """The phase of an ``op_name``: its innermost ``mx_*`` scope (the
-    transpose of forward or loss is backward); ``unscoped`` where it
-    holds none."""
-    phase = "unscoped"
-    for part in scope.split(";")[0].split("/"):
+def phase_and_block(scope):
+    """``(phase, block)`` of an ``op_name``.  The phase is its innermost
+    ``mx_*`` scope (the transpose of forward or loss is backward),
+    ``unscoped`` where it holds none.  Within forward and backward the
+    block is the innermost part after the phase's that is neither a
+    wrapper (it holds a ``(``) nor the last part, the operation itself;
+    ``""`` where there is none."""
+    parts = [q for q in scope.split(";")[0].split("/") if q]
+    phase, at = "unscoped", None
+    for i, part in enumerate(parts):
         m = _PHASE_PART.search(part)
         if m:
-            phase = m.group(1)
+            phase, at = m.group(1), i
             if part.startswith("transpose(") and phase in ("forward",
                                                            "loss"):
                 phase = "backward"
-    return phase
+    block = ""
+    if phase in ("forward", "backward"):
+        inner = [q for q in parts[at + 1:-1] if "(" not in q]
+        block = inner[-1] if inner else ""
+    return phase, block
+
+
+def phase_of(scope):
+    """The phase alone of an ``op_name``."""
+    return phase_and_block(scope)[0]
 
 
 def phase_table(hlo_text):
@@ -254,10 +292,18 @@ def phase_table(hlo_text):
     return table
 
 
-def _phase_of_event(event_name, scopes):
+def _phase_and_block_of_event(event_name, scopes):
     in_text = _OP_NAME.search(event_name)
-    return phase_of(in_text.group(1) if in_text
-                    else scopes.get(op_name(event_name), ""))
+    return phase_and_block(in_text.group(1) if in_text
+                           else scopes.get(op_name(event_name), ""))
+
+
+def block_seconds(by_block_s, blocks, phases=None):
+    """Seconds of the ``blocks`` in ``by_block_s``, over ``phases`` or
+    over all of them."""
+    return sum(seconds for phase, of_phase in by_block_s.items()
+               if phases is None or phase in phases
+               for block, seconds in of_phase.items() if block in blocks)
 
 
 def union(intervals):
@@ -298,8 +344,9 @@ def reduce(events, hlo_text=""):
     the devices: seconds busy (the union of the ``XLA Ops`` events), the
     window (first operation's start to the last one's end), seconds by
     class, and the collective seconds during which no operation of
-    another class ran on that device.  ``device_ops``, ``idle_gaps`` and
-    ``by_phase_s`` are of the busiest device."""
+    another class ran on that device.  ``device_ops``, ``idle_gaps``,
+    ``by_phase_s``, ``by_block_s``, ``by_phase_class_s`` and ``by_kernel_s``
+    are of the busiest device."""
     table = class_table(hlo_text) if hlo_text else {}
     scopes = phase_table(hlo_text) if hlo_text else {}
     host = [(n, s, s + d) for n, s, d in events["host"]
@@ -312,13 +359,21 @@ def reduce(events, hlo_text=""):
                   for n, s, d in lines["async"]]
         busy = union((s, e) for _, s, e, _ in ops)
         window = (busy[0][0], busy[-1][1])
-        by_class, by_op, by_phase = {}, {}, {}
+        by_class, by_op, by_phase, by_kernel = {}, {}, {}, {}
+        by_block, by_phase_class = {}, {}
         for n, s, e, c in ops:
             by_class[c] = by_class.get(c, 0.0) + (e - s)
-            key = f"{c}:{op_name(n)}"
+            name = op_name(n)
+            key = f"{c}:{name}"
             by_op[key] = by_op.get(key, 0.0) + (e - s)
-            phase = _phase_of_event(n, scopes)
+            phase, block = _phase_and_block_of_event(n, scopes)
             by_phase[phase] = by_phase.get(phase, 0.0) + (e - s)
+            for split, key in ((by_block, block), (by_phase_class, c)):
+                of_phase = split.setdefault(phase, {})
+                of_phase[key] = of_phase.get(key, 0.0) + (e - s)
+            if _base(opcode(n)) == "custom-call":
+                kernel = unnumbered(name)
+                by_kernel[kernel] = by_kernel.get(kernel, 0.0) + (e - s)
         coll = union((s, e) for _, s, e, c in ops + asyncs
                      if c == "collective")
         rest = union((s, e) for _, s, e, c in ops if c != "collective")
@@ -326,7 +381,8 @@ def reduce(events, hlo_text=""):
         per_device[plane] = {
             "busy_s": length(busy), "window_s": window[1] - window[0],
             "by_class_s": by_class, "by_op_s": by_op,
-            "by_phase_s": by_phase,
+            "by_phase_s": by_phase, "by_block_s": by_block,
+            "by_phase_class_s": by_phase_class, "by_kernel_s": by_kernel,
             "collective_s": length(coll),
             "collective_exposed_s": length(subtract(coll, rest)),
             "gaps": gaps,
@@ -351,6 +407,9 @@ def reduce(events, hlo_text=""):
         "collective_exposed_s": sum(d["collective_exposed_s"]
                                     for d in per_device.values()) / n,
         "by_phase_s": busiest["by_phase_s"],
+        "by_block_s": busiest["by_block_s"],
+        "by_phase_class_s": busiest["by_phase_class_s"],
+        "by_kernel_s": busiest["by_kernel_s"],
         "device_ops": _top_ops(busiest),
         "idle_gaps": _gaps_by_host_span(busiest["gaps"], host),
     }

@@ -1,9 +1,10 @@
 """The yardstick's arithmetic by hand: a layer table's rows over images
-and over a sequence, weights and batches from the seed unchanged to the
-bit, the first gradient handed in against the one derived, the plain
-AdamW rule against the program's, seconds by phase on the program's own
-recorded trace (and the benchmark's reading of a scope held to the
-program's), and the host annotations the loader keeps.  CPU only."""
+and over a sequence and rows whose work is given outright, weights and
+batches from the seed unchanged to the bit, the first gradient handed in against the one derived, the plain
+AdamW rule against the program's, seconds by phase, by block and by
+kernel on the recorded traces (and the benchmark's reading of a scope
+held to the program's), the readers of blocks and of the exchange, and
+the host annotations the loader keeps.  CPU only."""
 import gzip
 import hashlib
 import json
@@ -41,8 +42,9 @@ def test_conv_row_over_a_sequence():
     x, y, w = 3 * 12 * 16 * 2, 3 * 12 * 32 * 2, 32 * 16 * 2
     assert got["forward"] == (2 * macs, x + w + y)
     assert got["backward"] == (4 * macs, x + y + w + w + x)
+    # the look-up's row, given outright, holds no weights
     assert [flops.row_weights(r) for r in flops.rows(TOKWIT)] == [
-        16 * 32, 32 * 64]
+        16 * 32, 32 * 64, 0]
     assert flops.step_flops(TOKWIT, 3) == 6 * 3 * 12 * (16 * 32 + 32 * 64)
     least, by_flops, by_bytes = flops.step_roofline_s(TOKWIT, 3, PEAK)
     assert least == pytest.approx(by_flops + by_bytes) and least > 0
@@ -62,11 +64,90 @@ def test_conv_rows_read_as_they_did(name, macs, step_flops, step_bytes,
     config = cb.load_json("configs", name + ".json")
     assert flops.forward_macs_per_image(config) == macs
     assert flops.step_flops(config, 64) == step_flops
-    assert sum(c * b for _, _, c, _, b in flops.passes(config, 64)) \
-        == step_bytes
+    assert sum(c * b for _, _, c, _, b in flops.passes(
+        config, 64, flops.is_product)) == step_bytes
     assert flops.step_roofline_s(config, 64, PEAK)[0] == least
     if name == "resnet50_v1":
         assert abs(macs - 3.8e9) / 3.8e9 < 0.03  # arXiv:1512.03385
+
+
+# ------------------------------------------- rows whose work is given outright
+VGG16 = cb.load_json("configs", "vgg16.json")
+_GIVEN = {"name": "scores", "count": 3, "blocks": ["attn0"],
+          "per_row": {"forward": {"macs": 1000, "bytes": 600},
+                      "backward": {"macs": 2000, "bytes": 300}}}
+
+
+def test_row_given_outright_in_passes_flops_and_rooflines():
+    """``per_row`` is of one occurrence and one row of the batch: times
+    the batch in ``passes``, times ``count`` in the sums; ``step_flops``
+    counts its multiply-adds, ``step_roofline_s`` keeps to the products,
+    ``rows_roofline_s`` takes the rows it is asked for, of either form."""
+    config = dict(TOKWIT, layers=[r for r in TOKWIT["layers"]
+                                  if flops.is_product(r)])
+    both = dict(config, layers=config["layers"] + [_GIVEN])
+    assert _passes(both, "scores", 5) == {"forward": (2 * 5 * 1000, 5 * 600),
+                                          "backward": (2 * 5 * 2000, 5 * 300)}
+    assert flops.passes(both, 5)[:-2] == flops.passes(config, 5)
+    assert flops.passes(both, 5, flops.is_product) == flops.passes(config, 5)
+    assert flops.row_weights(_GIVEN) == 0
+    assert flops.step_flops(both, 5) == flops.step_flops(config, 5) \
+        + 3 * 2 * 5 * 3000
+    assert flops.step_roofline_s(both, 5, PEAK) \
+        == flops.step_roofline_s(config, 5, PEAK)
+    # at 10 FLOP a byte, bytes bind the forward pass (2000 FLOP, 600 B
+    # a row) and operations the backward pass (4000 FLOP, 300 B)
+    peak = {"bf16_flops_per_s": 1e3, "hbm_bytes_per_s": 1e2}
+    least, by_flops, by_bytes = flops.rows_roofline_s(both, 5, peak,
+                                                      {"scores"})
+    assert by_flops == pytest.approx(3 * 2 * 5 * 2000 / 1e3)  # backward
+    assert by_bytes == pytest.approx(3 * 5 * 600 / 1e2)  # forward
+    assert least == pytest.approx(by_flops + by_bytes)
+    products = {r["name"] for r in flops.rows(config)}
+    assert flops.rows_roofline_s(both, 5, PEAK, products) \
+        == flops.step_roofline_s(config, 5, PEAK)
+    assert flops.rows_roofline_s(both, 5, PEAK, set()) == (0.0, 0.0, 0.0)
+
+
+def test_vgg16_pooling_rows_are_the_hand_count_from_arch():
+    """Five max-poolings of 2x2, stride 2, one after each stage: no
+    multiply-adds; forward reads the map and writes a quarter of it,
+    backward reads the map and the quarter's gradient and writes the
+    map's gradient; bf16."""
+    arch = VGG16["arch"]
+    assert arch["pool"] == {"kernel": 2, "stride": 2}
+    side, expected = arch["input_side"], []
+    for i, stage in enumerate(arch["stages"]):
+        whole = side * side * stage["channels"]
+        quarter = (side // 2) ** 2 * stage["channels"]
+        expected.append({
+            "name": f"pool{i + 1}", "count": 1, "blocks": [f"vgg0_pool{i}"],
+            "per_row": {
+                "forward": {"macs": 0, "bytes": 2 * (whole + quarter)},
+                "backward": {"macs": 0,
+                             "bytes": 2 * (whole + quarter + whole)}}})
+        side //= 2
+    given = [r for r in flops.rows(VGG16) if not flops.is_product(r)]
+    assert given == expected
+    names = {r["name"] for r in given}
+    step_bytes = sum(c * b for _, _, c, _, b in flops.passes(
+        VGG16, 64, lambda r: r["name"] in names))
+    assert step_bytes == 2742419456  # 2.74 GB a step at batch 64
+    least, by_flops, by_bytes = flops.rows_roofline_s(VGG16, 64, PEAK, names)
+    assert by_flops == 0.0 and least == by_bytes
+    assert least == pytest.approx(step_bytes / 819e9)  # 3.35 ms
+    assert flops.rows_roofline_s(VGG16, 64, PEAK, {"pool1"})[0] \
+        == pytest.approx(1.7566e-3, rel=1e-4)
+
+
+def test_a_bank_of_experts_is_a_2d_dense_leaf():
+    """``weights._draw`` takes fan-in from all axes but the first: a bank
+    kept as ``(members x out, in)`` is drawn with variance 1 / in, one
+    stacked ``(members, out, in)`` ``out`` times too small."""
+    flat, stacked = (np.asarray(a) for a in weights.make(
+        [("dense", (8 * 64, 32)), ("dense", (8, 64, 32))], 7))
+    assert flat.std() == pytest.approx(32 ** -0.5, rel=0.02)
+    assert stacked.std() == pytest.approx((64 * 32) ** -0.5, rel=0.02)
 
 
 # ------------------------------------------------ weights and batches, seeded
@@ -192,9 +273,10 @@ def test_seconds_by_phase_on_the_programs_recorded_trace():
 
 
 def test_the_benchmarks_reading_of_a_scope_is_the_programs():
-    """``phase_of`` and ``phase_table`` are a copy, on purpose, of part of
-    ``mxnet_tpu/profiler.py``: over every instruction of the recorded
-    step they give what ``_scope_table`` and ``_phase_and_block`` give."""
+    """``phase_and_block`` and ``phase_table`` are a copy, on purpose, of
+    ``mxnet_tpu/profiler.py``'s: over every instruction of the recorded
+    step they give what ``_scope_table`` and ``_phase_and_block`` give
+    (the program says ``None`` where an operation names no block)."""
     from mxnet_tpu import profiler
 
     rec, _ = _recording()
@@ -203,11 +285,192 @@ def test_the_benchmarks_reading_of_a_scope_is_the_programs():
     assert ours == {name: scope for name, (scope, _) in theirs.items()}
     scopes = set(ours.values())
     assert len(scopes) > 100
+    blocks = set()
     for scope in scopes:
-        assert trace_reduce.phase_of(scope) == \
-            profiler._phase_and_block(scope)[0], scope
+        phase, block = profiler._phase_and_block(scope)
+        assert trace_reduce.phase_and_block(scope) == (phase, block or ""), \
+            scope
+        assert trace_reduce.phase_of(scope) == phase
+        blocks.add(block)
     assert {trace_reduce.phase_of(s) for s in scopes} >= {
         "forward", "backward", "loss", "optimizer", "unscoped"}
+    # convolutions, activations, poolings, dense layers; and none
+    assert len(blocks) > 30 and None in blocks
+    assert {f"vgg0_pool{i}" for i in range(5)} <= blocks
+
+
+def _resnet_recording():
+    rec = trace_reduce.load_events(os.path.join(
+        ROOT, "chipbench", "testdata", "resnet50_train_2steps.json.gz"))
+    return rec["events"], rec["hlo_text"]
+
+
+def _vgg_recording():
+    rec, events = _recording()
+    return events, rec["hlo_text"]
+
+
+#: what the parent of the PR that brought ``by_block_s`` and
+#: ``by_kernel_s`` read on the two recordings, to the last digit
+_AS_BEFORE = {
+    "vgg16": (_vgg_recording, {
+        "by_class_s": {"conv_dot": 0.097469616,
+                       "copy": 0.0022283309999992715,
+                       "custom_call": 4.399999997684034e-08,
+                       "other": 0.017739172999999067},
+        "by_phase_s": {"unscoped": 0.002555498999998372,
+                       "loss": 5.24500000002176e-06,
+                       "backward": 0.07966058900000003,
+                       "forward": 0.03520848099999995,
+                       "optimizer": 7.349999999933798e-06},
+        "busy_s": 0.11743716399999826, "window_s": 0.11744501700000001,
+        "collective_exposed_s": 0.0,
+        "idle_gaps": [["between_spans", 5.151999999994383e-06],
+                      ["under_2us_between_ops", 2.7010000017424485e-06]]}),
+    "resnet50": (_resnet_recording, {
+        "by_class_s": {"conv_dot": 0.07093073499999988,
+                       "copy": 0.005631808000017058,
+                       "custom_call": 1.2900000154192348e-07,
+                       "other": 0.017251731000009124},
+        "by_phase_s": {"unscoped": 0.09381440300002761},
+        "busy_s": 0.09381440300002139, "window_s": 0.09385084200000002,
+        "collective_exposed_s": 0.0,
+        "idle_gaps": [["cb_loss_read", 2.608300000001007e-05],
+                      ["under_2us_between_ops", 1.0355999978617358e-05]]}),
+}
+
+
+@pytest.mark.parametrize("recording", sorted(_AS_BEFORE))
+def test_blocks_add_up_to_their_phase_and_the_rest_reads_as_before(
+        recording):
+    load, before = _AS_BEFORE[recording]
+    r = trace_reduce.reduce(*load())
+    for key, value in before.items():
+        assert r[key] == value, key
+    assert r["device_ops"][0] == ["class:conv_dot",
+                                  before["by_class_s"]["conv_dot"]]
+    assert set(r["by_block_s"]) == set(r["by_phase_s"])
+    for phase, seconds in r["by_phase_s"].items():
+        for split in ("by_block_s", "by_phase_class_s"):
+            assert sum(r[split][phase].values()) == pytest.approx(
+                seconds, rel=1e-12)
+    for c, seconds in r["by_class_s"].items():  # one device: no average
+        assert sum(d.get(c, 0.0) for d in r["by_phase_class_s"].values()) \
+            == pytest.approx(seconds, rel=1e-12)
+    # a phase that is no pass names no block
+    for phase in set(r["by_block_s"]) - {"forward", "backward"}:
+        assert set(r["by_block_s"][phase]) == {""}
+
+
+def test_seconds_by_block_on_the_programs_recorded_trace():
+    """What the program's own reader gave on the uncut trace (its ten
+    longest), and pooling as ``pool_roofline.train`` reads it: the
+    unpaired step of PR 25, 5.77 ms a step where 3.35 would do."""
+    rec, events = _recording()
+    r = trace_reduce.reduce(events, rec["hlo_text"])
+    assert len(rec["known"]["blocks"]) == 10
+    for known in rec["known"]["blocks"]:
+        assert r["by_block_s"][known["phase"]][known["block"]] == \
+            pytest.approx(known["seconds"], rel=1e-9)
+    pools = {f"vgg0_pool{i}" for i in range(5)}
+    both = trace_reduce.block_seconds(r["by_block_s"], pools)
+    forward = trace_reduce.block_seconds(r["by_block_s"], pools,
+                                         ("forward",))
+    assert forward == pytest.approx(2 * 1.8026e-3, rel=1e-3)
+    assert both - forward == pytest.approx(2 * 3.9654e-3, rel=1e-3)
+    assert trace_reduce.block_seconds(r["by_block_s"], {"no_such"}) == 0
+    run = {"trace": dict(r, steps=2), "config": VGG16, "batch": 64,
+           "chips": 1, "peak": PEAK}
+    share = cb.load_module("metrics", "pool_roofline.train").read(run)
+    assert share == pytest.approx(100 * 3.3485e-3 / 5.7680e-3, rel=1e-3)
+    # on four chips a chip pools a quarter of the step's batch
+    assert cb.load_module("metrics", "pool_roofline.train").read(
+        dict(run, batch=256, chips=4)) == pytest.approx(share)
+
+
+_KERNEL_HLO = "\n".join([
+    "ENTRY %main (a: f32[8]) -> f32[8] {",
+    '  %flash_attention_fwd.3 = f32[8] custom-call(%a), '
+    'custom_call_target="tpu_custom_call", metadata={op_name='
+    '"jit(step)/mx_forward/net0_attn0/pallas_call"}',
+    '  %flash_attention_fwd.7 = f32[8] custom-call(%a), '
+    'custom_call_target="tpu_custom_call", metadata={op_name='
+    '"jit(step)/mx_forward/net0_attn1/pallas_call"}',
+    '  %custom-call.9 = f32[8] custom-call(%a), '
+    'custom_call_target="ConcatBitcast"',
+    '  %all-reduce.1 = f32[8] all-reduce(%a), metadata={op_name='
+    '"jit(step)/mx_exchange/psum"}',
+    # a collective that XLA rewrote and left without its scope
+    "  %all-reduce.2 = f32[8] all-reduce(%a)",
+    '  %reshape.5 = f32[8] reshape(%all-reduce.2), metadata={op_name='
+    '"jit(step)/mx_exchange/reshape"}',
+    "}"])
+
+
+def _kernel_trace():
+    ms = 1e-3
+    ops = [['%flash_attention_fwd.3 = f32[8] custom-call(%a), '
+            'custom_call_target="tpu_custom_call"', 0.0, 2 * ms],
+           ['%flash_attention_fwd.7 = f32[8] custom-call(%a), '
+            'custom_call_target="tpu_custom_call"', 2 * ms, 3 * ms],
+           ['%custom-call.9 = f32[8] custom-call(%a), '
+            'custom_call_target="ConcatBitcast"', 5 * ms, 1 * ms],
+           ["%all-reduce.1 = f32[8] all-reduce(%a)", 6 * ms, 4 * ms],
+           ["%all-reduce.2 = f32[8] all-reduce(%a)", 10 * ms, 2 * ms],
+           ["%reshape.5 = f32[8] reshape(%all-reduce.2)", 12 * ms, 1 * ms]]
+    return trace_reduce.reduce(
+        {"devices": {"/device:TPU:0": {"ops": ops, "async": []}},
+         "host": []}, _KERNEL_HLO)
+
+
+def test_seconds_by_kernel():
+    """Every ``custom-call`` event under its instruction's own name
+    without the number, listed by ``kernels/`` or not; the recordings
+    hold XLA's own (``%custom-call.N``) and no kernel."""
+    r = _kernel_trace()
+    assert r["by_kernel_s"] == {
+        "flash_attention_fwd": pytest.approx(5e-3),
+        "custom-call": pytest.approx(1e-3)}
+    assert "flash_attention_fwd" not in trace_reduce.conv_kernels()
+    assert r["by_block_s"]["forward"] == {
+        "net0_attn0": pytest.approx(2e-3), "net0_attn1": pytest.approx(3e-3)}
+    assert r["by_block_s"]["unscoped"] == {"": pytest.approx(3e-3)}
+    assert r["by_phase_class_s"] == {
+        "forward": {"custom_call": pytest.approx(5e-3)},
+        "unscoped": {"custom_call": pytest.approx(1e-3),
+                     "collective": pytest.approx(2e-3)},
+        "exchange": {"collective": pytest.approx(4e-3),
+                     "other": pytest.approx(1e-3)}}
+    for load, before in _AS_BEFORE.values():
+        events, text = load()
+        n = sum("custom-call(" in e[0]
+                for d in events["devices"].values() for e in d["ops"])
+        assert n > 100
+        assert trace_reduce.reduce(events, text)["by_kernel_s"] == {
+            "custom-call": before["by_class_s"]["custom_call"]}
+
+
+@pytest.mark.parametrize("name,per_step", [
+    ("exchange_ms.train", 3.5), ("pool_roofline.train", None)])
+def test_new_readers_on_a_trace_with_and_without_their_events(name,
+                                                              per_step):
+    read = cb.load_module("metrics", name).read
+    run = {"trace": dict(_kernel_trace(), steps=2), "config": VGG16,
+           "batch": 64, "chips": 1, "peak": PEAK}
+    # in 2 steps 4 ms of a collective under the exchange, 2 ms of one
+    # that lost its scope and 1 ms of the exchange's own reshape (the
+    # phase alone holds 5 ms); no block of a pooling row
+    assert run["trace"]["by_phase_s"]["exchange"] == pytest.approx(5e-3)
+    assert read(run) == (None if per_step is None
+                         else pytest.approx(per_step))
+    events, text = _vgg_recording()  # one chip: no exchange, but pooling
+    there = dict(run, trace=dict(trace_reduce.reduce(events, text), steps=2))
+    assert (read(there) is None) == (name == "exchange_ms.train")
+    assert read(dict(run, trace=None)) is None
+    # a configuration with no pooling row
+    assert read(dict(there, config=dict(VGG16, layers=[
+        r for r in VGG16["layers"] if not isinstance(r, dict)]))) is None \
+        or name == "exchange_ms.train"
 
 
 def test_phase_of_an_op_name():
@@ -219,6 +482,16 @@ def test_phase_of_an_op_name():
     assert ph("jit(step)/transpose(jvp(mx_loss))/sub") == "backward"
     assert ph("jit(step)/convert") == "unscoped"
     assert ph("") == "unscoped"
+    pb = trace_reduce.phase_and_block
+    assert pb("jit(step)/mx_forward/vgg0_conv2d1/conv") == (
+        "forward", "vgg0_conv2d1")
+    assert pb("jit(step)/transpose(jvp(mx_forward))//vgg0_pool4/ge") == (
+        "backward", "vgg0_pool4")
+    # the innermost block; a wrapper between is none
+    assert pb("jit(step)/mx_forward/net0/net0_attn0/jit(relu)/max") == (
+        "forward", "net0_attn0")
+    assert pb("jit(step)/mx_forward/add") == ("forward", "")
+    assert pb("jit(step)/mx_optimizer/bucket0/mul") == ("optimizer", "")
 
 
 def test_loader_keeps_the_benchmarks_host_spans(tmp_path):

@@ -5,7 +5,12 @@ for a token model: a configuration whose input is token ids and whose
 optimizer is AdamW, whose table's rows run over a sequence, its plain
 reference, its traffic file, a kernels file with one
 made-up name, the function that builds its net from the zoo's layers,
-and a ``BENCHMARK.json`` that names them.  The test copies ``chipbench/``
+a row whose work is given outright (the look-up of the embeddings, no
+product), a metric that reads the time of one of its blocks and one that
+reads the time of its kernel, a recorded trace and the checks of its
+own that run the two over it (``tokwit_checks.py``), and a
+``BENCHMARK.json`` that names them.  Its bank-shaped leaf, the hidden
+layer's weights, is a 2-D ``dense`` leaf.  The test copies ``chipbench/``
 and the suite to a temporary directory, lays the witness's files beside
 them, edits none (every copied file's hash is compared afterwards), and
 runs the suite there: what it does by hand is what such a PR does in the
@@ -35,6 +40,8 @@ REQUIRED = (
     "test_every_reader_reads_a_made_up_run_of_the_cell[tokwit_train]",
     "test_every_listed_kernel_is_a_conv_dot_event",
     "test_run_refuses_to_measure_on_a_cpu",
+    "test_embedding_row_is_the_hand_count",
+    "test_readers_of_a_block_and_of_a_kernel_read_the_recorded_trace",
 )
 
 
@@ -69,8 +76,11 @@ def test_a_token_configuration_is_added_by_files_alone(tmp_path):
     with open(copy / "BENCHMARK.json") as f:
         witness = json.load(f)
     assert witness["end_to_end"] == bench["end_to_end"]
-    assert [m for m in bench["per_layer"] if "workloads" not in m] == \
-        witness["per_layer"]
+    everywhere = [m for m in bench["per_layer"] if "workloads" not in m]
+    assert witness["per_layer"][:len(everywhere)] == everywhere
+    # and its own: the time of a block and of a kernel, in its cell alone
+    assert [m["workloads"] for m in witness["per_layer"][len(everywhere):]] \
+        == [["tokwit_train"]] * 2
 
     # the suite's cases for the witness, not those of the tree's own
     # configurations (their files are in the copy too)
@@ -81,8 +91,8 @@ def test_a_token_configuration_is_added_by_files_alone(tmp_path):
     env.pop("XLA_FLAGS", None)  # one device: the witness has one chip
     done = subprocess.run(
         [sys.executable, "-m", "pytest", "tests/chipbench/test_chipbench.py",
-         "-v", "-p", "no:cacheprovider", "-p", "no:xdist", "-p",
-         "no:randomly", "-k", others],
+         "tests/chipbench/tokwit_checks.py", "-v", "-p", "no:cacheprovider",
+         "-p", "no:xdist", "-p", "no:randomly", "-k", others],
         cwd=copy, env=env, capture_output=True, text=True, timeout=600)
     tail = done.stdout[-6000:] + done.stderr[-2000:]
     assert done.returncode == 0, tail
