@@ -314,6 +314,10 @@ def bucket_update(opt, w, g, state, t, *, seg=None, axis_name=None,
     if seg is not None:
         nseg = int(seg[1])
     reason = supported(opt, w.dtype, nseg=nseg)
+    if reason is None and w.ndim != 1:
+        # rows of a leaf-shaped bucket (parallel.zero): flattening them
+        # for the kernel would lay the shard out anew each way
+        reason = f"the kernels stream 1-D shards, not {w.ndim}-D rows"
     if reason is not None:
         kernel_target.declined("fused_bucket_opt", reason, "jnp",
                                shape=tuple(w.shape))

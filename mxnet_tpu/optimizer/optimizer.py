@@ -906,13 +906,17 @@ def _lars_step(w, mom, g, lr, wd, momentum, eta, eps):
 
 def _lars_bucket_step(w, mom, g, seg_ids, lr, wd, momentum, eta, eps,
                       num_segments, axis_name=None):
-    """LARS over one flat bucket shard: per-PARAMETER trust ratios from
+    """LARS over one bucket shard: per-PARAMETER trust ratios from
     segment-summed squared norms, psum'd over the shard axis when a
     parameter spans shards (the multi_lars/multi_sum_sq pipeline,
-    src/operator/contrib/multi_lars.cc, applied to the ZeRO layout)."""
-    w_ss = jax.ops.segment_sum(w * w, seg_ids,
+    src/operator/contrib/multi_lars.cc, applied to the ZeRO layout).
+    The shard is 1-D, or rows of a leaf-shaped bucket's one leaf
+    (``seg_ids`` of the same shape; summed in row-major order, which
+    is the flat shard's)."""
+    flat_ids = seg_ids.reshape(-1)
+    w_ss = jax.ops.segment_sum((w * w).reshape(-1), flat_ids,
                                num_segments=num_segments)
-    g_ss = jax.ops.segment_sum(g * g, seg_ids,
+    g_ss = jax.ops.segment_sum((g * g).reshape(-1), flat_ids,
                                num_segments=num_segments)
     if axis_name is not None:
         with jax.named_scope("mx_exchange"):

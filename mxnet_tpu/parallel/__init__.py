@@ -249,43 +249,64 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
     optimizer_sharding="ps": the sharded-server gradient exchange
     (ZeRO-1 ≡ the reference's key-sharded servers running the
     server-side optimizer, kvstore_dist_server.h:346, see
-    parallel.zero).  Gradients flatten into dtype-homogeneous flat
-    buckets (split threshold: ``bucket_bound`` elements, default the
-    authentic ``MXNET_KVSTORE_BIGARRAY_BOUND``), each bucket
-    ``reduce_scatter``s over the data axis, the optimizer's fused rule
-    updates ONLY the locally-owned shard (optimizer state is created,
-    donated and persisted SHARDED — per-chip state bytes ~ params/N),
-    and the updated param buckets ``all_gather`` back — ~2·buckets
-    collectives per step instead of one all-reduce per parameter
-    tensor.  ``None`` follows MXNET_OPTIMIZER_SHARDING ('ps' arms it,
-    '0' force-disables, empty leaves it off); needs a mesh and does
-    not compose with ``param_spec`` (tp) yet.  Dynamic loss scaling
-    checks finiteness on the SCATTERED shard and psums the verdict;
-    the nan-guard and donation contracts are unchanged; under the
-    forward each device sees its local batch shard, so BatchNorm uses
-    per-shard statistics — the reference DataParallel semantics
-    (executor_group.py), vs the replicated path's SyncBatchNorm-style
-    global stats.
+    parallel.zero).  Gradients go into dtype-homogeneous buckets
+    (split threshold: ``bucket_bound`` elements, default the authentic
+    ``MXNET_KVSTORE_BIGARRAY_BOUND``), each bucket is summed and
+    scattered over the data axis (``psum_scatter``), the optimizer's
+    fused rule updates ONLY the locally-owned shard (optimizer state
+    is created, donated and persisted SHARDED — per-chip state bytes
+    ~ params/N), and the updated shards ``all_gather`` back.  A bucket
+    of several leaves is packed flat; a bucket of ONE leaf whose rows
+    divide over the shards into whole tiles keeps the leaf's shape,
+    from the backward pass through the optimizer's state to the
+    gathered weights (``step_fn.zero_layout`` says which:
+    ``[(bucket key, "leaf" | "flat", elements)]``; the rule reads
+    shapes only, ``zero._leaf_shaped``).  What a v5e runs for it
+    (VGG-16 on four chips, from the compiled step): a leaf-shaped
+    bucket's gradient is a native ``reduce-scatter`` (a chip receives
+    a quarter) or the compiler's fused ``all-reduce-scatter``; a flat
+    bucket's is an ``all-reduce`` of the whole bucket that the update
+    then slices (the compiler keeps no ``reduce-scatter`` of a 1-D
+    operand), several flat buckets to a launch; one ``all-gather`` a
+    bucket, the small ones asynchronous.  So a step's collectives
+    return 1.39x the parameters' bytes to a chip there (770.7 MB),
+    2.00x with every bucket flat — which is what one all-reduce a
+    tensor returns, at a launch a tensor.  ``None`` follows
+    MXNET_OPTIMIZER_SHARDING ('ps' arms it, '0' force-disables, empty
+    leaves it off); needs a mesh and does not compose with
+    ``param_spec`` (tp) yet.  Dynamic loss scaling checks finiteness
+    on the SCATTERED shard and psums the verdict; the nan-guard and
+    donation contracts are unchanged; under the forward each device
+    sees its local batch shard, so BatchNorm uses per-shard statistics
+    — the reference DataParallel semantics (executor_group.py), vs the
+    replicated path's SyncBatchNorm-style global stats.
 
     zero_stage: the ZeRO stage of the sharded exchange (1, 2 or 3;
     None follows MXNET_ZERO_STAGE, which overrides the argument, and
     defaults to stage 2).  Setting a stage opts the step into
     optimizer_sharding="ps" under a mesh.  Stage 1 is the classic
-    ZeRO-1 exchange for ablation: one all-reduce per bucket, the
-    owned shard sliced off the replicated reduced gradient.  Stage 2
-    (the default — bit-for-bit the program this step has always
-    traced) reduce-scatters each bucket so no device materializes
-    full gradients.  Stage 3 additionally shards the PARAMETERS: the
-    returned params pytree is ``{"_bucket<i>": flat padded bucket}``
-    sharded over the data axis (per-chip param+state bytes ~ total/N),
-    the forward all-gathers each bucket with all launches issued
-    up-front so bucket k+1's gather overlaps bucket k's compute
-    (prefetch), the backward's reduce-scatters fall out of
-    differentiating through those gathers (interleaved with backward
-    compute), and nothing gathers back.  Use
+    ZeRO-1 exchange for ablation: one ``psum`` per bucket, the owned
+    shard sliced off the replicated reduced gradient.  Stage 2 (the
+    default) asks for each bucket's ``psum_scatter``, so that no
+    device need hold a whole reduced gradient (it still does for a
+    flat bucket on a v5e, see above).  Stage 3 additionally shards
+    the PARAMETERS: the returned params pytree is
+    ``{"_bucket<i>": the bucket's array}`` (flat padded, or the leaf
+    itself) sharded over the data axis on dimension 0 (per-chip
+    param+state bytes ~ total/N), the forward all-gathers each bucket
+    with all launches issued up-front so bucket k+1's gather can
+    overlap bucket k's compute (prefetch), the backward's
+    reduce-scatters fall out of differentiating through those gathers
+    (interleaved with backward compute), and nothing gathers back.
+    The three stages share one bucket plan and one copy of either
+    layout, and end bit for bit where each other does.  Use
     ``zero.gather_stage3_params(step_fn.zero_plan, params)`` to
     reassemble the named tree; ``step_fn.zero_stage`` /
-    ``step_fn.zero_plan`` expose the layout.
+    ``step_fn.zero_plan`` / ``step_fn.zero_layout`` expose the layout.
+    An ``opt_state`` (or stage-3 params) saved by bucket before
+    leaf-shaped buckets, every entry 1-D, is taken by the step: the
+    content is the same row-major, so it is reshaped once
+    (``zero.adopt_layout``); any other shape is refused.
 
     gradient_compression: ``{"type": "2bit", "threshold": t}`` —
     2-bit quantization (kvstore.GradientCompression math) applied
@@ -428,9 +449,10 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
         _zero.check_bucket_rule(opt)
         plan = _zero.plan_buckets(params, n_sh, capacity=bucket_bound)
         bucket_keys = _zero.stage3_param_keys(plan)
-        # optimizer state is created over the FLAT buckets and lives
-        # sharded for the step's whole life (the server owning its key
-        # shard's state) — per-chip state bytes ~ total/N
+        # optimizer state is created over the buckets, each in its
+        # layout (a flat pack, or the one leaf's own shape), and lives
+        # sharded on dimension 0 for the step's whole life (the server
+        # owning its key shard's state) — per-chip state bytes ~ total/N
         opt_state = {
             bk: opt.fused_state(_zero.flatten_bucket(b, params))
             for bk, b in zip(bucket_keys, plan)
@@ -445,14 +467,15 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
                 # error-feedback residual: per bucket-SHARD, fp32 (the
                 # narrow-accumulate discipline — a bf16 residual would
                 # lose the feedback below threshold/256)
-                opt_state[f"_residual{i}"] = jnp.zeros((b.padded,),
+                opt_state[f"_residual{i}"] = jnp.zeros(b.shape,
                                                        jnp.float32)
         if stage == 3:
             # stage 3: the params move into their persistent layout —
-            # one flat padded bucket per plan entry, sharded over the
-            # data axis at jit wiring below (per-chip param bytes
-            # ~ total/N); the named tree only ever rematerializes
-            # transiently inside the step's per-bucket gathers
+            # one array per plan entry (flat padded, or the leaf
+            # itself), sharded over the data axis at jit wiring below
+            # (per-chip param bytes ~ total/N); the named tree only
+            # ever rematerializes transiently inside the step's
+            # per-bucket gathers
             params = {bk: _zero.flatten_bucket(b, params)
                       for bk, b in zip(bucket_keys, plan)}
     else:
@@ -714,8 +737,8 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
 
         def ps_local_step(params_, opt_state_, x, y, key, t):
             # runs PER DEVICE under shard_map: params replicated in
-            # (stages 1/2) or the locally-owned flat bucket shards
-            # (stage 3), x/y are the local batch shard, bucket
+            # (stages 1/2) or the locally-owned bucket shards (stage
+            # 3), x/y are the local batch shard, bucket
             # states/residuals are the locally-owned shard
             idx = jax.lax.axis_index(data_axis)
             fkey = jax.random.fold_in(key, idx)
@@ -733,11 +756,9 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
                     # executes instead of serializing all gathers at
                     # the step head
                     named = {}
-                    with jax.named_scope("mx_exchange"):
-                        for bk_, b_ in zip(bucket_keys, plan):
-                            named.update(_zero.unflatten_bucket(
-                                b_, jax.lax.all_gather(
-                                    p[bk_], data_axis, tiled=True)))
+                    for bk_, b_ in zip(bucket_keys, plan):
+                        named.update(_zero.gather_bucket(b_, p[bk_],
+                                                         data_axis))
                     p = named
                 lv = loss_of(p, x_, y_, k_)
                 if dynamic_scaling or static_scale != 1.0:
@@ -787,7 +808,8 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
                 else:
                     # THE stage-2 exchange: one reduce-scatter for the
                     # whole bucket replaces len(b.names) per-tensor
-                    # all-reduces
+                    # all-reduces; a leaf-shaped bucket goes in as the
+                    # backward pass left it and comes out as its rows
                     with jax.named_scope("mx_exchange"):
                         g_sh = jax.lax.psum_scatter(
                             _zero.flatten_bucket(b, lgrads), data_axis,
@@ -942,8 +964,8 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
         repl = NamedSharding(mesh, P())
         batch_sharding = NamedSharding(mesh, P(data_axis))
         if ps_mode:
-            # params replicate (stages 1/2) or live sharded as flat
-            # buckets (stage 3 — the parameter-memory win); bucket
+            # params replicate (stages 1/2) or live sharded by bucket
+            # (stage 3 — the parameter-memory win); bucket
             # states + residuals live SHARDED over the data axis (the
             # ZeRO-1 memory win); scalar entries (loss-scale, bad-step
             # counters) replicate
@@ -1002,6 +1024,11 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
     # the RunLog can blame a retrace on a stage flip
     _tm_sharding = "none" if not ps_mode else (
         "ps" if stage == 2 else f"zero{stage}")
+    if ps_mode:
+        # ... and how much of the exchange keeps its leaves' shapes
+        n_leaf, n_buckets, share = _zero.leaf_share(plan)
+        _tm_sharding += (f" ({n_leaf} of {n_buckets} buckets leaf-shaped, "
+                         f"{100 * share:.1f}% of the elements)")
     _tm_seen = set()
     _tm_last = [None]
     _nm_period = _nm.sample_period() if numerics_on else 0
@@ -1053,6 +1080,12 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
                 _profiler.note_program(
                     noted,
                     lambda: _inner.lower(*args).compile().as_text())
+        if ps_mode:
+            # a by-bucket tree saved flat, before leaf-shaped buckets,
+            # is reshaped once (or refused); its own is handed through
+            o = _zero.adopt_layout(plan, o)
+            if stage == 3:
+                p = _zero.adopt_layout(plan, p)
         # the host span that causes this step's device work, on the
         # profiler's clock (inactive outside a profiler session)
         with _tm.tracing.region("mx_step", step_num=_calls[0]):
@@ -1111,11 +1144,13 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
         step_fn.lower = _jitted_step.lower
     if ps_mode:
         # the layout contract for checkpointing/eval callers: under
-        # stage 3 the params pytree is flat buckets, and
+        # stage 3 the params pytree is by bucket, and
         # zero.gather_stage3_params(step_fn.zero_plan, params)
-        # reassembles the named tree
+        # reassembles the named tree; zero_layout says which buckets
+        # keep their leaf's shape: [(key, "leaf" | "flat", elements)]
         step_fn.zero_stage = stage
         step_fn.zero_plan = plan
+        step_fn.zero_layout = _zero.bucket_layout(plan)
 
     return step_fn, params, opt_state
 

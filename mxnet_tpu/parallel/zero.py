@@ -11,12 +11,37 @@ al., VLDB'20; both in PAPERS.md).
 
 TPU-native redesign: instead of one XLA all-reduce per parameter
 tensor (54 launches for the r05 dp(16) ResNet-18 dryrun — pure launch
-overhead on small tensors) the gradients flatten into a few
-dtype-homogeneous FLAT BUCKETS, each bucket ``reduce_scatter``s over
-the data axis, the registry optimizer's fused rule runs ONLY on the
-locally-owned shard (optimizer state lives sharded — memory and FLOPs
-scale with params/N), and the updated param buckets ``all_gather``
-back: ~2·buckets collectives of the same total bytes.
+overhead on small tensors) the gradients go into a few
+dtype-homogeneous BUCKETS, each bucket is summed and scattered over
+the data axis (``psum_scatter``), the registry optimizer's fused rule
+runs ONLY on the locally-owned shard (optimizer state lives sharded —
+memory and FLOPs scale with params/N), and the updated shards
+``all_gather`` back.  A bucket has one of two layouts, which the plan
+picks from shapes alone (:func:`_leaf_shaped`):
+
+* **flat** — several small leaves (and every 1-D one) packed into one
+  padded 1-D array: one launch for many tensors.  What the chip runs
+  for it (v5e, jax 0.9): the compiler does NOT keep a
+  ``reduce-scatter`` of a 1-D operand; it all-reduces the bucket whole
+  (neighbouring buckets combined into one launch) and the update's
+  fusion slices the owned part, so a flat bucket moves its bytes twice
+  on the gradients' way.  That is the price of packing and is paid
+  only where packing buys launches (VGG-16: 4.2% of the elements).
+* **leaf** — ONE leaf over the bound (or left alone by its
+  neighbours) whose rows divide over the shards: packing it would pack
+  nothing, so it is never reshaped.  The gradient goes into
+  ``psum_scatter(scatter_dimension=0, tiled=True)`` as the backward
+  pass left it, the owned shard of the weights is a ``dynamic_slice``
+  of rows that fuses into the update, the optimizer's state is kept as
+  those rows, and ``all_gather(tiled=True)`` returns the leaf as the
+  forward pass reads it.  What the chip runs: a native
+  ``reduce-scatter`` (a quarter of the bytes a chip receives on four
+  chips) for the largest leaves and for convolution kernels, the
+  compiler's fused ``all-reduce-scatter`` (plus a small
+  ``collective-permute`` that realigns rows) for mid-sized 2-D leaves;
+  no 1-D form of the leaf anywhere.  Two whole-leaf copies remain that
+  no layout removes: a donated leaf is copied at the program's start,
+  and a gathered result that is a program output is copied into it.
 
 This module owns the pieces shared by ``make_train_step``'s
 ``optimizer_sharding="ps"`` path and the Module-side
@@ -24,15 +49,19 @@ This module owns the pieces shared by ``make_train_step``'s
 
 * :func:`plan_buckets` — greedy dtype-homogeneous packing honoring the
   authentic ``MXNET_KVSTORE_BIGARRAY_BOUND`` split threshold, padded
-  so every bucket divides the shard count;
+  so every bucket divides the shard count, each bucket marked with its
+  layout; :func:`bucket_layout` / :func:`leaf_share` report it;
 * :func:`flatten_bucket` / :func:`unflatten_bucket` /
-  :func:`shard_slice` — the flat layout;
+  :func:`shard_slice` / :func:`gather_bucket` — the ONE copy of either
+  layout, which every stage and both callers go through;
+* :func:`adopt_layout` — a by-bucket tree saved flat, taken into the
+  plan's layout (a reshape) or refused;
 * :func:`collective_bytes` — the HLO collective counter (moved here
   from ``__graft_entry__`` so bench.py and tests share it);
 * :class:`ShardedBucketUpdater` — Module's drop-in Updater with
   bucket-sharded optimizer state (gathers to the LEGACY per-param
   ``.states`` layout on save, re-shards on load, so checkpoint files
-  stay interchangeable with replicated runs).
+  stay interchangeable with replicated runs and between layouts).
 """
 from __future__ import annotations
 
@@ -49,6 +78,7 @@ __all__ = ["Bucket", "plan_buckets", "flatten_bucket", "unflatten_bucket",
            "resolve_sharding_env", "resolve_zero_stage",
            "plan_fingerprint", "flat_variant_key",
            "resolve_bucket_variant", "analytic_exchange_bytes",
+           "bucket_layout", "leaf_share", "adopt_layout",
            "stage3_param_keys", "shard_stage3_params",
            "gather_stage3_params", "overlap_report",
            "ShardedBucketUpdater"]
@@ -57,7 +87,8 @@ __all__ = ["Bucket", "plan_buckets", "flatten_bucket", "unflatten_bucket",
 # ------------------------------------------------------------ bucket plan
 @dataclasses.dataclass(frozen=True)
 class Bucket:
-    """One dtype-homogeneous flat bucket of whole parameters."""
+    """One dtype-homogeneous bucket of whole parameters: a flat pack of
+    several leaves, or ONE leaf kept in its own shape (``leaf``)."""
 
     dtype: str
     names: tuple          # parameter names, in packing order
@@ -68,10 +99,24 @@ class Bucket:
     #: opaque partition key (e.g. effective (lr, wd) of the bucket's
     #: params); params with different groups never share a bucket
     group: object = None
+    #: the bucket is one leaf whose rows divide over the shards: it is
+    #: scattered, updated, kept and gathered as rows of the leaf's own
+    #: shape, never repacked 1-D (:func:`_leaf_shaped` is the rule)
+    leaf: bool = False
 
     @property
     def pad(self):
         return self.padded - self.size
+
+    @property
+    def shape(self):
+        """Shape of the bucket's array (and of its optimizer state):
+        the leaf's own, or ``(padded,)``; sharded on dimension 0."""
+        return self.shapes[0] if self.leaf else (self.padded,)
+
+    @property
+    def layout(self):
+        return "leaf" if self.leaf else "flat"
 
 
 def _capacity(capacity=None):
@@ -82,8 +127,48 @@ def _capacity(capacity=None):
     return max(1, int(get_env("MXNET_KVSTORE_BIGARRAY_BOUND")))
 
 
+#: rows of one float32 tile on the chip (``T(8,128)``; narrower dtypes
+#: pack 16 or 32 rows a tile)
+_SUBLANES = 8
+
+
+def _leaf_shaped(shapes, dtype, n_shards):
+    """The layout rule, from what the plan can observe: a bucket that
+    holds ONE leaf of two or more dimensions whose leading dimension
+    divides by the shard count into shards of whole tile rows keeps the
+    leaf's shape.  Flattening such a bucket packs nothing: it costs a
+    relayout of the leaf each way (a 2-D array tiled ``T(8,128)``
+    rewritten 1-D tiled ``T(1024)`` and back), and the chip's compiler
+    keeps a ``reduce-scatter`` only for the leaf-shaped operand (it
+    all-reduces the flat one whole and slices).  A shard that is not
+    whole tile rows (``[1000, 4096]`` over 4: 250 rows) would be cut
+    across a tile, so such a leaf stays flat, as does every 1-D leaf
+    and every bucket that really packs."""
+    if len(shapes) != 1 or len(shapes[0]) < 2:
+        return False
+    rows = int(shapes[0][0])
+    tile = _SUBLANES * max(1, 4 // onp.dtype(dtype).itemsize)
+    return rows % (int(n_shards) * tile) == 0
+
+
+def bucket_layout(plan):
+    """``[(bucket key, "leaf" | "flat", elements)]`` of a plan: what
+    ``step_fn.zero_layout`` reports."""
+    return [(k, b.layout, b.padded)
+            for k, b in zip(stage3_param_keys(plan), plan)]
+
+
+def leaf_share(plan):
+    """``(leaf-shaped buckets, buckets, share of the elements that are
+    exchanged leaf-shaped)`` of a plan."""
+    total = sum(b.padded for b in plan)
+    leaf = sum(b.padded for b in plan if b.leaf)
+    return (sum(1 for b in plan if b.leaf), len(plan),
+            leaf / total if total else 0.0)
+
+
 def plan_buckets(params, n_shards, capacity=None, group_key=None):
-    """Pack ``{name: array}`` into dtype-homogeneous flat buckets.
+    """Pack ``{name: array}`` into dtype-homogeneous buckets.
 
     The split threshold is the authentic reference knob: a bucket is
     closed once adding the next parameter would push it past
@@ -92,7 +177,9 @@ def plan_buckets(params, n_shards, capacity=None, group_key=None):
     servers.  Whole parameters are never split across buckets; a
     single parameter larger than the bound gets a bucket of its own.
     Each bucket is padded to a multiple of ``n_shards`` so
-    reduce-scatter/all-gather tile evenly.
+    reduce-scatter/all-gather tile evenly.  A bucket of one leaf whose
+    rows divide over the shards is marked ``leaf`` and keeps the
+    leaf's shape (:func:`_leaf_shaped`; its padding is 0).
 
     ``group_key`` ({name: hashable}, optional) further partitions
     buckets: params with different keys never share one.  The Module
@@ -122,7 +209,8 @@ def plan_buckets(params, n_shards, capacity=None, group_key=None):
             padded = -(-cur_size // n_shards) * n_shards
             buckets.append(Bucket(dt, tuple(cur_names), tuple(cur_shapes),
                                   tuple(cur_offsets), cur_size, padded,
-                                  grp))
+                                  grp,
+                                  _leaf_shaped(cur_shapes, dt, n_shards)))
             cur_names, cur_shapes, cur_offsets, cur_size = [], [], [], 0
 
         for name, shape in per_part[part]:
@@ -140,10 +228,14 @@ def plan_buckets(params, n_shards, capacity=None, group_key=None):
 
 
 def flatten_bucket(bucket, tree):
-    """Concatenate the bucket's parameters (in plan order) from a
-    ``{name: array}`` tree into one flat padded array."""
+    """The bucket's array (``bucket.shape``) from a ``{name: array}``
+    tree: the bucket's parameters concatenated (in plan order) into one
+    flat padded array, or the one leaf of a leaf-shaped bucket as it
+    is."""
     import jax.numpy as jnp
 
+    if bucket.leaf:
+        return tree[bucket.names[0]]
     parts = [jnp.reshape(tree[n], (-1,)) for n in bucket.names]
     if bucket.pad:
         parts.append(jnp.zeros((bucket.pad,), dtype=parts[0].dtype))
@@ -151,7 +243,11 @@ def flatten_bucket(bucket, tree):
 
 
 def unflatten_bucket(bucket, flat):
-    """Inverse of :func:`flatten_bucket` (padding dropped)."""
+    """Inverse of :func:`flatten_bucket` (padding dropped).  A
+    leaf-shaped bucket's row-major content IS the flat layout's (one
+    leaf, padding 0), so either form of its array is taken."""
+    if bucket.leaf:
+        return {bucket.names[0]: flat.reshape(bucket.shapes[0])}
     out = {}
     for name, shape, off in zip(bucket.names, bucket.shapes,
                                 bucket.offsets):
@@ -167,8 +263,11 @@ def bucket_segments(bucket):
     padding gets an inert extra segment) for norm-based rules (LARS)
     that need per-parameter reductions over the flat layout.
 
-    Returns (ids int32 ndarray of length ``padded``, num_segments).
+    Returns (ids int32 ndarray of ``bucket.shape``, num_segments); a
+    leaf-shaped bucket is one segment (a zero-stride view: no memory).
     """
+    if bucket.leaf:
+        return onp.broadcast_to(onp.int32(0), bucket.shape), 2
     ids = onp.empty((bucket.padded,), onp.int32)
     for i, (shape, off) in enumerate(zip(bucket.shapes, bucket.offsets)):
         n = 1
@@ -180,8 +279,13 @@ def bucket_segments(bucket):
 
 
 def shard_slice(flat, n_shards, idx):
-    """This shard's slice of a flat padded bucket (inside shard_map:
-    ``idx`` is the traced ``lax.axis_index``)."""
+    """This shard's slice of a bucket's array (inside shard_map:
+    ``idx`` is the traced ``lax.axis_index``): a ``n_shards``-th of a
+    flat padded bucket, or that share of a leaf-shaped bucket's rows —
+    contiguous in the major dimension, so nothing is laid out anew."""
+    if flat.ndim > 1:
+        rows = flat.shape[0] // n_shards
+        return jax.lax.dynamic_slice_in_dim(flat, idx * rows, rows, 0)
     return flat.reshape(n_shards, -1)[idx]
 
 
@@ -193,11 +297,12 @@ def bucket_shard_update(bucket, opt, params, g_sh, state, t, *, n_shards,
     :meth:`ShardedBucketUpdater._build` and ``make_train_step``'s ps
     step — ONE copy, so the two arms' seg-id slicing and shard layout
     cannot drift apart (their parity IS the checkpoint-interchange
-    contract).  Slices this device's shard of the flat param bucket
+    contract).  Slices this device's shard of the param bucket (a
+    stretch of the flat pack, or rows of a leaf-shaped bucket's leaf)
     and runs the fused rule on it against the already-scattered
-    gradient shard ``g_sh``.  Returns ``(w_sh, new_w_sh, new_state)``
-    un-gathered, so the caller can finite-gate the update before
-    :func:`gather_bucket`.
+    gradient shard ``g_sh`` of the same shape.  Returns
+    ``(w_sh, new_w_sh, new_state)`` un-gathered, so the caller can
+    finite-gate the update before :func:`gather_bucket`.
 
     ``pallas``: which lowering runs the update — True for the fused
     Pallas bucket kernels (ops/pallas_opt.py: prep + rule + the
@@ -205,8 +310,9 @@ def bucket_shard_update(bucket, opt, params, g_sh, state, t, *, n_shards,
     ``fused_bucket_update``, None to consult the ``fused_bucket_opt``
     autotune variant at trace time (force > MXNET_PALLAS_OPT > cached
     per-program winner > jnp).  An infeasible kernel (unsupported
-    rule/dtype) silently keeps the jnp arm — in a race that just
-    means the jnp arm wins.
+    rule/dtype, or a leaf-shaped shard: the kernels stream 1-D shards)
+    declines and keeps the jnp arm — in a race that just means the jnp
+    arm wins.
 
     ``want_finite=True`` returns a 4th element: the loss-scale verdict
     ``isfinite(g_sh).all()`` of the RAW (pre-dtype-cast) gradient —
@@ -222,13 +328,15 @@ def bucket_shard_update(bucket, opt, params, g_sh, state, t, *, n_shards,
     if w_sh is None:
         # stages 1/2: params arrive replicated as the named tree and
         # the owned shard is sliced here; stage 3 already HOLDS the
-        # shard (params live sharded as flat buckets) and passes it in
+        # shard (params live sharded by bucket) and passes it in
         # directly via ``w_sh=`` — same update math either way
         w_sh = shard_slice(flatten_bucket(bucket, params), n_shards, idx)
     seg_sh = None
     if seg is not None:
         ids, nseg = seg
-        seg_sh = (shard_slice(jnp.asarray(ids), n_shards, idx), nseg)
+        # a leaf-shaped bucket is one segment: nothing to slice
+        seg_sh = (jnp.zeros(w_sh.shape, jnp.int32) if bucket.leaf else
+                  shard_slice(jnp.asarray(ids), n_shards, idx), nseg)
     use_pallas = pallas
     if use_pallas is None:
         from ..autotune import variant_choice
@@ -263,9 +371,10 @@ def bucket_shard_update(bucket, opt, params, g_sh, state, t, *, n_shards,
 
 @jax.named_scope("mx_exchange")
 def gather_bucket(bucket, w_sh, axis):
-    """All-gather an updated shard back to the replicated flat bucket
-    and split it per param (tiled, matching :func:`shard_slice`'s
-    row-major layout)."""
+    """All-gather an updated shard back to the replicated bucket and
+    split it per param (tiled on dimension 0, matching
+    :func:`shard_slice`: a leaf-shaped bucket comes back as the leaf
+    the forward pass wants, with nothing to unpack)."""
     return unflatten_bucket(
         bucket, jax.lax.all_gather(w_sh, axis, tiled=True))
 
@@ -282,10 +391,16 @@ def flat_variant_key(plan, stage=None):
     Module updater's winner still reaches the default train step.
     Stages 1 and 3 wrap the kernel in a different exchange (all-reduce
     + slice / persistently-sharded params), so they get their own key
-    dimension rather than inheriting a winner measured elsewhere."""
+    dimension rather than inheriting a winner measured elsewhere.  So
+    does a plan with leaf-shaped buckets, whose elements the kernels do
+    not stream (they decline rows): a winner measured over the whole
+    plan flat says nothing of it.  A plan that is all flat keeps the
+    legacy key."""
     shape = (sum(b.padded for b in plan),)
     if stage not in (None, 2):
         shape = shape + (int(stage),)
+    if any(b.leaf for b in plan):
+        shape = shape + ("leaf", sum(b.padded for b in plan if b.leaf))
     return (shape, plan[0].dtype if plan else "float32")
 
 
@@ -319,10 +434,12 @@ def resolve_bucket_variant(optimizer, plan, mesh=None, stage=None):
 def plan_fingerprint(plan, n_shards, stage=None):
     """Stable fingerprint of a bucket plan AT a shard count — the
     checkpoint manifest's ``topology.plan_fingerprint`` (resilience.
-    elastic).  Two runs share a fingerprint iff their flat layouts are
-    interchangeable: same buckets in the same order with the same
-    member names/shapes/dtypes/padding, sharded the same number of
-    ways.  A resume whose fingerprint differs must re-plan + re-shard;
+    elastic).  Two runs share a fingerprint iff their bucket layouts
+    are interchangeable: same buckets in the same order with the same
+    member names/shapes/dtypes/padding and the same layout (a
+    leaf-shaped bucket is tagged; an all-flat plan hashes as it always
+    did), sharded the same number of ways.  A resume whose
+    fingerprint differs must re-plan + re-shard;
     one whose fingerprint matches is a same-topology no-op.
 
     ``stage``: ZeRO stages None/1/2 hash identically — their params
@@ -341,6 +458,8 @@ def plan_fingerprint(plan, n_shards, stage=None):
     for b in plan:
         h.update(repr((b.dtype, b.names, b.shapes, b.offsets,
                        b.size, b.padded, b.group)).encode())
+        if b.leaf:
+            h.update(b"leaf")
     return h.hexdigest()[:16]
 
 
@@ -386,15 +505,17 @@ def resolve_zero_stage():
 
 # ------------------------------------------------- stage-3 param layout
 def stage3_param_keys(plan):
-    """The pytree keys of the stage-3 parameter layout: one flat
-    padded bucket per plan entry, sharded over the data axis."""
+    """The pytree keys of the by-bucket layout (stage-3 parameters,
+    every stage's optimizer state): one array of ``bucket.shape`` per
+    plan entry, sharded over the data axis on dimension 0."""
     return [f"_bucket{i}" for i in range(len(plan))]
 
 
 def shard_stage3_params(plan, named, mesh=None, data_axis="data"):
     """Named ``{name: array}`` params -> the stage-3 persistent layout
-    ``{"_bucket<i>": flat padded array}``, placed sharded over the
-    data axis when a mesh is given (per-chip param bytes ~ total/N)."""
+    ``{"_bucket<i>": the bucket's array}`` (flat padded, or the leaf
+    itself), placed sharded over the data axis when a mesh is given
+    (per-chip param bytes ~ total/N)."""
     import jax
     import jax.numpy as jnp
 
@@ -410,13 +531,47 @@ def shard_stage3_params(plan, named, mesh=None, data_axis="data"):
 
 def gather_stage3_params(plan, pshards):
     """Inverse of :func:`shard_stage3_params`: reassemble the named
-    ``{name: array}`` tree from flat bucket arrays (host-side; for a
+    ``{name: array}`` tree from the buckets' arrays (host-side; for a
     multi-process world pass buckets through
     ``resilience.elastic.host_gather`` first)."""
     named = {}
     for k, b in zip(stage3_param_keys(plan), plan):
         named.update(unflatten_bucket(b, onp.asarray(pshards[k])))
     return named
+
+
+def adopt_layout(plan, tree):
+    """A by-bucket tree (a ``make_train_step`` ``opt_state``, stage-3
+    params) as the plan lays it out.  Trees saved before leaf-shaped
+    buckets hold every ``_bucket<i>`` / ``_residual<i>`` 1-D; a
+    leaf-shaped bucket's row-major content equals that flat content
+    element for element, so such an entry is reshaped.  Any other shape
+    is refused: it was laid out under another plan.  Returns ``tree``
+    itself when nothing differs."""
+    out = tree
+    for i, (k, b) in enumerate(zip(stage3_param_keys(plan), plan)):
+        if not b.leaf:
+            continue
+
+        def fit(a, key):
+            if not getattr(a, "ndim", 0) or tuple(a.shape) == b.shape:
+                return a
+            if tuple(a.shape) == (b.padded,):
+                return a.reshape(b.shape)
+            raise MXNetError(
+                f"{key} holds an array of shape {tuple(a.shape)} where "
+                f"the bucket plan keeps {b.names[0]!r} leaf-shaped as "
+                f"{b.shape} (or flat as ({b.padded},), which is taken): "
+                "it was saved under another bucket plan; gather it to "
+                "named parameters and re-plan")
+
+        for key in (k, f"_residual{i}"):
+            leaves = jax.tree_util.tree_leaves(tree.get(key))
+            if any(fit(a, key) is not a for a in leaves):
+                out = dict(out) if out is tree else out
+                out[key] = jax.tree_util.tree_map(
+                    lambda a: fit(a, key), tree[key])
+    return out
 
 
 # ---------------------------------------------- analytic exchange bytes
@@ -991,19 +1146,19 @@ class ShardedBucketUpdater:
                 "set_states before saving or updating again")
         per_param = {}
         for b, st in zip(self.plan, self._states):
-            per_leaf = [onp.asarray(s) for s in st]
-            for name, shape, off in zip(b.names, b.shapes, b.offsets):
-                n = 1
-                for d in shape:
-                    n *= int(d)
+            per_leaf = [unflatten_bucket(b, onp.asarray(s))
+                        if getattr(s, "ndim", 0) else onp.asarray(s)
+                        for s in st]
+            for name in b.names:
                 per_param[name] = tuple(
-                    s[off:off + n].reshape(shape)
-                    if getattr(s, "ndim", 0) else s for s in per_leaf)
+                    s[name] if isinstance(s, dict) else s
+                    for s in per_leaf)
         return per_param
 
     def _flatten_to_plan(self, per_param):
-        """Inverse of :meth:`_gather_per_param`: flatten per-param
-        leaf tuples into the current plan's buckets and re-shard."""
+        """Inverse of :meth:`_gather_per_param`: pack per-param leaf
+        tuples into the current plan's buckets (each in its layout) and
+        re-shard."""
         import jax.numpy as jnp
 
         new_states = []

@@ -396,6 +396,9 @@ _COMPUTATION = re.compile(
     r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s*->.*\{\s*$")
 _CALLS = re.compile(r"calls=%?([\w.\-]+)")
 _OPCODE = re.compile(r"\s([a-z][a-z\-]*)\(")
+_COLLECTIVE = re.compile(
+    r"\s(all-reduce|all-gather|reduce-scatter|collective-permute|"
+    r"all-to-all)(?:-start|-done)?\(")
 _MODULE = re.compile(r"^HloModule\s+([\w.\-]+)")
 
 
@@ -449,8 +452,17 @@ def _scope_table(hlo_text):
     computation's root instruction; the second entry lists the phases
     of every instruction of the called computation, so that a fusion
     of the backward pass into which XLA put the optimizer's update
-    says so."""
-    own, calls, roots, held, current = {}, {}, {}, {}, None
+    says so.
+
+    A collective without metadata goes under ``mx_exchange``, and so
+    does an unnamed fusion that holds one: the compiler's passes that
+    rewrite a collective (a ``psum_scatter`` decomposed into an
+    all-reduce and a slice or fused as ``all-reduce-scatter``,
+    neighbouring all-reduces combined into one launch, a
+    collective-permute that realigns rows) drop the ``op_name`` the
+    program gave it, and a compiled step moves data between chips for
+    nothing but its exchange."""
+    own, calls, roots, held, wire, current = {}, {}, {}, {}, {}, None
     for line in hlo_text.splitlines():
         m = _COMPUTATION.match(line)
         if m:
@@ -464,8 +476,14 @@ def _scope_table(hlo_text):
         scope = _OP_NAME.search(line)
         if scope:
             own[name] = scope.group(1)
-            if current is not None:
-                held[current].add(_phase_and_block(scope.group(1))[0])
+        else:
+            rewritten = _COLLECTIVE.search(line)
+            if rewritten:
+                own[name] = "mx_exchange/" + rewritten.group(1)
+                if current is not None:
+                    wire[current] = own[name]
+        if name in own and current is not None:
+            held[current].add(_phase_and_block(own[name])[0])
         called = _CALLS.search(line)
         if called:
             calls[name] = called.group(1)
@@ -473,7 +491,8 @@ def _scope_table(hlo_text):
             roots[current] = name
     table = {name: (scope, ()) for name, scope in own.items()}
     for name, body in calls.items():
-        scope = own.get(name) or own.get(roots.get(body), "")
+        scope = own.get(name) or own.get(roots.get(body)) \
+            or wire.get(body, "")
         table[name] = (scope, tuple(sorted(
             held.get(body, set()) - {"unscoped"})))
     return table

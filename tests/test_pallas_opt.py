@@ -190,6 +190,44 @@ def test_bucket_shard_update_variant_plumbing(cache_dir):
     assert fin_f is None  # fell back: jnp arm, no fused verdict
 
 
+@pytest.mark.parametrize("opt,n_state", [
+    (SGD(momentum=0.9, learning_rate=0.1, wd=1e-4), 1),
+    (Adam(learning_rate=0.01), 2),
+    (LARS(momentum=0.9, learning_rate=0.1), 1),
+])
+def test_leaf_shaped_shard_declines_the_kernels(opt, n_state):
+    """The kernels stream 1-D shards.  Rows of a leaf-shaped bucket
+    are not flattened for them (that would lay the shard out anew each
+    way): the arm reports itself infeasible there, counted with the
+    reason, and the jnp rule runs — same numbers as pallas=False."""
+    from mxnet_tpu.ops import kernel_target
+
+    w = _flat(64 * 8, 0).reshape(64, 8)
+    (b,) = zero.plan_buckets({"w": w}, 2, capacity=1)
+    assert b.leaf
+    g = _flat(32 * 8, 1).reshape(32, 8)     # shard 1 of 2: 32 rows
+    state = tuple(jnp.zeros((32, 8), jnp.float32)
+                  for _ in range(n_state))
+    seg = zero.bucket_segments(b) if isinstance(opt, LARS) else None
+    kw = dict(n_shards=2, idx=1, axis=None, seg=seg, want_finite=True)
+    before = kernel_target.declined_counts().get("fused_bucket_opt", 0)
+    w_sh, uw_p, us_p, fin_p = zero.bucket_shard_update(
+        b, opt, {"w": w}, g, state, 1.0, pallas=True, **kw)
+    assert kernel_target.declined_counts()["fused_bucket_opt"] == \
+        before + 1
+    assert fin_p is None                    # the caller's check stands
+    assert w_sh.shape == uw_p.shape == (32, 8)
+    onp.testing.assert_array_equal(onp.asarray(w_sh),
+                                   onp.asarray(w[32:]))
+    _, uw_j, us_j, _ = zero.bucket_shard_update(
+        b, opt, {"w": w}, g, state, 1.0, pallas=False, **kw)
+    assert bool((uw_p == uw_j).all())
+    for a, c in zip(us_p, us_j):
+        assert a.shape == (32, 8) and bool((a == c).all())
+    assert po.bucket_update(opt, w_sh, g, state, 1.0,
+                            interpret=True) is None
+
+
 def test_sharded_updater_pallas_parity_and_key():
     """ShardedBucketUpdater with the kernel arm forced matches the jnp
     arm on a dp(4) CPU mesh (adam, two steps), and its variant cache

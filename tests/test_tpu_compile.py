@@ -318,3 +318,117 @@ def test_vgg_stage1_runs_paired_without_a_relayout(one_chip, tpu_target):
                  if code in ("copy", "transpose")
                  and math.prod(out) >= stage]
     assert relayouts == []
+
+
+# ------------------------------------ the ps exchange of a one-leaf bucket
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    import numpy as onp
+    from jax.sharding import Mesh
+
+    if len(topo.devices) < 4:
+        pytest.skip("the exchange exists only across chips: four needed")
+    return Mesh(onp.array(topo.devices[:4]), ("data",))
+
+
+def _named_instructions(text):
+    """``{name: (opcode, text of the result's shape)}`` of a compiled
+    text; a tuple-shaped result (an async start) keeps its whole text."""
+    import re
+
+    pat = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*?)\s"
+                     r"([a-z][a-z\-]*)\(")
+    out = {}
+    for line in text.splitlines():
+        m = pat.match(line)
+        if m:
+            out[m.group(1)] = (m.group(3), m.group(2))
+    return out
+
+
+def _ps_step_text(mesh, monkeypatch, stage):
+    """The compiled text of a ``ps`` step over one Dense layer whose
+    weight, f32[4096, 1024], is a bucket of its own (over the bound),
+    for four described chips.  Nothing can be placed on a described
+    device, so the step's state stays where it is and the lowering gets
+    shapes."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import gluon
+    from mxnet_tpu.parallel import make_train_step
+
+    monkeypatch.setattr(jax, "device_put", lambda x, *a, **k: x)
+    net = gluon.nn.Dense(4096, in_units=1024)
+    net.initialize(init=mx.init.Xavier())
+    step, params, state = make_train_step(
+        net, gluon.loss.L2Loss(), optimizer="sgd", learning_rate=0.1,
+        momentum=0.9, mesh=mesh, donate=False, autotune=False,
+        optimizer_sharding="ps", zero_stage=stage)
+    repl, rows = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+
+    def spec(a, sharded):
+        return jax.ShapeDtypeStruct(
+            a.shape, a.dtype,
+            sharding=rows if sharded and a.ndim else repl)
+
+    p = jax.tree_util.tree_map(lambda a: spec(a, stage == 3), params)
+    s = jax.tree_util.tree_map(lambda a: spec(a, True), state)
+    x = jax.ShapeDtypeStruct((256, 1024), jnp.float32, sharding=rows)
+    y = jax.ShapeDtypeStruct((256, 4096), jnp.float32, sharding=rows)
+    text = step.lower(p, s, x, y, jax.random.key(0), 1.0) \
+        .compile().as_text()
+    return step, text
+
+
+@pytest.mark.parametrize("stage", [2, 3])
+def test_one_leaf_bucket_is_exchanged_in_the_leafs_shape(
+        four_chips, monkeypatch, stage):
+    import re
+
+    from mxnet_tpu import profiler
+
+    step, text = _ps_step_text(four_chips, monkeypatch, stage)
+    assert [(lay, n) for _, lay, n in step.zero_layout] == [
+        ("leaf", 4096 * 1024), ("flat", 4096)]
+    table = _named_instructions(text)
+    # no 1-D form of the leaf or of its shard, anywhere: neither the
+    # gradient nor the weights are packed flat, cut flat or unpacked
+    flat = [(n, shape) for n, (op, shape) in table.items()
+            if re.match(r"f32\[(4194304|1048576)\]", shape)]
+    assert not flat, flat[:5]
+    # the gradient is scattered by rows: a reduce-scatter to the
+    # shard's own shape, or the compiler's fused form of one (a kCustom
+    # `all-reduce-scatter` fusion over the leaf padded by some rows);
+    # never an all-reduce of the flat leaf that a slice then cuts
+    scattered = [n for n, (op, shape) in table.items()
+                 if op == "reduce-scatter"
+                 and shape.startswith("f32[1024,1024]")]
+    fused = re.findall(r"fusion\([^)]*\), kind=kCustom, "
+                       r"calls=%all-reduce-scatter", text)
+    assert scattered or fused, sorted(
+        (op, shape) for op, shape in table.values() if "reduce" in op)
+    # the weights come back as rows, gathered into the leaf's shape
+    # (at stage 3 the compiler gathers what the product reads: bf16)
+    assert [n for n, (op, shape) in table.items()
+            if op == "all-gather"
+            and re.match(r"(f32|bf16)\[4096,1024\]", shape)]
+    # and the program's reader puts every collective of the step, and
+    # every fusion that holds one, under mx_exchange
+    scopes = profiler._scope_table(text)
+    wire = [n for n, (op, _) in table.items()
+            if op.split("-start")[0].split("-done")[0] in (
+                "all-reduce", "all-gather", "reduce-scatter",
+                "collective-permute", "all-to-all")]
+    assert len(wire) >= 2
+    for name in wire:
+        phase, _ = profiler._phase_and_block(scopes[name][0])
+        assert phase == "exchange", (name, scopes[name])
+    # what the exchange itself packs (the bias's flat bucket) says so
+    packing = [n for n, (scope, _) in scopes.items()
+               if "mx_exchange" in scope and n in table
+               and table[n][0] in ("reshape", "concatenate", "pad",
+                                   "dynamic-slice", "bitcast", "copy")]
+    for name in packing:
+        assert profiler._phase_and_block(scopes[name][0])[0] == \
+            "exchange"

@@ -172,6 +172,70 @@ def test_every_collective_is_under_mx_exchange(compiled):
             (code, scope)
 
 
+#: what a v5e's compiler leaves of the exchange's scopes (lines of
+#: vgg16_train_4chip's compiled step, shortened): the passes that
+#: rewrite a collective drop the op_name the program gave it
+_REWRITTEN = """HloModule jit__scoped_step, is_scheduled=true
+
+%region_0.1 (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[]{:T(128)} parameter(0), metadata={op_name="reduce_scatter"}
+  %b = f32[]{:T(128)} parameter(1), metadata={op_name="reduce_scatter"}
+  ROOT %add.2 = f32[]{:T(128)} add(%a, %b), metadata={op_name="add"}
+}
+
+%all-reduce-scatter (input: f32[4096,4096]) -> f32[1120,4096] {
+  %input = f32[4096,4096]{1,0:T(8,128)} parameter(0)
+  %constant.4 = f32[] constant(0)
+  %pad.62 = f32[4480,4096]{1,0:T(8,128)} pad(%input, %constant.4), padding=0_384x0_0
+  %all-reduce.24 = f32[4480,4096]{1,0:T(8,128)} all-reduce(%pad.62), channel_id=23, replica_groups={{0,1,2,3}}, to_apply=%region_0.1
+  %partition-id.3 = u32[] partition-id()
+  ROOT %dynamic-slice.14 = f32[1120,4096]{1,0:T(8,128)S(1)} dynamic-slice(%all-reduce.24, %partition-id.3, %partition-id.3), dynamic_slice_sizes={1120,4096}
+}
+
+%fused_computation.9 (p: f32[1408,4096]) -> f32[1024,4096] {
+  %p = f32[1408,4096]{1,0:T(8,128)S(1)} parameter(0)
+  ROOT %dynamic-slice.3 = f32[1024,4096]{1,0:T(8,128)} slice(%p), slice={[0:1024], [0:4096]}
+}
+
+ENTRY %main.59_spmd (param.1: f32[4096,4096]) -> f32[1024,4096] {
+  %param.1 = f32[4096,4096]{1,0:T(8,128)} parameter(0)
+  %fusion.1 = f32[1120,4096]{1,0:T(8,128)S(1)} fusion(%param.1), kind=kCustom, calls=%all-reduce-scatter
+  %slice.138 = f32[288,4096]{1,0:T(8,128)S(1)} slice(%fusion.1), slice={[832:1120], [0:4096]}
+  %collective-permute-start = (f32[288,4096]{1,0:T(8,128)S(1)}, f32[288,4096]{1,0:T(8,128)S(1)}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%slice.138), channel_id=4, source_target_pairs={{0,1},{1,2},{2,3}}
+  %collective-permute-done = f32[288,4096]{1,0:T(8,128)S(1)} collective-permute-done(%collective-permute-start)
+  %all-reduce.26 = (f32[555328]{0:T(1024)S(1)}, f32[1000]{0:T(1024)S(1)}) all-reduce(%param.1, %param.1), channel_id=18, replica_groups={{0,1,2,3}}, to_apply=%region_0.1
+  %all-reduce.25 = f32[512]{0:T(512)} all-reduce(%param.1), channel_id=19, to_apply=%region_0.1, metadata={op_name="jit(_scoped_step)/shard_map/mx_guard/mx_exchange/psum"}
+  %get-tuple-element.9 = f32[1000]{0:T(1024)S(1)} get-tuple-element(%all-reduce.26), index=1
+  %concatenate.1 = f32[1408,4096]{1,0:T(8,128)S(1)} concatenate(%collective-permute-done, %fusion.1), dimensions={0}
+  ROOT %fusion.9 = f32[1024,4096]{1,0:T(8,128)} fusion(%concatenate.1), kind=kLoop, calls=%fused_computation.9
+}
+"""
+
+
+def test_a_collective_the_compiler_rewrote_still_reads_as_exchange():
+    """``dumps()`` reads scopes from the compiled text.  The compiler
+    decomposes a ``psum_scatter`` (an all-reduce and a slice, or its
+    fused ``all-reduce-scatter`` with a permute that realigns rows) and
+    combines neighbouring all-reduces, and gives the new instructions
+    no ``op_name``: they, and an unnamed fusion that holds one, still
+    go under ``mx_exchange``; a reference to a collective and the
+    compiler's own padding and slicing around it do not."""
+    table = profiler._scope_table(_REWRITTEN)
+
+    def phase(name):
+        return profiler._phase_and_block(table.get(name, ("",))[0])[0]
+
+    for name in ("all-reduce.24", "fusion.1", "collective-permute-start",
+                 "collective-permute-done", "all-reduce.26",
+                 "all-reduce.25"):
+        assert phase(name) == "exchange", (name, table.get(name))
+    assert table["fusion.1"][1] == ("exchange",)
+    assert table["all-reduce.25"][0].endswith("mx_guard/mx_exchange/psum")
+    for name in ("get-tuple-element.9", "slice.138", "concatenate.1",
+                 "fusion.9", "pad.62", "param.1"):
+        assert phase(name) == "unscoped", (name, table.get(name))
+
+
 def test_eager_call_opens_no_scope(monkeypatch):
     opened = []
     real = jax.named_scope

@@ -85,24 +85,155 @@ def test_flatten_unflatten_roundtrip_and_segments():
     assert (ids[17:] == 2).all()
 
 
+class _Spec:
+    def __init__(self, shape, dtype="float32"):
+        self.shape, self.dtype = shape, onp.dtype(dtype)
+
+
+def _vgg16_shaped():
+    """VGG-16's parameter dict by shape, in the order the step's dict
+    has it (sorted by name)."""
+    convs = [(64, 3), (64, 64), (128, 64), (128, 128), (256, 128),
+             (256, 256), (256, 256), (512, 256), (512, 512), (512, 512),
+             (512, 512), (512, 512), (512, 512)]
+    params = {}
+    for i, (cout, cin) in enumerate(convs):
+        params[f"vgg0_conv2d{i}_weight"] = _Spec((cout, 3, 3, cin))
+        params[f"vgg0_conv2d{i}_bias"] = _Spec((cout,))
+    for i, (out, fan_in) in enumerate([(4096, 25088), (4096, 4096),
+                                       (1000, 4096)]):
+        params[f"vgg0_dense{i}_weight"] = _Spec((out, fan_in))
+        params[f"vgg0_dense{i}_bias"] = _Spec((out,))
+    return dict(sorted(params.items()))
+
+
+def test_plan_marks_leaf_shaped_buckets_of_vgg16():
+    """At 4 shards and the default bound: a bucket of ONE leaf whose
+    rows divide into shards of whole tile rows keeps the leaf's shape
+    (dense0, dense1, the six convolutions over the bound); dense2 (250
+    rows a shard), every 1-D leaf and every bucket that packs stay
+    flat."""
+    plan = zero.plan_buckets(_vgg16_shaped(), 4)
+    by_name = {n: b for b in plan for n in b.names}
+    for n in ("vgg0_dense0_weight", "vgg0_dense1_weight"):
+        b = by_name[n]
+        assert b.leaf and b.names == (n,) and b.pad == 0
+        assert b.shape == b.shapes[0] and b.layout == "leaf"
+    d2 = by_name["vgg0_dense2_weight"]
+    assert not d2.leaf and d2.shape == (d2.padded,)
+    for b in plan:
+        if len(b.names) > 1 or len(b.shapes[0]) < 2:
+            assert not b.leaf and b.layout == "flat", b.names
+    # the convolutions that sit alone in a bucket (512 rows: 128 a
+    # shard) go leaf-shaped; those that pack with a bias stay flat
+    for i in (7, 8, 9, 10, 11, 12):
+        assert by_name[f"vgg0_conv2d{i}_weight"].leaf, i
+    n_leaf, n_buckets, share = zero.leaf_share(plan)
+    assert (n_leaf, n_buckets) == (8, len(plan))
+    assert share >= 0.86
+    dense = sum(by_name[f"vgg0_dense{i}_weight"].padded for i in (0, 1))
+    assert dense / sum(b.padded for b in plan) >= 0.86
+    layout = zero.bucket_layout(plan)
+    assert [k for k, _, _ in layout] == zero.stage3_param_keys(plan)
+    assert [(lay, n) for _, lay, n in layout] == \
+        [(b.layout, b.padded) for b in plan]
+
+
+@pytest.mark.parametrize("shape,n_shards,dtype,leaf", [
+    ((4096, 4096), 4, "float32", True),
+    ((1000, 4096), 8, "float32", False),   # 125 rows a shard
+    ((1000, 4096), 4, "float32", False),   # 250 rows: cuts a tile
+    ((1024, 4096), 8, "float32", True),
+    ((64, 8), 8, "float32", True),
+    ((64, 8), 8, "bfloat16", False),       # 16 rows a bf16 tile
+    ((128, 8), 8, "bfloat16", True),
+    ((4096 * 4096,), 4, "float32", False),  # nothing to keep 1-D
+    ((512, 3, 3, 512), 4, "float32", True),
+])
+def test_leaf_rule_reads_rows_shards_and_dtype(shape, n_shards, dtype,
+                                               leaf):
+    (b,) = zero.plan_buckets({"w": _Spec(shape, dtype)}, n_shards,
+                             capacity=1)
+    assert b.leaf == leaf
+    assert b.shape == (shape if leaf else (b.padded,))
+    # two leaves in one bucket pack, whatever their shapes
+    (b2,) = zero.plan_buckets(
+        {"w": _Spec(shape, dtype), "v": _Spec(shape, dtype)}, n_shards,
+        capacity=1 << 40)
+    assert not b2.leaf
+
+
+def test_leaf_bucket_roundtrip_slices_and_segments():
+    w = jnp.arange(64.0 * 8).reshape(64, 8)
+    (b,) = zero.plan_buckets({"w": w}, n_shards=8, capacity=1)
+    assert b.leaf
+    arr = zero.flatten_bucket(b, {"w": w})
+    assert arr is w                       # nothing is packed
+    assert zero.unflatten_bucket(b, arr)["w"] is w
+    # a flat copy of the same content (a tree saved before leaf-shaped
+    # buckets) unflattens to the leaf too
+    onp.testing.assert_array_equal(
+        onp.asarray(zero.unflatten_bucket(b, w.reshape(-1))["w"]),
+        onp.asarray(w))
+    # shard k is rows [8k, 8k+8): the flat layout's k-th stretch
+    for k in (0, 5):
+        onp.testing.assert_array_equal(
+            onp.asarray(zero.shard_slice(arr, 8, k)).reshape(-1),
+            onp.asarray(zero.shard_slice(w.reshape(-1), 8, k)))
+    ids, nseg = zero.bucket_segments(b)
+    assert nseg == 2 and ids.shape == b.shape and not ids.any()
+    assert ids.strides == (0, 0)          # a view: no memory
+
+
+def test_layout_tells_variant_keys_and_fingerprints_apart(monkeypatch):
+    params = {"w": _Spec((64, 8)), "b": _Spec((64,))}
+    leafy = zero.plan_buckets(params, 8, capacity=300)
+    assert [b.leaf for b in leafy] == [True, False]
+    monkeypatch.setattr(zero, "_leaf_shaped", lambda *a: False)
+    flat = zero.plan_buckets(params, 8, capacity=300)
+    assert not any(b.leaf for b in flat)
+    assert [b.padded for b in flat] == [b.padded for b in leafy]
+    assert zero.flat_variant_key(flat) == ((576,), "float32")  # legacy
+    assert zero.flat_variant_key(leafy) != zero.flat_variant_key(flat)
+    for stage in (None, 3):
+        assert zero.plan_fingerprint(leafy, 8, stage) != \
+            zero.plan_fingerprint(flat, 8, stage)
+
+
 # ----------------------------------------------------------------- parity
-def _mlp_net():
+#: widths of the seeded MLP: the historic one packs or stays flat at
+#: 8 shards (32, 16 and 4 rows); the leafy one has leaves of 64 and 128
+#: rows, which sit alone over bucket_bound=300 and go leaf-shaped
+_MLP, _LEAFY = (32, 16, 4), (64, 128, 4)
+_WIDTHS = pytest.mark.parametrize("widths", [_MLP, _LEAFY],
+                                  ids=["mlp", "leafy"])
+
+
+def _mlp_net(widths=_MLP):
     mx.random.seed(0)
     onp.random.seed(0)
     net = nn.HybridSequential()
     with net.name_scope():
-        net.add(nn.Dense(32, activation="relu"),
-                nn.Dense(16, activation="relu"), nn.Dense(4))
+        net.add(nn.Dense(widths[0], activation="relu"),
+                nn.Dense(widths[1], activation="relu"),
+                nn.Dense(widths[2]))
     net.initialize(init=mx.init.Xavier())
     net(mx.nd.zeros((1, 8)))
     return net
 
 
-def _run_steps(optimizer, n_steps=10, **kw):
+def _layouts(state):
+    """{bucket key: "leaf" | "flat"} read off a by-bucket opt_state."""
+    return {k: "leaf" if max(getattr(a, "ndim", 0) for a in v) > 1
+            else "flat" for k, v in state.items()
+            if k.startswith("_bucket") and v}
+
+
+def _run_steps(optimizer, n_steps=10, widths=_MLP, **kw):
     mesh = get_mesh((8,), ("data",))
     loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
     step, p, s = make_train_step(
-        _mlp_net(), loss_fn, optimizer=optimizer, learning_rate=0.1,
+        _mlp_net(widths), loss_fn, optimizer=optimizer, learning_rate=0.1,
         momentum=0.9, mesh=mesh, donate=False, **kw)
     rng = onp.random.RandomState(0)
     X = jnp.asarray(rng.rand(32, 8).astype("float32"))
@@ -116,16 +247,34 @@ def _run_steps(optimizer, n_steps=10, **kw):
     return float(loss), p, s
 
 
+@_WIDTHS
 @pytest.mark.parametrize("optimizer,exact", [
     ("sgd", True),      # acceptance: bit-exact fp32
     ("adam", False),    # allclose (carries 2 slots)
     ("lars", False),    # allclose (trust ratios via segment psum)
 ])
-def test_sharded_step_parity_with_replicated(optimizer, exact):
-    l_r, p_r, _ = _run_steps(optimizer)
-    l_s, p_s, s_s = _run_steps(optimizer, optimizer_sharding="ps",
-                               bucket_bound=300)
+def test_sharded_step_parity_with_replicated(optimizer, exact, widths,
+                                             monkeypatch):
+    l_r, p_r, _ = _run_steps(optimizer, widths=widths)
+    l_s, p_s, s_s = _run_steps(optimizer, widths=widths,
+                               optimizer_sharding="ps", bucket_bound=300)
     assert set(p_r) == set(p_s)
+    layouts = _layouts(s_s)
+    if widths == _LEAFY:
+        # two leaves are exchanged, updated and kept as their own rows
+        # ... and the same step over flat buckets (the layout every
+        # bucket had before) ends bit for bit where this one does
+        assert sorted(layouts.values()).count("leaf") == 2
+        monkeypatch.setattr(zero, "_leaf_shaped", lambda *a: False)
+        l_f, p_f, s_f = _run_steps(optimizer, widths=widths,
+                                   optimizer_sharding="ps",
+                                   bucket_bound=300)
+        assert set(_layouts(s_f).values()) == {"flat"}
+        assert l_f == l_s
+        for k in p_f:
+            onp.testing.assert_array_equal(p_f[k], p_s[k], err_msg=k)
+    else:
+        assert set(layouts.values()) == {"flat"}
     if exact:
         assert l_r == l_s
         for k in p_r:
@@ -145,16 +294,59 @@ def test_sharded_step_parity_with_replicated(optimizer, exact):
                     "data")
 
 
-def test_sharded_step_parity_under_dynamic_loss_scaling():
-    l_r, p_r, s_r = _run_steps("sgd", loss_scale="dynamic")
-    l_s, p_s, s_s = _run_steps("sgd", loss_scale="dynamic",
+@_WIDTHS
+def test_sharded_step_parity_under_dynamic_loss_scaling(widths):
+    l_r, p_r, s_r = _run_steps("sgd", loss_scale="dynamic", widths=widths)
+    l_s, p_s, s_s = _run_steps("sgd", loss_scale="dynamic", widths=widths,
                                optimizer_sharding="ps", bucket_bound=300)
+    assert ("leaf" in _layouts(s_s).values()) == (widths == _LEAFY)
     assert l_r == l_s
     for k in p_r:
         onp.testing.assert_array_equal(p_r[k], p_s[k], err_msg=k)
     # the scale/finite-counter bookkeeping matches too
     for a, b in zip(s_r["_loss_scale"], s_s["_loss_scale"]):
         assert float(onp.asarray(a)) == float(onp.asarray(b))
+
+
+def test_zero_layout_and_runlog_name_the_leaf_shaped_share(tmp_path):
+    """What the plan decided is readable off the step and off the
+    RunLog's compile record, with no program text."""
+    from mxnet_tpu import telemetry
+
+    path = str(tmp_path / "run.jsonl")
+    telemetry.reset(path)
+    try:
+        mesh = get_mesh((8,), ("data",))
+        step, p, s = make_train_step(
+            _mlp_net(_LEAFY), gluon.loss.SoftmaxCrossEntropyLoss(),
+            optimizer="sgd", learning_rate=0.1, momentum=0.9, mesh=mesh,
+            donate=False, optimizer_sharding="ps", bucket_bound=300)
+        rng = onp.random.RandomState(0)
+        X = jnp.asarray(rng.rand(32, 8).astype("float32"))
+        y = jnp.asarray(rng.randint(0, 4, (32,)).astype("float32"))
+        step(p, s, X, y, jax.random.key(0), 1.0)
+    finally:
+        telemetry.close()
+    plan = step.zero_plan
+    assert step.zero_layout == [
+        (f"_bucket{i}", "leaf" if b.leaf else "flat", b.padded)
+        for i, b in enumerate(plan)]
+    leaf = {b.names[0].split("_", 1)[-1]: b.shape for b in plan if b.leaf}
+    assert leaf == {"dense0_weight": (64, 8), "dense1_weight": (128, 64)}
+    n_leaf, n, share = zero.leaf_share(plan)
+    assert (n_leaf, n) == (2, 6)
+    assert share == (512 + 8192) / sum(b.padded for b in plan)
+    with open(path) as f:
+        recs = [json.loads(line) for line in f if line.strip()]
+    (comp,) = [r for r in recs if r.get("type") == "compile"
+               and r.get("program") == "train_step"]
+    assert comp["fingerprint"]["sharding"] == (
+        f"ps (2 of 6 buckets leaf-shaped, {100 * share:.1f}% of the "
+        "elements)")
+    # the state of a leaf-shaped bucket is the leaf's rows
+    for (bk, lay, _), b in zip(step.zero_layout, plan):
+        assert s[bk][0].shape == b.shape
+        assert (s[bk][0].ndim > 1) == (lay == "leaf")
 
 
 def test_sharded_step_env_knob_and_guards():
@@ -376,21 +568,22 @@ def test_optimizer_state_bytes_shard_as_params_over_n():
 
 
 # ----------------------------------------------- Module dist_sync mapping
-def _mlp_symbol():
+def _mlp_symbol(hidden=16):
     d = sym.Variable("data")
-    fc1 = sym.FullyConnected(d, num_hidden=16, name="fc1")
+    fc1 = sym.FullyConnected(d, num_hidden=hidden, name="fc1")
     act = sym.Activation(fc1, act_type="relu", name="relu1")
     fc2 = sym.FullyConnected(act, num_hidden=4, name="fc2")
     return sym.SoftmaxOutput(fc2, sym.Variable("softmax_label"),
                              name="softmax")
 
 
-def _fit_module(kvstore, optimizer="sgd", epochs=2, extra_params=()):
+def _fit_module(kvstore, optimizer="sgd", epochs=2, extra_params=(),
+                hidden=16):
     rng = onp.random.RandomState(7)
     X = rng.randn(64, 10).astype("float32")
     y = (X @ rng.randn(10, 4)).argmax(axis=1).astype("float32")
     it = mx.io.NDArrayIter(X, y, batch_size=8, shuffle=False)
-    mod = mx.mod.Module(_mlp_symbol(),
+    mod = mx.mod.Module(_mlp_symbol(hidden),
                         context=[mx.gpu(i) for i in range(8)])
     mod.bind(data_shapes=it.provide_data,
              label_shapes=it.provide_label)
@@ -450,6 +643,62 @@ def test_module_dist_sync_maps_to_sharded_updater():
     for k in a:
         for x, yv in zip(a[k], b[k]):
             onp.testing.assert_array_equal(x.asnumpy(), yv.asnumpy())
+
+
+@pytest.mark.parametrize("optimizer", ["sgd", "adam"])
+def test_module_leaf_shaped_states_interchange(optimizer, monkeypatch):
+    """A leaf-shaped bucket's state lives as rows of the leaf; on save
+    it gathers to the same legacy per-param pickle as ever, so it loads
+    into a replicated run and into a flat-bucket run and back, bit for
+    bit."""
+    import pickle
+
+    monkeypatch.setenv("MXNET_KVSTORE_BIGARRAY_BOUND", "300")
+    mod_s, p_s = _fit_module("dist_sync", optimizer, hidden=64)
+    upd = mod_s._updater
+    assert isinstance(upd, zero.ShardedBucketUpdater)
+    leafy = [b for b in upd.plan if b.leaf]
+    assert [b.names for b in leafy] == [("fc1_weight",)]
+    for b, st in zip(upd.plan, upd._states):
+        for leaf in st:
+            if getattr(leaf, "ndim", 0):
+                assert leaf.shape == b.shape
+                assert leaf.sharding.spec == \
+                    jax.sharding.PartitionSpec("data")
+    mod_l, p_l = _fit_module("local", optimizer, hidden=64)
+    with monkeypatch.context() as m:
+        m.setattr(zero, "_leaf_shaped", lambda *a: False)
+        mod_f, p_f = _fit_module("dist_sync", optimizer, hidden=64)
+    assert not any(b.leaf for b in mod_f._updater.plan)
+    for n in p_l:
+        onp.testing.assert_allclose(p_s[n], p_l[n], rtol=1e-5,
+                                    atol=1e-6, err_msg=n)
+        onp.testing.assert_array_equal(p_s[n], p_f[n], err_msg=n)
+
+    def legacy(mod):
+        states, _ = pickle.loads(mod._get_optimizer_states())
+        return {k: [x.asnumpy() for x in v] for k, v in states.items()
+                if k != "__step"}
+
+    want = legacy(mod_s)
+    assert want["fc1_weight"][0].shape == (64, 10)
+    blob = mod_s._get_optimizer_states()
+    for other in (mod_l, mod_f):
+        # leaf-shaped -> the other layout -> and back
+        other._set_optimizer_states(blob)
+        got = legacy(other)
+        assert set(got) == set(want)
+        for k in want:
+            for a, b in zip(want[k], got[k]):
+                onp.testing.assert_array_equal(a, b, err_msg=k)
+        mod_s._set_optimizer_states(other._get_optimizer_states())
+        back = legacy(mod_s)
+        for k in want:
+            for a, b in zip(want[k], back[k]):
+                onp.testing.assert_array_equal(a, b, err_msg=k)
+    # the plans tell themselves apart where a checkpoint is stamped
+    assert upd.topology()["plan_fingerprint"] != \
+        mod_f._updater.topology()["plan_fingerprint"]
 
 
 def test_module_sharded_engages_with_weight_decay():
@@ -808,20 +1057,23 @@ def test_narrow_accumulate_survives_bucket_roundtrip(dtype):
         onp.dtype(out.asnumpy().dtype).itemsize <= 4
 
 
-def test_sharded_step_compression_residual_shard_local():
+@_WIDTHS
+def test_sharded_step_compression_residual_shard_local(widths):
     """In-step 2-bit compression: residual carried as fp32 shard-local
-    state, error feedback converges training."""
+    state in its bucket's layout, error feedback converges training."""
     mesh = get_mesh((8,), ("data",))
     loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
     step, p, s = make_train_step(
-        _mlp_net(), loss_fn, optimizer="sgd", learning_rate=0.05,
+        _mlp_net(widths), loss_fn, optimizer="sgd", learning_rate=0.05,
         momentum=0.9, mesh=mesh, donate=False, optimizer_sharding="ps",
         bucket_bound=300,
         gradient_compression={"type": "2bit", "threshold": 0.05})
     rkeys = [k for k in s if k.startswith("_residual")]
-    assert len(rkeys) == 3  # one per bucket
-    for rk in rkeys:
+    assert len(rkeys) == len(step.zero_plan)  # one per bucket
+    assert len(rkeys) == (3 if widths == _MLP else 6)
+    for rk, b in zip(rkeys, step.zero_plan):
         assert s[rk].dtype == jnp.float32
+        assert s[rk].shape == b.shape
         assert s[rk].sharding.spec == jax.sharding.PartitionSpec("data")
     rng = onp.random.RandomState(0)
     X = jnp.asarray(rng.rand(32, 8).astype("float32"))

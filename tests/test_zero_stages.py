@@ -41,19 +41,27 @@ from mxnet_tpu.parallel import get_mesh, make_train_step, zero
 from mxnet_tpu.resilience.elastic import reshard_verdict, topology_block
 
 
-def _mlp_net():
+#: widths of the seeded MLP: the historic one stays flat at 8 shards
+#: (32, 16 and 4 rows); the leafy one has leaves of 64 and 128 rows,
+#: which sit alone over bucket_bound=300 and are exchanged leaf-shaped
+_MLP, _LEAFY = (32, 16, 4), (64, 128, 4)
+
+
+def _mlp_net(widths=_MLP):
     mx.random.seed(0)
     onp.random.seed(0)
     net = nn.HybridSequential()
     with net.name_scope():
-        net.add(nn.Dense(32, activation="relu"),
-                nn.Dense(16, activation="relu"), nn.Dense(4))
+        net.add(nn.Dense(widths[0], activation="relu"),
+                nn.Dense(widths[1], activation="relu"),
+                nn.Dense(widths[2]))
     net.initialize(init=mx.init.Xavier())
     net(mx.nd.zeros((1, 8)))
     return net
 
 
-def _run_stage(optimizer, stage, n_steps=6, momentum=0.9, **kw):
+def _run_stage(optimizer, stage, n_steps=6, momentum=0.9, widths=_MLP,
+               **kw):
     """Train the seeded MLP for ``n_steps`` under the given ZeRO stage
     (None = the caller's kw decide); returns (loss, step_fn, params,
     opt_state) with params still in the stage's live layout."""
@@ -62,7 +70,7 @@ def _run_stage(optimizer, stage, n_steps=6, momentum=0.9, **kw):
     if stage is not None:
         kw.update(optimizer_sharding="ps", zero_stage=stage)
     step, p, s = make_train_step(
-        _mlp_net(), loss_fn, optimizer=optimizer, learning_rate=0.1,
+        _mlp_net(widths), loss_fn, optimizer=optimizer, learning_rate=0.1,
         momentum=momentum, mesh=mesh, donate=False, autotune=False,
         bucket_bound=300, **kw)
     rng = onp.random.RandomState(0)
@@ -77,7 +85,7 @@ def _run_stage(optimizer, stage, n_steps=6, momentum=0.9, **kw):
 
 def _named(step, p):
     """Named host params regardless of live layout (stage 3 gathers
-    its flat buckets back first); block auto-prefix differs between
+    its buckets back first); block auto-prefix differs between
     builds, align by suffix."""
     if getattr(step, "zero_stage", None) == 3:
         p = zero.gather_stage3_params(
@@ -86,20 +94,30 @@ def _named(step, p):
 
 
 # ------------------------------------------------------ bit-identity
+@pytest.mark.parametrize("widths", [_MLP, _LEAFY], ids=["mlp", "leafy"])
 @pytest.mark.parametrize("optimizer,momentum", [
     ("sgd", 0.0),   # plain sgd
     ("sgd", 0.9),   # sgd + momentum slot
     ("adam", 0.9),  # two slots + bias correction
-    ("lars", 0.9),  # segment-wise trust ratios over the flat bucket
+    ("lars", 0.9),  # segment-wise trust ratios over the bucket
 ])
-def test_stages_bit_identical(optimizer, momentum):
+def test_stages_bit_identical(optimizer, momentum, widths, monkeypatch):
     finals = {}
     losses = {}
     for stage in (1, 2, 3):
         loss, step, p, _ = _run_stage(optimizer, stage,
-                                      momentum=momentum)
+                                      momentum=momentum, widths=widths)
         losses[stage] = loss
         finals[stage] = _named(step, p)
+        layouts = [lay for _, lay, _ in step.zero_layout]
+        assert layouts.count("leaf") == (2 if widths == _LEAFY else 0)
+        if stage == 3:
+            # the parameters themselves live by bucket, each in its
+            # bucket's shape, rows over the data axis
+            for (bk, _, _), b in zip(step.zero_layout, step.zero_plan):
+                assert p[bk].shape == b.shape
+                assert p[bk].sharding.spec == \
+                    jax.sharding.PartitionSpec("data")
     assert losses[1] == losses[2] == losses[3]
     for stage in (1, 3):
         assert set(finals[stage]) == set(finals[2])
@@ -107,6 +125,18 @@ def test_stages_bit_identical(optimizer, momentum):
             onp.testing.assert_array_equal(
                 finals[stage][k], finals[2][k],
                 err_msg=f"stage {stage} vs 2 at {k}")
+    if widths == _LEAFY:
+        # the same ladder over flat buckets (every bucket's layout
+        # before): one algorithm, so the same bits
+        monkeypatch.setattr(zero, "_leaf_shaped", lambda *a: False)
+        loss, step, p, _ = _run_stage(optimizer, 2, momentum=momentum,
+                                      widths=widths)
+        assert {lay for _, lay, _ in step.zero_layout} == {"flat"}
+        assert loss == losses[2]
+        flat = _named(step, p)
+        for k in finals[2]:
+            onp.testing.assert_array_equal(flat[k], finals[2][k],
+                                           err_msg=f"flat vs leaf {k}")
 
 
 def test_stage2_is_the_unset_default_program():
@@ -240,6 +270,55 @@ def test_stage3_param_checkpoint_roundtrip_bit_exact():
                                        onp.asarray(p[bk]), err_msg=bk)
         assert back[bk].sharding.spec == \
             jax.sharding.PartitionSpec("data")
+
+
+@pytest.mark.parametrize("stage", [2, 3])
+def test_state_saved_flat_is_taken_by_the_leaf_shaped_step(stage,
+                                                           monkeypatch):
+    """An ``opt_state`` (and, at stage 3, the params) saved by bucket
+    before leaf-shaped buckets holds every ``_bucket<i>`` 1-D.  Its
+    content is the leaf's, row-major, so the new step reshapes it and
+    goes on bit for bit; anything else is refused with the reason."""
+    kw = dict(momentum=0.9, widths=_LEAFY)
+    _, step, p3, s3 = _run_stage("adam", stage, n_steps=3, **kw)
+    _, _, p6, s6 = _run_stage("adam", stage, n_steps=6, **kw)
+    with monkeypatch.context() as m:  # the flat run: same 3 steps
+        m.setattr(zero, "_leaf_shaped", lambda *a: False)
+        _, fstep, fp3, fs3 = _run_stage("adam", stage, n_steps=3, **kw)
+    assert {lay for _, lay, _ in fstep.zero_layout} == {"flat"}
+    leafy = [bk for bk, lay, _ in step.zero_layout if lay == "leaf"]
+    assert len(leafy) == 2
+    for bk in leafy:
+        assert fs3[bk][0].ndim == 1 and s3[bk][0].ndim == 2
+        onp.testing.assert_array_equal(
+            onp.asarray(fs3[bk][0]).reshape(s3[bk][0].shape),
+            onp.asarray(s3[bk][0]))
+    # ... three more steps of the NEW step from the flat-saved trees
+    rng = onp.random.RandomState(0)
+    X = jnp.asarray(rng.rand(32, 8).astype("float32"))
+    y = jnp.asarray(rng.randint(0, 4, (32,)).astype("float32"))
+    p, s = fp3, fs3
+    if stage == 2:
+        # named params: the block's auto-prefix differs between builds
+        mine = {k.split("_", 1)[-1]: k for k in p3}
+        p = {mine[k.split("_", 1)[-1]]: v for k, v in fp3.items()}
+    for i in range(3, 6):
+        _, p, s = step(p, s, X, y, jax.random.key(0), float(i + 1))
+    for bk in s6:
+        for a, b in zip(jax.tree_util.tree_leaves(s[bk]),
+                        jax.tree_util.tree_leaves(s6[bk])):
+            onp.testing.assert_array_equal(onp.asarray(a),
+                                           onp.asarray(b), err_msg=bk)
+    want = _named(step, p6)
+    for k, v in _named(step, p).items():
+        onp.testing.assert_array_equal(v, want[k], err_msg=k)
+    # a bucket laid out under another plan is refused, not mis-laid
+    bad = dict(s3)
+    bad[leafy[0]] = tuple(a[:-8] if a.ndim else a for a in s3[leafy[0]])
+    with pytest.raises(MXNetError, match="another bucket plan"):
+        step(p3, bad, X, y, jax.random.key(0), 4.0)
+    # and what already is in the plan's layout is handed through as is
+    assert zero.adopt_layout(step.zero_plan, s3) is s3
 
 
 # ------------------------------------------------------- env plumbing
