@@ -1671,10 +1671,6 @@ def _collectives_probe(n_devices):
     ``optimizer_sharding="ps"`` — and print ONE JSON line with each
     program's HLO collective counts/bytes.  Runs in a subprocess
     because the device count must be forced before JAX initializes."""
-    # the probe DEFINES its two arms: a caller-level
-    # MXNET_OPTIMIZER_SHARDING (force-on or force-off) would make both
-    # arms compile the same program and the A/B silently lie
-    os.environ.pop("MXNET_OPTIMIZER_SHARDING", None)
     import jax
 
     jax.config.update("jax_platforms", "cpu")

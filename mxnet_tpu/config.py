@@ -115,27 +115,21 @@ register_env("MXNET_KVSTORE_BIGARRAY_BOUND", 1000000, int,
              "ps-lite bound above which arrays are sliced across "
              "servers.  Fewer, larger buckets mean fewer collective "
              "launches; the collectives-budget CI gate runs at 4e6.")
-register_env("MXNET_OPTIMIZER_SHARDING", "", str,
-             "Sharded-server optimizer (ZeRO-1 as the TPU-native "
-             "parameter server): 'ps'/'1' forces it on for every "
-             "make_train_step/Module mesh, '0'/'off' forces it off "
-             "(overriding the kvstore='dist_sync' mapping and explicit "
-             "opt-ins), empty defers to the caller.  Gradients "
-             "reduce-scatter in flat buckets, the optimizer updates "
-             "only the locally-owned shard (state lives sharded), and "
-             "the params all-gather back.")
 register_env("MXNET_ZERO_STAGE", "", str,
              "ZeRO stage of the sharded-server exchange "
-             "(optimizer_sharding='ps', parallel.zero): '1' = classic "
-             "ZeRO-1 (per-bucket all-reduce, grads replicated, "
-             "optimizer state sharded), '2' = gradient shards "
-             "(per-bucket reduce-scatter — the default program when "
-             "unset), '3' = parameter shards (params live sharded as "
-             "flat buckets; the forward all-gathers each bucket with "
-             "bucket-wise prefetch and nothing gathers back).  Setting "
-             "a stage also opts the step into sharding under a mesh; "
-             "unset defers to the caller's zero_stage/optimizer_"
-             "sharding arguments.  Unknown values raise.")
+             "(optimizer_sharding='ps', parallel.zero.resolve_stage): "
+             "'1' = classic ZeRO-1 (per-bucket all-reduce, grads "
+             "replicated, optimizer state sharded), '2' = gradient "
+             "shards (per-bucket reduce-scatter: the 'ps' program), "
+             "'3' = parameter shards (params live sharded by bucket; "
+             "the forward all-gathers each bucket and nothing gathers "
+             "back).  A stage overrides the caller's "
+             "zero_stage/optimizer_sharding and opts every meshed "
+             "make_train_step in (for Module, whose updater is ZeRO-1 "
+             "whatever the stage, it forces the sharded updater); '0' "
+             "forces the replicated step and updater over every opt-in "
+             "and over the kvstore='dist_sync' mapping; unset defers to "
+             "the caller.  Unknown values raise.")
 register_env("MXNET_COLLECTIVES_BUDGET", 8, int,
              "Per-step collective-launch budget the dp dryrun verdict "
              "gates against under optimizer_sharding='ps': at most "

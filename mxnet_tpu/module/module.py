@@ -276,25 +276,23 @@ class Module(BaseModule):
         """Map ``kvstore='dist_*'`` (whose reference semantics ARE the
         server-side optimizer on key shards, kvstore_dist_server.h:346)
         to the sharded-server updater over this module's data mesh.
-        MXNET_OPTIMIZER_SHARDING overrides in both directions.
+        MXNET_ZERO_STAGE overrides in both directions (0 off; 1/2/3 on:
+        the updater is ZeRO-1 whatever the stage).
         Per-param lr_mult/wd_mult ARE supported (the updater
         partitions buckets by effective (lr, wd)); semantics the flat
         buckets cannot reproduce — per-update lr schedules, stochastic
         rules, multi-precision masters, fused/eager state-layout
         mismatches — fall back to the eager per-param Updater with a
         logged reason."""
-        from ..parallel.zero import (resolve_sharding_env,
-                                     sharding_rule_reasons)
+        from ..parallel.zero import resolve_stage, sharding_rule_reasons
 
-        env = resolve_sharding_env()
-        if env is False:
-            return None
         kv_name = kvstore if isinstance(kvstore, str) else \
             getattr(kvstore, "type", "")
-        if env != "ps" and not str(kv_name).startswith("dist"):
+        # one device: nothing to shard over, whatever the kvstore says
+        asked = "ps" if str(kv_name).startswith("dist") \
+            and self._mesh is not None else None
+        if resolve_stage(asked, None, self._mesh) is None:
             return None
-        if self._mesh is None:
-            return None  # one device: nothing to shard over
         reasons = sharding_rule_reasons(optimizer)
         if reasons:
             self.logger.warning(

@@ -75,7 +75,7 @@ from ..base import MXNetError
 
 __all__ = ["Bucket", "plan_buckets", "flatten_bucket", "unflatten_bucket",
            "bucket_segments", "shard_slice", "collective_bytes",
-           "resolve_sharding_env", "resolve_zero_stage",
+           "resolve_stage",
            "plan_fingerprint", "flat_variant_key",
            "resolve_bucket_variant", "analytic_exchange_bytes",
            "bucket_layout", "leaf_share", "adopt_layout",
@@ -463,44 +463,55 @@ def plan_fingerprint(plan, n_shards, stage=None):
     return h.hexdigest()[:16]
 
 
-def resolve_sharding_env():
-    """The MXNET_OPTIMIZER_SHARDING tri-state: "ps" forced on, False
-    forced OFF (overriding kvstore mapping / explicit opt-in), None
-    unset (caller decides).  Unknown values raise — a typo'd force-on
-    silently training replicated is the silent-green failure mode the
-    dryrun case filter also rejects."""
-    from ..config import get_env
-
-    raw = str(get_env("MXNET_OPTIMIZER_SHARDING")).strip().lower()
-    if raw in ("ps", "1", "on", "true", "yes"):
-        return "ps"
-    if raw in ("0", "off", "false", "no"):
-        return False
-    if raw:
-        raise MXNetError(
-            f"MXNET_OPTIMIZER_SHARDING={raw!r} is not a recognized "
-            "value (use 'ps' to force sharding on, '0' to force it "
-            "off, or unset)")
-    return None
-
-
-def resolve_zero_stage():
-    """The MXNET_ZERO_STAGE knob: 1/2/3 select the exchange stage
-    (all-reduce grads / reduce-scatter grads / parameter shards), None
-    means unset (the caller's ``zero_stage`` argument decides, default
-    stage 2 under sharding).  Unknown values raise — a typo'd stage
-    silently training the wrong exchange is the same silent-green
-    failure mode MXNET_OPTIMIZER_SHARDING rejects."""
+def _env_stage():
+    """MXNET_ZERO_STAGE: None unset, 0 forced off, 1/2/3 the stage.
+    Unknown values raise — a typo'd stage silently training the wrong
+    exchange is the silent-green failure mode the dryrun case filter
+    also rejects."""
     from ..config import get_env
 
     raw = str(get_env("MXNET_ZERO_STAGE")).strip()
     if not raw:
         return None
-    if raw in ("1", "2", "3"):
+    if raw in ("0", "1", "2", "3"):
         return int(raw)
     raise MXNetError(
         f"MXNET_ZERO_STAGE={raw!r} is not a recognized stage (use 1, "
-        "2 or 3, or unset)")
+        "2 or 3, 0 to force the replicated step, or unset)")
+
+
+def resolve_stage(optimizer_sharding=None, zero_stage=None, mesh=None,
+                  param_spec=None):
+    """The ZeRO ladder's one spelling: ``None`` (replicated) or the
+    stage 1/2/3 of the sharded exchange.  ``optimizer_sharding="ps"`` is
+    stage 2 (that program bit for bit) unless ``zero_stage`` names
+    another; MXNET_ZERO_STAGE's 1/2/3 override the caller and opt in,
+    its 0 overrides every opt-in.  No mesh: warns and stays replicated."""
+    if optimizer_sharding not in (None, False, "", "ps"):
+        raise MXNetError(
+            f"unknown optimizer_sharding {optimizer_sharding!r} (only "
+            "'ps')")
+    if zero_stage not in (None, 1, 2, 3):
+        raise MXNetError(
+            f"unknown zero_stage {zero_stage!r} (use 1, 2 or 3)")
+    env = _env_stage()
+    stage = zero_stage if env is None else env
+    if stage is None and optimizer_sharding == "ps":
+        stage = 2  # the default exchange: reduce-scattered gradients
+    if not stage:
+        return None
+    if mesh is None:
+        import warnings
+
+        warnings.warn(
+            "optimizer_sharding='ps' needs a mesh (nothing to shard "
+            "over on one device) — step stays replicated", stacklevel=3)
+        return None
+    if param_spec:
+        raise MXNetError(
+            "optimizer_sharding='ps' does not compose with param_spec "
+            "(tensor parallelism) yet")
+    return stage
 
 
 # ------------------------------------------------- stage-3 param layout

@@ -471,7 +471,10 @@ class ModelHost:
             new._suppress_health_gauges = True  # the host aggregates
             new.start(warm=True)
             probe = onp.zeros(new.item_shape, new.dtype)
-            out = new.submit(probe).result(timeout=probe_timeout)
+            # the probe carries the swap's own budget, not the model's
+            # SLO: a warm latency over the SLO must not shed it
+            out = new.submit(probe, deadline_ms=probe_timeout * 1e3) \
+                .result(timeout=probe_timeout)
             out = onp.asarray(out)
             if onp.issubdtype(out.dtype, onp.floating) \
                     and not onp.isfinite(out).all():

@@ -216,6 +216,30 @@ def test_swap_keeps_per_model_overrides_and_guards_unload(tmp_path):
         host.close_all()
 
 
+def test_swap_probe_carries_the_swaps_budget_not_the_slo(tmp_path):
+    """A model whose SLO sits under its own warm latency (the override
+    survives a swap by design) still swaps: the warm probe is the
+    swap's request, admitted against ``probe_timeout``, not shed as
+    "estimated completion exceeds deadline" against the model's SLO."""
+    p1, _ = _export(tmp_path, "v1", seed=7)
+    p2, net2 = _export(tmp_path, "v2", seed=8)
+    host = ModelHost(server_kw={"slo_ms": 30000, "coalesce_ms": 0.5})
+    try:
+        host.load("model", p1, slo_ms=1e-3)  # 1 us: under any batch
+        with pytest.raises(ServeRejected) as shed:
+            host.submit(onp.zeros(3, "float32"))
+        assert shed.value.reason == "deadline"
+        host.swap("model", p2)
+        assert host.stats["swaps"] == 1 and host.stats["rollbacks"] == 0
+        assert host.get("model").slo_ms == 1e-3
+        x = onp.random.rand(3).astype("float32")
+        onp.testing.assert_allclose(
+            host.submit(x, deadline_ms=30000).result(30),
+            net2(nd.array(x[None])).asnumpy()[0], rtol=1e-5, atol=1e-5)
+    finally:
+        host.close_all()
+
+
 # -------------------------------------------------------- HTTP frontend
 def test_frontend_predict_health_metrics_and_rejections():
     srv = ModelServer(_np_model(delay=0.002), (3,), max_batch=4,

@@ -91,15 +91,15 @@ def test_fp8_qdq_snaps_and_straight_through():
 def test_scale_bookkeeping_shared_with_loss_scaler():
     """The loss-scale verdict helper lives in pallas_opt beside
     fp8_delayed_scale (one module, so the two backoff rules cannot
-    drift) and parallel re-exports it."""
+    drift) and the train step calls it, at its one bookkeeping site."""
     import inspect
 
-    from mxnet_tpu import parallel as par
+    from mxnet_tpu.parallel import train_step
 
-    # make_train_step binds the dynamic-loss-scale verdict to the
+    # the step body binds the dynamic-loss-scale verdict to the
     # pallas_opt helper rather than an inline copy
-    assert "_scale_bookkeeping = _po.scale_bookkeeping" in \
-        inspect.getsource(par)
+    assert inspect.getsource(train_step).count(
+        "_po.scale_bookkeeping(") == 1
     s, g = po.scale_bookkeeping(jnp.bool_(False), jnp.float32(8.0),
                                 jnp.int32(5))
     assert float(s) == 4.0 and int(g) == 0  # overflow halves, resets

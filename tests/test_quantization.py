@@ -602,7 +602,9 @@ def test_fleet_residency_surfaces_quantized(tmp_path):
         res = host.residency()["models"]["m"]
         assert res["quantized"] is True
         assert res["param_dtypes"].get("int8", 0) >= 2
-        out = host.submit(x[0]).result(timeout=30)
+        # the request waits as long as its result is waited for: the
+        # default 100 ms SLO sheds it on a machine busy with six workers
+        out = host.submit(x[0], deadline_ms=30000).result(timeout=30)
         assert onp.isfinite(onp.asarray(out)).all()
     finally:
         host.close_all()

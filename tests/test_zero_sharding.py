@@ -308,6 +308,92 @@ def test_sharded_step_parity_under_dynamic_loss_scaling(widths):
         assert float(onp.asarray(a)) == float(onp.asarray(b))
 
 
+def _trajectory(poison, **kw):
+    """Four steps of the leafy MLP on dp(8) from the seeded weights,
+    the third batch carrying one ``inf`` where ``poison``: the loss,
+    params (by name suffix) and opt_state after every step, the
+    start included as entry 0."""
+    mesh = get_mesh((8,), ("data",))
+    step, p, s = make_train_step(
+        _mlp_net(_LEAFY), gluon.loss.SoftmaxCrossEntropyLoss(),
+        optimizer="sgd", learning_rate=0.1, momentum=0.9, mesh=mesh,
+        donate=False, **kw)
+    rng = onp.random.RandomState(0)
+    X = rng.rand(32, 8).astype("float32")
+    y = jnp.asarray(rng.randint(0, 4, (32,)).astype("float32"))
+    X_bad = X.copy()
+    X_bad[3, 2] = onp.inf
+
+    def snap(loss, p, s):
+        return (None if loss is None else onp.asarray(loss),
+                {k.split("_", 1)[-1]: onp.asarray(v) for k, v in p.items()},
+                jax.tree_util.tree_map(onp.asarray, s))
+
+    traj = [snap(None, p, s)]
+    for i in range(4):
+        xb = X_bad if poison and i == 2 else X
+        loss, p, s = step(p, s, jnp.asarray(xb), y, jax.random.key(0),
+                          float(i + 1))
+        traj.append(snap(loss, p, s))
+    return traj
+
+
+@pytest.mark.parametrize("loss_scale,nan_guard", [
+    (None, False), (None, True), (128.0, False), (128.0, True),
+    ("dynamic", False),  # dynamic scaling turns the guard off
+])
+def test_both_arms_take_the_same_steps(loss_scale, nan_guard):
+    """The replicated step and the ``ps`` step are one sequence (scale,
+    gradient, verdict, update, keep, bookkeeping): from the same
+    weights they take the same four steps under every loss-scale mode,
+    with and without the guard, and a poisoned batch leaves params and
+    state as they came in both."""
+    poison = nan_guard or loss_scale == "dynamic"
+    rep = _trajectory(poison, loss_scale=loss_scale, nan_guard=nan_guard)
+    ps = _trajectory(poison, loss_scale=loss_scale, nan_guard=nan_guard,
+                     optimizer_sharding="ps", bucket_bound=300)
+    assert "leaf" in _layouts(ps[0][2]).values()
+    for i in range(1, 5):
+        (l_r, p_r, s_r), (l_s, p_s, s_s) = rep[i], ps[i]
+        onp.testing.assert_array_equal(l_r, l_s, err_msg=f"loss {i}")
+        assert set(p_r) == set(p_s)
+        for k in p_r:
+            onp.testing.assert_array_equal(p_r[k], p_s[k],
+                                           err_msg=f"{k} after step {i}")
+        for key in ("_loss_scale", "_bad_steps"):
+            assert (key in s_r) == (key in s_s)
+            if key in s_r:
+                for a, b in zip(jax.tree_util.tree_leaves(s_r[key]),
+                                jax.tree_util.tree_leaves(s_s[key])):
+                    onp.testing.assert_array_equal(
+                        a, b, err_msg=f"{key} after step {i}")
+    assert ("_bad_steps" in rep[0][2]) == nan_guard
+    assert ("_loss_scale" in rep[0][2]) == (loss_scale == "dynamic")
+    if not poison:
+        assert all(onp.isfinite(t[0]) for t in rep[1:])
+        return
+    for traj in (rep, ps):
+        (_, p2, s2), (l3, p3, s3), (l4, p4, _) = traj[2], traj[3], traj[4]
+        assert not onp.isfinite(l3) and onp.isfinite(l4)
+        for k in p2:
+            onp.testing.assert_array_equal(p3[k], p2[k], err_msg=k)
+            assert not onp.array_equal(p4[k], p3[k]), k
+        for k in s2:
+            if k.startswith("_") and not k.startswith("_bucket"):
+                continue  # the bookkeeping is what moves
+            for a, b in zip(jax.tree_util.tree_leaves(s3[k]),
+                            jax.tree_util.tree_leaves(s2[k])):
+                onp.testing.assert_array_equal(a, b, err_msg=k)
+        if nan_guard:
+            assert [int(t[2]["_bad_steps"]) for t in traj] == \
+                [0, 0, 0, 1, 0]
+        else:
+            scales = [float(t[2]["_loss_scale"][0]) for t in traj]
+            assert scales == [65536.0] * 3 + [32768.0] * 2
+            assert [int(t[2]["_loss_scale"][1]) for t in traj] == \
+                [0, 1, 2, 0, 1]
+
+
 def test_zero_layout_and_runlog_name_the_leaf_shaped_share(tmp_path):
     """What the plan decided is readable off the step and off the
     RunLog's compile record, with no program text."""
@@ -354,21 +440,24 @@ def test_sharded_step_env_knob_and_guards():
     loss_fn = gluon.loss.L2Loss()
     net = nn.Dense(4, in_units=8)
     net.initialize()
-    # env force-ON (the MXNET_OPTIMIZER_SHARDING knob)
-    os.environ["MXNET_OPTIMIZER_SHARDING"] = "ps"
+    # env force-ON: MXNET_ZERO_STAGE=1/2/3 opts a meshed step in
+    os.environ["MXNET_ZERO_STAGE"] = "2"
     try:
-        _, _, s = make_train_step(net, loss_fn, mesh=mesh, donate=False)
+        step, _, s = make_train_step(net, loss_fn, mesh=mesh, donate=False)
         assert any(k.startswith("_bucket") for k in s)
+        assert step.zero_stage == 2
     finally:
-        del os.environ["MXNET_OPTIMIZER_SHARDING"]
-    # env force-OFF beats the explicit opt-in
-    os.environ["MXNET_OPTIMIZER_SHARDING"] = "0"
+        del os.environ["MXNET_ZERO_STAGE"]
+    # env force-OFF (0) beats the explicit opt-in, by either keyword
+    os.environ["MXNET_ZERO_STAGE"] = "0"
     try:
-        _, _, s = make_train_step(net, loss_fn, mesh=mesh, donate=False,
-                                  optimizer_sharding="ps")
-        assert not any(k.startswith("_bucket") for k in s)
+        for kw in (dict(optimizer_sharding="ps"), dict(zero_stage=3)):
+            step, _, s = make_train_step(net, loss_fn, mesh=mesh,
+                                         donate=False, **kw)
+            assert not any(k.startswith("_bucket") for k in s)
+            assert getattr(step, "zero_stage", None) is None
     finally:
-        del os.environ["MXNET_OPTIMIZER_SHARDING"]
+        del os.environ["MXNET_ZERO_STAGE"]
     # tp param_spec does not compose
     from mxnet_tpu.parallel import P
 
@@ -740,9 +829,9 @@ def test_sharded_live_num_update_clock_matches_eager():
 
 
 def test_sharding_env_rejects_unknown_values(monkeypatch):
-    monkeypatch.setenv("MXNET_OPTIMIZER_SHARDING", "sharded")
+    monkeypatch.setenv("MXNET_ZERO_STAGE", "sharded")
     with pytest.raises(MXNetError, match="not a recognized"):
-        zero.resolve_sharding_env()
+        zero.resolve_stage("ps", None, get_mesh((8,), ("data",)))
 
 
 def test_stale_step_entry_loses_to_fresh_optimizer_counters():
@@ -959,13 +1048,16 @@ def test_sharded_dump_optimizer_seeds_eager_counters():
     assert eager.optimizer._index_update_count["fc1_bias"] == upd._t + 1
 
 
-def test_module_sharding_env_force_off_and_fallbacks():
-    os.environ["MXNET_OPTIMIZER_SHARDING"] = "0"
-    try:
-        mod, _ = _fit_module("dist_sync", epochs=1)
-        assert not isinstance(mod._updater, zero.ShardedBucketUpdater)
-    finally:
-        del os.environ["MXNET_OPTIMIZER_SHARDING"]
+def test_module_sharding_env_force_off_and_fallbacks(monkeypatch):
+    # 0 beats the kvstore='dist_sync' mapping; any stage forces the
+    # sharded updater (ZeRO-1 whatever the stage) on a local kvstore
+    monkeypatch.setenv("MXNET_ZERO_STAGE", "0")
+    mod, _ = _fit_module("dist_sync", epochs=1)
+    assert not isinstance(mod._updater, zero.ShardedBucketUpdater)
+    monkeypatch.setenv("MXNET_ZERO_STAGE", "3")
+    mod, _ = _fit_module("local", epochs=1)
+    assert isinstance(mod._updater, zero.ShardedBucketUpdater)
+    monkeypatch.delenv("MXNET_ZERO_STAGE")
     # semantics the flat buckets cannot reproduce fall back LOUDLY to
     # the eager updater instead of silently changing the math
     mod, _ = _fit_module("dist_sync", optimizer="nadam", epochs=1)
