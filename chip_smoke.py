@@ -266,7 +266,7 @@ def phase_train(args, sz, dev, net):
     n_kernels = text.count("tpu_custom_call")
     # the kernels stream flat buckets; a leaf-shaped bucket (one leaf
     # kept as its own rows) declines them and runs the jnp rule
-    n_flat = sum(lay == "flat" for _, lay, _ in step2.zero_layout)
+    n_flat = sum(lay == "flat" for _, lay, *_ in step2.zero_layout)
     if not args.rehearse:
         check(n_kernels == n_flat,
               f"{n_kernels} tpu_custom_call in the lowered step for "

@@ -86,7 +86,9 @@ zero_smoke() {
     # ZeRO stage-ladder gate on the virtual 8-dev CPU mesh, seconds:
     # the stage 1/2/3 bit-identity drill over sgd/sgd-mom/adam/lars
     # (stage 3's AD-transposed reduce-scatter must equal stage 2's
-    # explicit psum_scatter EXACTLY), the RS+AG bytes <= 1.05x
+    # explicit psum_scatter EXACTLY over flat buckets; where a
+    # leaf-shaped bucket rides the ring, stages 1 and 2 exactly and
+    # stage 3 within the sum's order), the RS+AG bytes <= 1.05x
     # analytic budget, per-chip param bytes = total/N, the compiled
     # forward's per-bucket all-gather/compute interleave + Perfetto
     # export, the stage-salted fingerprint refusing a stage-2 resume,

@@ -263,10 +263,15 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
     whole tiles (``step_fn.zero_layout``).  The rule updates only the
     locally-owned shard; ``opt_state`` is by bucket and lives sharded.
     Stage 1 sums each bucket and slices its shard off, stage 2
-    reduce-scatters it, both gather the updated shards back; stage 3
-    shards the params too: the params pytree is ``{"_bucket<i>": array}``
+    reduce-scatters it, both gather the updated shards back; both send
+    a leaf-shaped bucket round the axis hop by hop instead
+    (``zero.ring_reduce_scatter`` / ``ring_gather``: asynchronous hops
+    that run under the backward pass).  Stage 3 shards the params too:
+    the params pytree is ``{"_bucket<i>": array}``
     (``zero.gather_stage3_params(step_fn.zero_plan, params)`` names it
-    again).  The stages end bit for bit where each other does.  A
+    again).  Stages 1 and 2 end bit for bit where each other does;
+    stage 3 too where every bucket is flat, and within float32 rounding
+    of them where a bucket rides the ring (its sum's order).  A
     by-bucket tree saved with every entry 1-D is reshaped once
     (``zero.adopt_layout``).  Each device's forward sees its local batch
     shard, so BatchNorm statistics are per shard, where the replicated

@@ -266,6 +266,8 @@ class ShardedExchange:
         self.opt, self.stage, self.mesh, self.axis = opt, stage, mesh, axis
         self.n = int(mesh.shape[axis])
         self.bucket_bound, self.threshold = bucket_bound, threshold
+        # the way round the axis that a leaf-shaped bucket's hops take
+        self.ring = _zero.ring_order(mesh, axis)
 
     def init_state(self, params):
         _zero.check_bucket_rule(self.opt)
@@ -366,6 +368,13 @@ class ShardedExchange:
                 # bucket, interleaved with the backward compute: the
                 # gradient arrives summed and scattered
                 g_sh = grads[bk]
+            elif _zero.rides_ring(b, self.stage):
+                # hop by hop round the ring, each hop asynchronous and
+                # tied to this leaf's gradient alone: the backward pass
+                # of the layers below runs under it (stage 1 takes the
+                # same hops, so the two stages stay bit for bit equal)
+                g_sh = _zero.ring_reduce_scatter(
+                    grads[b.names[0]], self.axis, self.ring, self.idx)
             elif self.stage == 1:
                 with jax.named_scope("mx_exchange"):
                     g_sh = _zero.shard_slice(
@@ -373,9 +382,7 @@ class ShardedExchange:
                                      self.axis), self.n, self.idx)
             else:
                 # one reduce-scatter for the whole bucket replaces
-                # len(b.names) per-tensor all-reduces; a leaf-shaped
-                # bucket goes in as the backward pass left it and
-                # comes out as its rows
+                # len(b.names) per-tensor all-reduces
                 with jax.named_scope("mx_exchange"):
                     g_sh = jax.lax.psum_scatter(
                         _zero.flatten_bucket(b, grads), self.axis,
@@ -430,7 +437,12 @@ class ShardedExchange:
             # params stay sharded: the updated shard IS the new param
             # bucket (the next forward's prefetch gathers it)
             return {u.key: w}, new_s
-        return _zero.gather_bucket(self.plan[u.index], w, self.axis), new_s
+        b = self.plan[u.index]
+        if _zero.rides_ring(b, self.stage):
+            name = b.names[0]
+            return {name: _zero.ring_gather(u.weight[name], w, self.axis,
+                                            self.ring, self.idx)}, new_s
+        return _zero.gather_bucket(b, w, self.axis), new_s
 
     def mean_loss(self, loss):
         with jax.named_scope("mx_exchange"):
@@ -544,14 +556,16 @@ class HostStep:
         if ex.stage is not None:
             # ... and how much of the exchange keeps its leaves' shapes
             n_leaf, n_buckets, share = _zero.leaf_share(ex.plan)
+            n_ring, _, ring = _zero.ring_share(ex.plan, ex.stage)
             self._sharding += (
                 f" ({n_leaf} of {n_buckets} buckets leaf-shaped, "
-                f"{100 * share:.1f}% of the elements)")
+                f"{100 * share:.1f}% of the elements; {n_ring} by the "
+                f"ring, {100 * ring:.1f}%)")
             # the layout contract for checkpointing/eval callers: under
             # stage 3 the params pytree is by bucket
             # (zero.gather_stage3_params reassembles the named tree)
             self.zero_stage, self.zero_plan = ex.stage, ex.plan
-            self.zero_layout = _zero.bucket_layout(ex.plan)
+            self.zero_layout = _zero.bucket_layout(ex.plan, ex.stage)
         self._seen, self._last = set(), None
         self._nm_period = _nm.sample_period() if numerics_on else 0
         self._nm_step = self._calls = 0

@@ -30,18 +30,29 @@ picks from shapes alone (:func:`_leaf_shaped`):
 * **leaf** — ONE leaf over the bound (or left alone by its
   neighbours) whose rows divide over the shards: packing it would pack
   nothing, so it is never reshaped.  The gradient goes into
-  ``psum_scatter(scatter_dimension=0, tiled=True)`` as the backward
-  pass left it, the owned shard of the weights is a ``dynamic_slice``
-  of rows that fuses into the update, the optimizer's state is kept as
-  those rows, and ``all_gather(tiled=True)`` returns the leaf as the
-  forward pass reads it.  What the chip runs: a native
-  ``reduce-scatter`` (a quarter of the bytes a chip receives on four
-  chips) for the largest leaves and for convolution kernels, the
-  compiler's fused ``all-reduce-scatter`` (plus a small
-  ``collective-permute`` that realigns rows) for mid-sized 2-D leaves;
-  no 1-D form of the leaf anywhere.  Two whole-leaf copies remain that
-  no layout removes: a donated leaf is copied at the program's start,
-  and a gathered result that is a program output is copied into it.
+  scatter as the backward pass left it, the owned shard of the weights
+  is a ``dynamic_slice`` of rows that fuses into the update, the
+  optimizer's state is kept as those rows, and the gather returns the
+  leaf as the forward pass reads it; no 1-D form of the leaf anywhere.
+  Who scatters and gathers it (:func:`rides_ring`):
+
+  - the train step at stages 1 and 2 sends it **round the ring**
+    (:func:`ring_reduce_scatter`, :func:`ring_gather`): n-1 hops of
+    ``ppermute`` each, in halves both ways round, over physical
+    neighbours (:func:`ring_order`).  On the chip every hop is an
+    asynchronous ``collective-permute`` that depends on its leaf's
+    gradient alone, so the scheduler runs the backward pass of the
+    layers below under it; the gathered rows are written into the
+    step's own (donated) weights, so no whole-leaf copy is left.
+  - stage 3 (its gather is the forward pass's, its scatter that
+    gather's transpose) and the Module-side updater (whose program has
+    no backward pass to hide a hop under) keep the native collectives:
+    ``psum_scatter(scatter_dimension=0, tiled=True)`` and
+    ``all_gather(tiled=True)``.  What the chip runs for those: a native
+    ``reduce-scatter``, synchronous, for the largest leaves and for
+    convolution kernels, the compiler's fused ``all-reduce-scatter``
+    for mid-sized 2-D leaves, and two whole-leaf copies (a donated
+    leaf at the program's start, a gathered result into the output).
 
 This module owns the pieces shared by ``make_train_step``'s
 ``optimizer_sharding="ps"`` path and the Module-side
@@ -54,6 +65,9 @@ This module owns the pieces shared by ``make_train_step``'s
 * :func:`flatten_bucket` / :func:`unflatten_bucket` /
   :func:`shard_slice` / :func:`gather_bucket` — the ONE copy of either
   layout, which every stage and both callers go through;
+* :func:`ring_order` / :func:`ring_reduce_scatter` / :func:`ring_gather`
+  — a leaf-shaped bucket's exchange in the train step, hop by hop;
+  :func:`ring_share` reports how much of a plan takes it;
 * :func:`adopt_layout` — a by-bucket tree saved flat, taken into the
   plan's layout (a reshape) or refused;
 * :func:`collective_bytes` — the HLO collective counter (moved here
@@ -78,7 +92,8 @@ __all__ = ["Bucket", "plan_buckets", "flatten_bucket", "unflatten_bucket",
            "resolve_stage",
            "plan_fingerprint", "flat_variant_key",
            "resolve_bucket_variant", "analytic_exchange_bytes",
-           "bucket_layout", "leaf_share", "adopt_layout",
+           "bucket_layout", "leaf_share", "ring_share", "adopt_layout",
+           "ring_order", "ring_reduce_scatter", "ring_gather",
            "stage3_param_keys", "shard_stage3_params",
            "gather_stage3_params", "overlap_report",
            "ShardedBucketUpdater"]
@@ -151,20 +166,41 @@ def _leaf_shaped(shapes, dtype, n_shards):
     return rows % (int(n_shards) * tile) == 0
 
 
-def bucket_layout(plan):
-    """``[(bucket key, "leaf" | "flat", elements)]`` of a plan: what
-    ``step_fn.zero_layout`` reports."""
-    return [(k, b.layout, b.padded)
+def rides_ring(bucket, stage):
+    """Whether the train step exchanges this bucket hop by hop round
+    the ring (:func:`ring_reduce_scatter`, :func:`ring_gather`): a
+    leaf-shaped bucket at stages 1 and 2.  A flat bucket keeps the
+    native collectives (it packs small leaves: one launch for many),
+    and stage 3 gathers in the forward pass and scatters by that
+    gather's transpose."""
+    return bucket.leaf and stage in (1, 2)
+
+
+def bucket_layout(plan, stage=None):
+    """``[(bucket key, "leaf" | "flat", elements, "ring" | "native")]``
+    of a plan exchanged at ``stage``: what ``step_fn.zero_layout``
+    reports."""
+    return [(k, b.layout, b.padded,
+             "ring" if rides_ring(b, stage) else "native")
             for k, b in zip(stage3_param_keys(plan), plan)]
+
+
+def _share(plan, which):
+    total = sum(b.padded for b in plan)
+    picked = [b.padded for b in plan if which(b)]
+    return len(picked), len(plan), sum(picked) / total if total else 0.0
 
 
 def leaf_share(plan):
     """``(leaf-shaped buckets, buckets, share of the elements that are
     exchanged leaf-shaped)`` of a plan."""
-    total = sum(b.padded for b in plan)
-    leaf = sum(b.padded for b in plan if b.leaf)
-    return (sum(1 for b in plan if b.leaf), len(plan),
-            leaf / total if total else 0.0)
+    return _share(plan, lambda b: b.leaf)
+
+
+def ring_share(plan, stage):
+    """``(buckets exchanged by the ring, buckets, their share of the
+    elements)`` of a plan exchanged at ``stage``."""
+    return _share(plan, lambda b: rides_ring(b, stage))
 
 
 def plan_buckets(params, n_shards, capacity=None, group_key=None):
@@ -367,6 +403,126 @@ def bucket_shard_update(bucket, opt, params, g_sh, state, t, *, n_shards,
     if want_finite:
         return w_sh, uw, us, None
     return w_sh, uw, us
+
+
+# ------------------------------------------------ the ring over the axis
+def ring_order(mesh, axis):
+    """The data axis' indices in an order in which each device and the
+    next (and the last and the first) are physical neighbours: the way
+    round that the hops of :func:`ring_reduce_scatter` and
+    :func:`ring_gather` take.  Read from the devices' ``coords``: a
+    2x2 of v5e chips listed (0,0) (1,0) (0,1) (1,1) along the axis has
+    a diagonal between its second and third, so its ring is 0, 1, 3, 2.
+    Devices without coordinates (the CPU's), or among which no such way
+    round exists, keep the axis' own order."""
+    devs = onp.moveaxis(mesh.devices,
+                        mesh.axis_names.index(axis), 0)
+    devs = devs.reshape(devs.shape[0], -1)[:, 0]
+    n = len(devs)
+    coords = [getattr(d, "coords", None) for d in devs]
+    if n < 4 or any(c is None for c in coords):
+        return tuple(range(n))
+
+    def near(i, j):
+        return sum(abs(a - b) for a, b in zip(coords[i], coords[j])) <= 1
+
+    budget = 10000    # a search, not a proof: the axis' order else
+
+    def walk(path, left):
+        nonlocal budget
+        if not left:
+            return path if near(path[-1], path[0]) else None
+        for j in sorted(left):
+            if budget > 0 and near(path[-1], j):
+                budget -= 1
+                found = walk(path + [j], left - {j})
+                if found:
+                    return found
+        return None
+
+    return tuple(walk([0], set(range(1, n))) or range(n))
+
+
+def _ring_ways(ring, rows, dtype):
+    """``[(permutation, step, first row, rows)]``: the ways round a
+    chunk of ``rows`` rows travels.  Both ways in halves where each
+    half is whole tile rows (a link carries both directions at once,
+    so a hop takes half the time); one way else."""
+    n = len(ring)
+    tile = _SUBLANES * max(1, 4 // onp.dtype(dtype).itemsize)
+
+    def perm(step):
+        return [(ring[p], ring[(p + step) % n]) for p in range(n)]
+
+    if n > 2 and rows % (2 * tile) == 0:
+        return [(perm(1), 1, 0, rows // 2),
+                (perm(-1), -1, rows // 2, rows // 2)]
+    return [(perm(1), 1, 0, rows)]
+
+
+def _ring_place(ring, idx):
+    """(the ring's order as an array, this device's place in it)."""
+    import jax.numpy as jnp
+
+    return (jnp.asarray(ring, jnp.int32),
+            jnp.asarray(onp.argsort(ring), jnp.int32)[idx])
+
+
+@jax.named_scope("mx_exchange")
+def ring_reduce_scatter(leaf, axis, ring, idx):
+    """This device's rows of the sum of ``leaf`` over the axis (what
+    ``psum_scatter(scatter_dimension=0, tiled=True)`` returns), as
+    ``len(ring) - 1`` hops round ``ring``: each hop ``ppermute``s the
+    running chunk to the next device and adds this device's rows of
+    that chunk.  A chunk ends at its owner having passed every other
+    device in the ring's order, so the order of addition is fixed by
+    the devices' places and a run repeats to the bit (it is not the
+    native collective's order: equal to it within float rounding).
+    Every hop is an asynchronous ``collective-permute`` that depends on
+    this leaf's gradient alone, so the compiler's scheduler runs the
+    backward pass of the layers below under it; the native
+    ``reduce-scatter`` is synchronous on the chip (v5e, jax 0.9)."""
+    import jax.numpy as jnp
+
+    n = len(ring)
+    rows = leaf.shape[0] // n
+    order, place = _ring_place(ring, idx)
+    parts = []
+    for perm, step, lo, cnt in _ring_ways(ring, rows, leaf.dtype):
+        def mine(k):
+            # this device's rows of the chunk owned ``k`` places back
+            owner = order[(place - step * k) % n]
+            return jax.lax.dynamic_slice_in_dim(
+                leaf, owner * rows + lo, cnt, 0)
+
+        acc = mine(1)
+        for hop in range(n - 1):
+            acc = jax.lax.ppermute(acc, axis, perm) + mine(hop + 2)
+        parts.append(acc)
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+
+@jax.named_scope("mx_exchange")
+def ring_gather(into, w_sh, axis, ring, idx):
+    """``into`` (an array of the leaf's shape: the weights as the step
+    got them) with every device's rows ``w_sh`` written over its own
+    (what ``all_gather(tiled=True)`` returns), as ``len(ring) - 1``
+    hops round ``ring``, each written with ``dynamic_update_slice``:
+    the forward pass gets the leaf with nothing to unpack, and the
+    compiler has no gathered temporary to copy into the output (where
+    the step's params are donated the rows land in their buffer)."""
+    n = len(ring)
+    rows = w_sh.shape[0]
+    order, place = _ring_place(ring, idx)
+    out = jax.lax.dynamic_update_slice_in_dim(into, w_sh, idx * rows, 0)
+    for perm, step, lo, cnt in _ring_ways(ring, rows, w_sh.dtype):
+        cur = jax.lax.slice_in_dim(w_sh, lo, lo + cnt, axis=0)
+        for hop in range(n - 1):
+            cur = jax.lax.ppermute(cur, axis, perm)
+            owner = order[(place - step * (hop + 1)) % n]
+            out = jax.lax.dynamic_update_slice_in_dim(
+                out, cur, owner * rows + lo, 0)
+    return out
 
 
 @jax.named_scope("mx_exchange")

@@ -331,29 +331,50 @@ def four_chips(topo):
     return Mesh(onp.array(topo.devices[:4]), ("data",))
 
 
-def _named_instructions(text):
-    """``{name: (opcode, text of the result's shape)}`` of a compiled
-    text; a tuple-shaped result (an async start) keeps its whole text."""
+def _instruction(line):
+    """``(name, opcode, text of the result's shape, operands and
+    attributes)`` of an instruction's line, else None; a tuple-shaped
+    result (an async start) keeps its whole text."""
     import re
 
-    pat = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*?)\s"
-                     r"([a-z][a-z\-]*)\(")
-    out = {}
-    for line in text.splitlines():
-        m = pat.match(line)
-        if m:
-            out[m.group(1)] = (m.group(3), m.group(2))
-    return out
+    m = re.match(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*?)\s"
+                 r"([a-z][a-z\-]*)\((.*)$", line)
+    return m and (m.group(1), m.group(3), m.group(2), m.group(4))
+
+
+def _named_instructions(text):
+    """``{name: (opcode, text of the result's shape)}`` of a compiled
+    text."""
+    found = filter(None, map(_instruction, text.splitlines()))
+    return {name: (op, shape) for name, op, shape, _ in found}
+
+
+def _lowered_text(step, params, state, mesh, x, y, params_sharded=False):
+    """The compiled text of a ``ps`` step for described chips.  Nothing
+    can be placed on a described device, so the step's state stays where
+    it is and the lowering gets shapes: ``x`` and ``y`` are ``(shape,
+    dtype)`` of the whole batch."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    repl, rows = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+
+    def spec(a, sharded):
+        return jax.ShapeDtypeStruct(
+            a.shape, a.dtype,
+            sharding=rows if sharded and a.ndim else repl)
+
+    p = jax.tree_util.tree_map(lambda a: spec(a, params_sharded), params)
+    s = jax.tree_util.tree_map(lambda a: spec(a, True), state)
+    x, y = (jax.ShapeDtypeStruct(shape, dtype, sharding=rows)
+            for shape, dtype in (x, y))
+    return step.lower(p, s, x, y, jax.random.key(0), 1.0) \
+        .compile().as_text()
 
 
 def _ps_step_text(mesh, monkeypatch, stage):
     """The compiled text of a ``ps`` step over one Dense layer whose
     weight, f32[4096, 1024], is a bucket of its own (over the bound),
-    for four described chips.  Nothing can be placed on a described
-    device, so the step's state stays where it is and the lowering gets
-    shapes."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
+    for four described chips."""
     import mxnet_tpu as mx
     from mxnet_tpu import gluon
     from mxnet_tpu.parallel import make_train_step
@@ -365,20 +386,17 @@ def _ps_step_text(mesh, monkeypatch, stage):
         net, gluon.loss.L2Loss(), optimizer="sgd", learning_rate=0.1,
         momentum=0.9, mesh=mesh, donate=False, autotune=False,
         optimizer_sharding="ps", zero_stage=stage)
-    repl, rows = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    return step, _lowered_text(
+        step, params, state, mesh, ((256, 1024), jnp.float32),
+        ((256, 4096), jnp.float32), params_sharded=stage == 3)
 
-    def spec(a, sharded):
-        return jax.ShapeDtypeStruct(
-            a.shape, a.dtype,
-            sharding=rows if sharded and a.ndim else repl)
 
-    p = jax.tree_util.tree_map(lambda a: spec(a, stage == 3), params)
-    s = jax.tree_util.tree_map(lambda a: spec(a, True), state)
-    x = jax.ShapeDtypeStruct((256, 1024), jnp.float32, sharding=rows)
-    y = jax.ShapeDtypeStruct((256, 4096), jnp.float32, sharding=rows)
-    text = step.lower(p, s, x, y, jax.random.key(0), 1.0) \
-        .compile().as_text()
-    return step, text
+_WIRE = ("all-reduce", "all-gather", "reduce-scatter",
+         "collective-permute", "all-to-all")
+
+
+def _base(op):
+    return op.split("-start")[0].split("-done")[0]
 
 
 @pytest.mark.parametrize("stage", [2, 3])
@@ -389,37 +407,47 @@ def test_one_leaf_bucket_is_exchanged_in_the_leafs_shape(
     from mxnet_tpu import profiler
 
     step, text = _ps_step_text(four_chips, monkeypatch, stage)
-    assert [(lay, n) for _, lay, n in step.zero_layout] == [
-        ("leaf", 4096 * 1024), ("flat", 4096)]
+    assert [(lay, n, how) for _, lay, n, how in step.zero_layout] == [
+        ("leaf", 4096 * 1024, "ring" if stage == 2 else "native"),
+        ("flat", 4096, "native")]
     table = _named_instructions(text)
     # no 1-D form of the leaf or of its shard, anywhere: neither the
     # gradient nor the weights are packed flat, cut flat or unpacked
     flat = [(n, shape) for n, (op, shape) in table.items()
             if re.match(r"f32\[(4194304|1048576)\]", shape)]
     assert not flat, flat[:5]
-    # the gradient is scattered by rows: a reduce-scatter to the
-    # shard's own shape, or the compiler's fused form of one (a kCustom
-    # `all-reduce-scatter` fusion over the leaf padded by some rows);
-    # never an all-reduce of the flat leaf that a slice then cuts
     scattered = [n for n, (op, shape) in table.items()
                  if op == "reduce-scatter"
                  and shape.startswith("f32[1024,1024]")]
     fused = re.findall(r"fusion\([^)]*\), kind=kCustom, "
                        r"calls=%all-reduce-scatter", text)
-    assert scattered or fused, sorted(
-        (op, shape) for op, shape in table.values() if "reduce" in op)
-    # the weights come back as rows, gathered into the leaf's shape
-    # (at stage 3 the compiler gathers what the product reads: bf16)
-    assert [n for n, (op, shape) in table.items()
-            if op == "all-gather"
-            and re.match(r"(f32|bf16)\[4096,1024\]", shape)]
+    gathered = [n for n, (op, shape) in table.items()
+                if _base(op) == "all-gather"
+                and re.search(r"(f32|bf16)\[4096,1024\]", shape)]
+    hops = [n for n, (op, shape) in table.items()
+            if op == "collective-permute-start"
+            and shape.startswith("(f32[512,1024]")]
+    if stage == 2:
+        # the train step's ring: three hops each way round for the
+        # gradient's rows and three for the weights', each half a
+        # shard's rows; no native collective of the leaf is left
+        assert len(hops) == 12, hops
+        assert not scattered and not fused and not gathered
+    else:
+        # stage 3 gathers in the forward pass and scatters by that
+        # gather's transpose: a reduce-scatter to the shard's own shape,
+        # or the compiler's fused form of one (a kCustom
+        # `all-reduce-scatter` fusion over the leaf padded by some
+        # rows); the weights come back as rows, gathered into the
+        # leaf's shape (the compiler gathers what the product reads:
+        # bf16); no hop
+        assert scattered or fused, sorted(
+            (op, shape) for op, shape in table.values() if "reduce" in op)
+        assert gathered and not hops
     # and the program's reader puts every collective of the step, and
     # every fusion that holds one, under mx_exchange
     scopes = profiler._scope_table(text)
-    wire = [n for n, (op, _) in table.items()
-            if op.split("-start")[0].split("-done")[0] in (
-                "all-reduce", "all-gather", "reduce-scatter",
-                "collective-permute", "all-to-all")]
+    wire = [n for n, (op, _) in table.items() if _base(op) in _WIRE]
     assert len(wire) >= 2
     for name in wire:
         phase, _ = profiler._phase_and_block(scopes[name][0])
@@ -432,3 +460,98 @@ def test_one_leaf_bucket_is_exchanged_in_the_leafs_shape(
     for name in packing:
         assert profiler._phase_and_block(scopes[name][0])[0] == \
             "exchange"
+
+
+# --------------------- the ring's hops under the backward convolutions
+def _scheduled_entry(text):
+    """``[(name, opcode, shape text, the computation it calls, operands
+    and attributes)]`` of the compiled text's ENTRY computation, in the
+    order the chip runs them (the text of a compiled TPU program
+    ``is_scheduled``)."""
+    import re
+
+    assert "is_scheduled=true" in text.split("\n", 1)[0]
+    body = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", text,
+                     re.S | re.M).group(1)
+    out = []
+    for name, op, shape, rest in filter(
+            None, map(_instruction, body.splitlines())):
+        calls = re.search(r"calls=%?([\w.\-]+)", rest)
+        out.append((name, op, shape, calls and calls.group(1), rest))
+    return out
+
+
+def _convolution_fusions(text):
+    """Names of the computations that hold a ``convolution`` (on the
+    chip a dense product is one too)."""
+    import re
+
+    return {m.group(1) for m in re.finditer(
+        r"^%?([\w.\-]+) [^\n]*\{\n(.*?)^\}", text, re.S | re.M)
+        if " convolution(" in m.group(2)}
+
+
+def test_ring_hops_run_under_the_backward_convolutions(four_chips,
+                                                       monkeypatch):
+    """The ``ps`` step of a small net whose first layer's weight is a
+    leaf-shaped bucket of its own, before four convolutions: its
+    gradient exists while the convolutions' backward passes are still
+    to run, so the scheduler has work to put under its hops.  Holds the
+    compiled SCHEDULE (ROADMAP D12 asks for such a guard): no native
+    collective of the leaf's shape, every hop asynchronous, and at
+    least one convolution fusion between a hop's start and its done."""
+    import re
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import gluon
+    from mxnet_tpu.gluon import nn
+    from mxnet_tpu.parallel import make_train_step
+
+    monkeypatch.setattr(jax, "device_put", lambda x, *a, **k: x)
+    with nn.default_layout("NHWC"):
+        net = nn.HybridSequential()
+        for _ in range(6):
+            net.add(nn.Conv2D(128, 3, padding=1, in_channels=128,
+                              activation="relu"))
+        net.add(nn.Flatten(), nn.Dense(512, in_units=16 * 16 * 128))
+    net.initialize(init=mx.init.Xavier())
+    step, params, state = make_train_step(
+        net, gluon.loss.L2Loss(), optimizer="sgd", learning_rate=0.1,
+        momentum=0.9, mesh=four_chips, donate=True, autotune=False,
+        compute_dtype="bfloat16", optimizer_sharding="ps")
+    dense = [(lay, n, how) for _, lay, n, how in step.zero_layout
+             if n == 512 * 32768]
+    assert dense == [("leaf", 512 * 32768, "ring")]
+    text = _lowered_text(step, params, state, four_chips,
+                         ((256, 16, 16, 128), jnp.bfloat16),
+                         ((256, 512), jnp.float32))
+    entry = _scheduled_entry(text)
+    # no native collective of the leaf or of its shard
+    leafish = re.compile(r"\(?f32\[(512|128),32768\]")
+    native = [(n, op) for n, op, shape, _, _ in entry
+              if _base(op) in ("reduce-scatter", "all-gather",
+                               "all-reduce")
+              and leafish.match(shape)]
+    assert not native, native
+    # every hop of the leaf: half a shard's rows, asynchronous
+    where = {n: i for i, (n, *_rest) in enumerate(entry)}
+    starts = [n for n, op, shape, _, _ in entry
+              if op == "collective-permute-start"
+              and shape.startswith("(f32[64,32768]")]
+    assert len(starts) == 12, starts
+    convs = _convolution_fusions(text)
+    under = {}
+    for n, op, _, _, rest in entry:
+        if op != "collective-permute-done":
+            continue
+        start = re.match(r"%?([\w.\-]+)", rest).group(1)
+        if start in starts:
+            under[start] = sum(
+                1 for _, op2, _, calls, _ in
+                entry[where[start] + 1:where[n]]
+                if op2 == "convolution" or calls in convs)
+    assert sorted(under) == sorted(starts)
+    # the guard: convolutions run while the leaf's hops are in flight
+    # (8 of the 12 at jax 0.9; the first scatter hops have nothing
+    # above them but the leaf's own product)
+    assert sum(1 for k in under.values() if k) >= 4, under

@@ -133,10 +133,18 @@ def test_plan_marks_leaf_shaped_buckets_of_vgg16():
     assert share >= 0.86
     dense = sum(by_name[f"vgg0_dense{i}_weight"].padded for i in (0, 1))
     assert dense / sum(b.padded for b in plan) >= 0.86
-    layout = zero.bucket_layout(plan)
-    assert [k for k, _, _ in layout] == zero.stage3_param_keys(plan)
-    assert [(lay, n) for _, lay, n in layout] == \
+    layout = zero.bucket_layout(plan, stage=2)
+    assert [k for k, *_ in layout] == zero.stage3_param_keys(plan)
+    assert [(lay, n) for _, lay, n, _ in layout] == \
         [(b.layout, b.padded) for b in plan]
+    # the train step exchanges every leaf-shaped bucket round the ring
+    # at stages 1 and 2, none at stage 3 (its gather is the forward's)
+    assert [how for *_, how in layout] == \
+        ["ring" if b.leaf else "native" for b in plan]
+    for stage in (1, 2):
+        assert zero.ring_share(plan, stage) == zero.leaf_share(plan)
+    assert zero.ring_share(plan, 3) == (0, len(plan), 0.0)
+    assert {how for *_, how in zero.bucket_layout(plan, 3)} == {"native"}
 
 
 @pytest.mark.parametrize("shape,n_shards,dtype,leaf", [
@@ -200,6 +208,120 @@ def test_layout_tells_variant_keys_and_fingerprints_apart(monkeypatch):
             zero.plan_fingerprint(flat, 8, stage)
 
 
+# ------------------------------------------- the ring over the data axis
+def _ring_vs_native(n, shape, dtype, ring, leaf):
+    """(ring's rows, native rows, ring's gathered leaf on every device,
+    native gathered leaf) of ``leaf``: one gradient a device, stacked."""
+    from jax.sharding import PartitionSpec as P
+
+    from mxnet_tpu.parallel import compat_shard_map
+
+    mesh = get_mesh((n,), ("data",))
+
+    def local(g):
+        g = g[0]
+        idx = jax.lax.axis_index("data")
+        mine = zero.ring_reduce_scatter(g, "data", ring, idx)
+        ref = jax.lax.psum_scatter(g, "data", scatter_dimension=0,
+                                   tiled=True)
+        # the gather writes over an array of the leaf's shape (the old
+        # weights in the step): every row of it has to be replaced
+        full = zero.ring_gather(jnp.full_like(g, 7), mine, "data", ring,
+                                idx)
+        return mine, ref, full[None], jax.lax.all_gather(
+            ref, "data", tiled=True)[None]
+
+    fn = jax.jit(compat_shard_map(local, mesh, in_specs=P("data"),
+                                  out_specs=(P("data"),) * 4))
+    return [onp.asarray(a) for a in fn(leaf)], fn
+
+
+#: a leaf of 2 dimensions and a convolution's of 4; rows a shard that
+#: travel both ways round in halves (16 a shard at 8 shards) or one
+#: way whole (8 a shard: a half would cut a float32 tile)
+_RING_SHAPES = {"dense": lambda n: (n * 16, 24),
+                "conv": lambda n: (n * 32, 3, 3, 5),
+                "one_way": lambda n: (n * 8, 40)}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", sorted(_RING_SHAPES))
+@pytest.mark.parametrize("n", [2, 4, 8])
+def test_ring_exchange_against_the_native_collectives(n, kind, dtype):
+    shape = _RING_SHAPES[kind](n)
+    # a ring that is not the axis' own order where there is one to take
+    ring = (0, 1, 3, 2) + tuple(range(4, n)) if n >= 4 else (1, 0)
+    rng = onp.random.RandomState(n)
+    # whole numbers: any order of addition is exact, so the ring's sum
+    # IS the native collective's, to the bit
+    whole = jnp.asarray(rng.randint(-8, 9, (n,) + shape), dtype)
+    (mine, ref, full, gathered), fn = _ring_vs_native(
+        n, shape, dtype, ring, whole)
+    assert mine.dtype == ref.dtype and mine.shape == ref.shape == shape
+    onp.testing.assert_array_equal(mine, ref)
+    onp.testing.assert_array_equal(
+        mine.astype("float32"),
+        onp.asarray(whole).astype("float32").sum(0))
+    # the gather's result has the leaf's shape, every device holds the
+    # same array, and it is what the native gather returns
+    assert full.shape == (n,) + shape
+    for d in range(n):
+        onp.testing.assert_array_equal(full[d], gathered[0])
+        onp.testing.assert_array_equal(full[d], mine)
+    # random data: the same n terms in the ring's order, so within
+    # n roundings of the dtype of the sum of the terms' sizes ...
+    noisy = jnp.asarray(rng.randn(*((n,) + shape)), dtype)
+    (mine, ref, full, _), _ = _ring_vs_native(n, shape, dtype, ring,
+                                              noisy)
+    eps = float(jnp.finfo(dtype).eps)
+    size = onp.abs(onp.asarray(noisy).astype("float64")).sum(0)
+    gap = onp.abs(mine.astype("float64") - ref.astype("float64"))
+    assert (gap <= n * eps * size).all(), float((gap / size).max())
+    exact = onp.asarray(noisy).astype("float64").sum(0)
+    assert (onp.abs(mine.astype("float64") - exact)
+            <= n * eps * size).all()
+    # ... and a second run repeats the first to the bit
+    again = [onp.asarray(a) for a in fn(noisy)]
+    onp.testing.assert_array_equal(again[0], mine)
+    onp.testing.assert_array_equal(again[2], full)
+
+
+def test_ring_order_follows_physical_neighbours():
+    """The hops go round physical neighbours: read from the devices'
+    coordinates where they have any (a 2x2 listed row by row has a
+    diagonal between its second and third), the axis' order else."""
+    import collections
+
+    from jax.sharding import Mesh
+
+    Chip = collections.namedtuple("Chip", "id coords")
+
+    def order(coords, axes=("data",), shape=None):
+        devs = onp.empty(len(coords), object)
+        devs[:] = [Chip(i, c) for i, c in enumerate(coords)]
+        return zero.ring_order(
+            Mesh(devs.reshape(shape or (len(coords),)), axes), "data")
+
+    square = [(0, 0, 0), (1, 0, 0), (0, 1, 0), (1, 1, 0)]
+    assert order(square) == (0, 1, 3, 2)
+    # already a ring: kept
+    assert order([square[i] for i in (0, 1, 3, 2)]) == (0, 1, 2, 3)
+    # 2x4, listed row by row: out along one row, back along the other
+    two_by_four = [(x, y, 0) for y in range(2) for x in range(4)]
+    ring = order(two_by_four)
+    assert sorted(ring) == list(range(8)) and ring[0] == 0
+    for a, b in zip(ring, ring[1:] + ring[:1]):
+        assert sum(abs(p - q) for p, q in zip(two_by_four[a],
+                                              two_by_four[b])) == 1
+    # a line of chips has no way round: the axis' own order
+    assert order([(x, 0, 0) for x in range(4)]) == (0, 1, 2, 3)
+    # the data axis of a (data, model) mesh: read along its first column
+    assert order(square, ("data", "model"), (4, 1)) == (0, 1, 3, 2)
+    # the CPU's devices have no coordinates
+    assert zero.ring_order(get_mesh((8,), ("data",)), "data") == \
+        tuple(range(8))
+
+
 # ----------------------------------------------------------------- parity
 #: widths of the seeded MLP: the historic one packs or stays flat at
 #: 8 shards (32, 16 and 4 rows); the leafy one has leaves of 64 and 128
@@ -207,6 +329,28 @@ def test_layout_tells_variant_keys_and_fingerprints_apart(monkeypatch):
 _MLP, _LEAFY = (32, 16, 4), (64, 128, 4)
 _WIDTHS = pytest.mark.parametrize("widths", [_MLP, _LEAFY],
                                   ids=["mlp", "leafy"])
+
+
+#: The train step sums a leaf-shaped bucket's gradient hop by hop in the
+#: ring's order; the replicated step and a flat bucket are summed by the
+#: native collective in its own.  The same eight float32 terms in another
+#: order round differently, so after ten steps a weight of the leafy net
+#: stands within this bound, not on the bits: 64 roundings (2**-23 each)
+#: of the weight's own size, and as many of 0.01 for weights near nought.
+#: Adam divides a gradient by its own size, which magnifies the rounding
+#: of one near nought: a thousandth of a step of lr = 0.1 there.
+_ROUNDINGS = 64 * 2.0 ** -23
+_SUM_ORDER = {"adam": dict(rtol=_ROUNDINGS, atol=0.1 * 1e-3)}
+_SUM_ORDER_ELSE = dict(rtol=_ROUNDINGS, atol=_ROUNDINGS * 1e-2)
+
+
+def _assert_same(got, want, exact, err_msg, optimizer=None):
+    if exact:
+        onp.testing.assert_array_equal(got, want, err_msg=err_msg)
+    else:
+        onp.testing.assert_allclose(
+            got, want, err_msg=err_msg,
+            **_SUM_ORDER.get(optimizer, _SUM_ORDER_ELSE))
 
 
 def _mlp_net(widths=_MLP):
@@ -263,22 +407,33 @@ def test_sharded_step_parity_with_replicated(optimizer, exact, widths,
     if widths == _LEAFY:
         # two leaves are exchanged, updated and kept as their own rows
         # ... and the same step over flat buckets (the layout every
-        # bucket had before) ends bit for bit where this one does
+        # bucket had before) ends where this one does, the order of the
+        # ring's sum apart
         assert sorted(layouts.values()).count("leaf") == 2
         monkeypatch.setattr(zero, "_leaf_shaped", lambda *a: False)
         l_f, p_f, s_f = _run_steps(optimizer, widths=widths,
                                    optimizer_sharding="ps",
                                    bucket_bound=300)
         assert set(_layouts(s_f).values()) == {"flat"}
-        assert l_f == l_s
+        _assert_same(l_f, l_s, False, "loss", optimizer)
         for k in p_f:
-            onp.testing.assert_array_equal(p_f[k], p_s[k], err_msg=k)
+            _assert_same(p_f[k], p_s[k], False, k, optimizer)
+        if exact:
+            # the flat step IS the replicated one, bit for bit
+            assert l_r == l_f
+            for k in p_r:
+                onp.testing.assert_array_equal(p_r[k], p_f[k], err_msg=k)
     else:
         assert set(layouts.values()) == {"flat"}
     if exact:
-        assert l_r == l_s
+        ring = widths == _LEAFY
+        _assert_same(l_r, l_s, not ring, "loss")
         for k in p_r:
-            onp.testing.assert_array_equal(p_r[k], p_s[k], err_msg=k)
+            _assert_same(p_r[k], p_s[k], not ring, k)
+    elif widths == _LEAFY:
+        assert onp.isclose(l_r, l_s, rtol=1e-6)
+        for k in p_r:
+            _assert_same(p_r[k], p_s[k], False, k, optimizer)
     else:
         assert onp.isclose(l_r, l_s, rtol=1e-6)
         for k in p_r:
@@ -299,23 +454,24 @@ def test_sharded_step_parity_under_dynamic_loss_scaling(widths):
     l_r, p_r, s_r = _run_steps("sgd", loss_scale="dynamic", widths=widths)
     l_s, p_s, s_s = _run_steps("sgd", loss_scale="dynamic", widths=widths,
                                optimizer_sharding="ps", bucket_bound=300)
-    assert ("leaf" in _layouts(s_s).values()) == (widths == _LEAFY)
-    assert l_r == l_s
+    ring = widths == _LEAFY
+    assert ("leaf" in _layouts(s_s).values()) == ring
+    _assert_same(l_r, l_s, not ring, "loss")
     for k in p_r:
-        onp.testing.assert_array_equal(p_r[k], p_s[k], err_msg=k)
+        _assert_same(p_r[k], p_s[k], not ring, k)
     # the scale/finite-counter bookkeeping matches too
     for a, b in zip(s_r["_loss_scale"], s_s["_loss_scale"]):
         assert float(onp.asarray(a)) == float(onp.asarray(b))
 
 
-def _trajectory(poison, **kw):
-    """Four steps of the leafy MLP on dp(8) from the seeded weights,
+def _trajectory(poison, widths, **kw):
+    """Four steps of the seeded MLP on dp(8) from the seeded weights,
     the third batch carrying one ``inf`` where ``poison``: the loss,
     params (by name suffix) and opt_state after every step, the
     start included as entry 0."""
     mesh = get_mesh((8,), ("data",))
     step, p, s = make_train_step(
-        _mlp_net(_LEAFY), gluon.loss.SoftmaxCrossEntropyLoss(),
+        _mlp_net(widths), gluon.loss.SoftmaxCrossEntropyLoss(),
         optimizer="sgd", learning_rate=0.1, momentum=0.9, mesh=mesh,
         donate=False, **kw)
     rng = onp.random.RandomState(0)
@@ -342,24 +498,33 @@ def _trajectory(poison, **kw):
     (None, False), (None, True), (128.0, False), (128.0, True),
     ("dynamic", False),  # dynamic scaling turns the guard off
 ])
-def test_both_arms_take_the_same_steps(loss_scale, nan_guard):
+@_WIDTHS
+def test_both_arms_take_the_same_steps(loss_scale, nan_guard, widths):
     """The replicated step and the ``ps`` step are one sequence (scale,
     gradient, verdict, update, keep, bookkeeping): from the same
     weights they take the same four steps under every loss-scale mode,
-    with and without the guard, and a poisoned batch leaves params and
-    state as they came in both."""
+    with and without the guard (to the bit over flat buckets; within
+    the order of the ring's sum, ``_SUM_ORDER``, where a bucket is
+    leaf-shaped), and a poisoned batch leaves params and state as they
+    came in both."""
     poison = nan_guard or loss_scale == "dynamic"
-    rep = _trajectory(poison, loss_scale=loss_scale, nan_guard=nan_guard)
-    ps = _trajectory(poison, loss_scale=loss_scale, nan_guard=nan_guard,
-                     optimizer_sharding="ps", bucket_bound=300)
-    assert "leaf" in _layouts(ps[0][2]).values()
+    ring = widths == _LEAFY
+    rep = _trajectory(poison, widths, loss_scale=loss_scale,
+                      nan_guard=nan_guard)
+    ps = _trajectory(poison, widths, loss_scale=loss_scale,
+                     nan_guard=nan_guard, optimizer_sharding="ps",
+                     bucket_bound=300)
+    assert ("leaf" in _layouts(ps[0][2]).values()) == ring
     for i in range(1, 5):
         (l_r, p_r, s_r), (l_s, p_s, s_s) = rep[i], ps[i]
-        onp.testing.assert_array_equal(l_r, l_s, err_msg=f"loss {i}")
+        if onp.isfinite(l_r):
+            _assert_same(l_r, l_s, not ring, f"loss {i}")
+        else:
+            onp.testing.assert_array_equal(l_r, l_s, err_msg=f"loss {i}")
         assert set(p_r) == set(p_s)
         for k in p_r:
-            onp.testing.assert_array_equal(p_r[k], p_s[k],
-                                           err_msg=f"{k} after step {i}")
+            _assert_same(p_r[k], p_s[k], not ring,
+                         f"{k} after step {i}")
         for key in ("_loss_scale", "_bad_steps"):
             assert (key in s_r) == (key in s_s)
             if key in s_r:
@@ -415,7 +580,8 @@ def test_zero_layout_and_runlog_name_the_leaf_shaped_share(tmp_path):
         telemetry.close()
     plan = step.zero_plan
     assert step.zero_layout == [
-        (f"_bucket{i}", "leaf" if b.leaf else "flat", b.padded)
+        (f"_bucket{i}", "leaf", b.padded, "ring") if b.leaf else
+        (f"_bucket{i}", "flat", b.padded, "native")
         for i, b in enumerate(plan)]
     leaf = {b.names[0].split("_", 1)[-1]: b.shape for b in plan if b.leaf}
     assert leaf == {"dense0_weight": (64, 8), "dense1_weight": (128, 64)}
@@ -428,9 +594,10 @@ def test_zero_layout_and_runlog_name_the_leaf_shaped_share(tmp_path):
                and r.get("program") == "train_step"]
     assert comp["fingerprint"]["sharding"] == (
         f"ps (2 of 6 buckets leaf-shaped, {100 * share:.1f}% of the "
-        "elements)")
+        f"elements; 2 by the ring, {100 * share:.1f}%)")
+    assert zero.ring_share(plan, step.zero_stage) == (2, 6, share)
     # the state of a leaf-shaped bucket is the leaf's rows
-    for (bk, lay, _), b in zip(step.zero_layout, plan):
+    for (bk, lay, *_), b in zip(step.zero_layout, plan):
         assert s[bk][0].shape == b.shape
         assert (s[bk][0].ndim > 1) == (lay == "leaf")
 
