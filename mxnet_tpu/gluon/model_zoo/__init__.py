@@ -1,2 +1,2 @@
 """Model zoo (reference: python/mxnet/gluon/model_zoo/)."""
-from . import vision  # noqa: F401
+from . import language, vision  # noqa: F401

@@ -141,7 +141,8 @@ class Dense(HybridBlock):
 
     def hybrid_forward(self, F, x, weight, bias=None):
         out = F.FullyConnected(
-            x, weight, bias, no_bias=bias is None, num_hidden=self._units,
+            *((x, weight) if bias is None else (x, weight, bias)),
+            no_bias=bias is None, num_hidden=self._units,
             flatten=self._flatten,
         )
         if self.act is not None:

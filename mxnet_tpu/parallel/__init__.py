@@ -50,9 +50,13 @@ def compat_shard_map(f, mesh, in_specs, out_specs, check_vma=False):
 
 
 #: parameter-name suffixes that stay fp32 under mixed precision (the AMP
-#: policy the reference encodes in contrib/amp/lists: norm affine+stats)
+#: policy the reference encodes in contrib/amp/lists: norm affine+stats),
+#: and with them what a state-space mixer and a router of experts keep
+#: in float32: a decay's ``A_log``, ``dt_bias`` and skip ``D``, the
+#: router's weights and its correction bias (gluon/nn/sequence_layers.py)
 NORM_STAT_SUFFIXES = ("gamma", "beta", "running_mean", "running_var",
-                      "moving_mean", "moving_var")
+                      "moving_mean", "moving_var", "A_log", "dt_bias", "_D",
+                      "router_weight", "correction_bias")
 
 
 def _is_norm_stat(name):
@@ -213,6 +217,35 @@ def _compression_threshold(gradient_compression, stage):
     return float(gradient_compression.get("threshold", 0.5))
 
 
+def _declared_counters(block):
+    """``{name: "sum" | "max"}`` of what the blocks of ``block`` count in
+    a forward pass (a block's ``step_counters``), by name."""
+    found = dict(getattr(block, "step_counters", {}))
+    for child in block._children.values():
+        found.update(_declared_counters(child))
+    return dict(sorted(found.items()))
+
+
+def _release_block_state(block):
+    """Move the arrays that ``block``'s parameters hold on an accelerator,
+    data and gradient buffers, to the host's memory.  The compiled step
+    trains its own copy (``params``, ``opt_state``); the block's stays a
+    valid, stale copy that ``sync_to_block``/``set_data`` overwrite, and
+    no longer takes a parameter's worth of bytes twice over on the chip
+    (for a net initialised on the accelerator: 8 bytes a parameter)."""
+    from ..gluon.block import _collect_all_params
+
+    host = jax.local_devices(backend="cpu")[0]
+    for p in _collect_all_params(block):
+        held = p._data
+        for arr in (held, getattr(held, "_grad", None)):
+            value = getattr(arr, "_data", None)
+            if isinstance(value, jax.Array) and not isinstance(
+                    value, jax.core.Tracer) and any(
+                        d.platform != "cpu" for d in value.devices()):
+                arr._adopt(jax.device_put(value, host))
+
+
 def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
                     momentum=0.9, wd=0.0, beta1=0.9, beta2=0.999,
                     epsilon=1e-8, mesh=None, data_axis="data",
@@ -239,7 +272,16 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
     consecutive finite steps, halves on overflow and skips that update).
 
     donate=True donates params/opt_state to XLA: the inputs are dead
-    after the call, thread the returned ones.
+    after the call, thread the returned ones.  Either way the returned
+    ``params`` are the training state from then on: the block's own
+    arrays and gradient buffers are moved off the accelerator to the
+    host's memory (``_release_block_state``), where they stay a valid,
+    stale copy until ``sync_to_block`` or ``set_data`` writes them.
+
+    What the net's blocks count in a forward pass (a block's
+    ``step_counters``, ``profiler.count``) leaves the step in
+    ``opt_state["_counters"]``; ``profiler.step_counters()`` reads the
+    newest step's.
 
     sample_data=(x, y): races each op of ``variant_ops`` inside THIS step
     on the sample batch (mxnet_tpu.autotune); the winner persists and the
@@ -349,10 +391,17 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
         opt_state["_numerics"] = _nm.summary_template(
             dict.fromkeys([*params, "__loss"]))
 
+    counters = _declared_counters(block)
+    if counters:
+        # like _numerics: the template keeps the state's tree the same
+        # from the first call on
+        opt_state["_counters"] = {k: jnp.zeros((), jnp.float32)
+                                  for k in counters}
+
     # ---- the step ----------------------------------------------------
     cfg = _ts.StepConfig(
         *_ts.make_loss_of(apply_fn, loss_fn, compute_dtype), dynamic,
-        static_scale, nan_guard, fp8_rung, numerics_on)
+        static_scale, nan_guard, fp8_rung, numerics_on, counters)
     shardings = None if mesh is None else ex.shardings(
         mesh, params, opt_state, param_spec)
     step = ex.wrap(functools.partial(_ts.step_body, cfg), shardings)
@@ -409,6 +458,10 @@ def make_train_step(block, loss_fn, optimizer="sgd", learning_rate=0.01,
         opt_state = jax.device_put(opt_state, opt_shard)
     else:
         jitted = jax.jit(_scoped_step, donate_argnums=donate_argnums)
+
+    # the step's state is the training state from here on: the block's
+    # own copy of it, and its gradient buffers, leave the accelerator
+    _release_block_state(block)
 
     # ---- the host's side ---------------------------------------------
     step_fn = _ts.HostStep(jitted, opt, ex,

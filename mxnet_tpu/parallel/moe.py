@@ -9,6 +9,14 @@ GShard/Switch formulation — fixed shapes, so XLA can tile it onto the
 MXU and insert the all-to-all-style collectives itself), and dropped
 tokens fall through a residual path.
 
+What this is beside ``gluon.nn.SparseMoE`` (``ops/routed_experts.py``):
+this module is a capacity-bounded dispatch that DROPS what exceeds an
+expert's capacity, over a mesh axis, reached by no gluon block; the
+gluon block is one chip's share of an expert-parallel layer, told which
+experts it holds, with no capacity and no drop (the held experts as one
+wide MLP gated by the routing weights), and is what the zoo's language
+models train through.
+
 Public API:
   top_k_gating(logits, k, capacity)       — dispatch/combine tensors
   moe_apply(expert_fn, stacked_params, gate_w, x, ...)

@@ -9,7 +9,7 @@
     verdict      finite?  (nan_guard or dynamic scaling only)
     keep         a non-finite step leaves weight, state, residual
     publish      the params pytree the caller gets back
-    bookkeeping  _loss_scale, _bad_steps, _fp8, _numerics
+    bookkeeping  _loss_scale, _bad_steps, _counters, _fp8, _numerics
 
 The two exchanges differ in what a unit is, how its gradient gets there
 and how the result is published: :class:`ReplicatedExchange` (the named
@@ -51,7 +51,7 @@ Unit = collections.namedtuple("Unit", "key grad weight state index")
 #: what the body reads besides its exchange, fixed at build time
 StepConfig = collections.namedtuple(
     "StepConfig", "loss_of ladder_arm dynamic static_scale nan_guard "
-    "fp8_rung numerics_on")
+    "fp8_rung numerics_on counters")
 
 
 # ------------------------------------------------------------------ loss
@@ -69,6 +69,7 @@ def make_loss_of(apply_fn, loss_fn, compute_dtype):
         return _at.variant_choice("dtype_ladder")
 
     def loss_of(param_dict, x, y, key, fp8=None):
+        """``(mean loss, {counter: value})`` of a batch."""
         cdt = compute_dtype
         arm = ladder_arm()
         if arm == "bf16":
@@ -95,17 +96,22 @@ def make_loss_of(apply_fn, loss_fn, compute_dtype):
         # else: backward reads transpose(jvp(mx_forward)) without
         # further code, and every gluon block names itself inside
         # (gluon.Block.__call__)
-        with jax.named_scope("mx_forward"):
+        with jax.named_scope("mx_forward"), _profiler.counting() as counted:
             if cdt is not None:
                 # AMP policy (reference contrib/amp list semantics):
                 # matmul/conv weights in bf16, norm affine+stats in fp32
                 param_dict = amp_cast_params(param_dict, cdt)
-                x = x.astype(cdt)
+                if jnp.issubdtype(x.dtype, jnp.floating):
+                    # token ids stay whole: bf16 holds none above 256
+                    x = x.astype(cdt)
             out = apply_fn(param_dict, x, key=key)
         with jax.named_scope("mx_loss"):
             loss_nd = loss_fn(nd.NDArray(out.astype(jnp.float32)),
                               nd.NDArray(y))
-            return jnp.mean(loss_nd._data)
+            # beside the loss, what the forward pass's blocks counted
+            # (profiler.count): the step hands it out in its state
+            return jnp.mean(loss_nd._data), {
+                k: v for k, (_, v) in counted.items()}
 
     return loss_of, ladder_arm
 
@@ -249,6 +255,9 @@ class ReplicatedExchange:
 
     def mean_loss(self, loss):
         return loss
+
+    def total_counters(self, counted, hows):
+        return counted
 
 
 class ShardedExchange:
@@ -448,6 +457,11 @@ class ShardedExchange:
         with jax.named_scope("mx_exchange"):
             return jax.lax.pmean(loss, self.axis)
 
+    def total_counters(self, counted, hows):
+        with jax.named_scope("mx_exchange"):
+            return {k: (jax.lax.pmax if hows[k] == "max" else jax.lax.psum)(
+                v, self.axis) for k, v in counted.items()}
+
 
 # ------------------------------------------------------------------ body
 def step_body(cfg, ex, params, opt_state, x, y, key, t):
@@ -463,14 +477,14 @@ def step_body(cfg, ex, params, opt_state, x, y, key, t):
     fp8_on = cfg.fp8_rung and cfg.ladder_arm() == "fp8"
 
     def scaled_loss(p, x_, y_, k_):
-        lv = cfg.loss_of(ex.materialize(p), x_, y_, k_,
-                         fp8=fp8 if fp8_on else None)
+        lv, counted = cfg.loss_of(ex.materialize(p), x_, y_, k_,
+                                  fp8=fp8 if fp8_on else None)
         if scale is not None:
             with jax.named_scope("mx_guard"):
                 lv = lv * scale
-        return lv
+        return lv, counted
 
-    lval, grads = jax.value_and_grad(scaled_loss)(
+    (lval, counted), grads = jax.value_and_grad(scaled_loss, has_aux=True)(
         params, x, y, ex.forward_key(key))
     loss, carried, units = ex.exchange(params, opt_state, lval, grads,
                                        scale)
@@ -517,6 +531,13 @@ def step_body(cfg, ex, params, opt_state, x, y, key, t):
             # host enforces MXNET_BAD_STEP_LIMIT reading it)
             new_s["_bad_steps"] = jnp.where(
                 finite, jnp.int32(0), opt_state["_bad_steps"] + 1)
+    if cfg.counters:
+        # what the net's blocks counted in this step's forward pass,
+        # over every chip's part of the batch; the host reads it from
+        # the state (HostStep -> profiler.step_counters())
+        new_s["_counters"] = ex.total_counters(
+            {k: jnp.asarray(counted[k], jnp.float32) for k in cfg.counters},
+            cfg.counters)
     if cfg.fp8_rung or cfg.numerics_on:
         named = dict(sorted((u.key, u.grad) for u, *_ in done))
     if cfg.fp8_rung:
@@ -659,6 +680,9 @@ class HostStep:
         with _tm.tracing.region("mx_step", step_num=self._calls):
             result = self._jitted(p, o, x, y, key, t)
         self._calls += 1
+        if "_counters" in result[2]:
+            # the arrays as they are: nobody waits unless somebody asks
+            _profiler.note_step_counters(result[2]["_counters"])
         if self._nm_period and rl is not None:
             self._read_numerics(rl, result)
         return result

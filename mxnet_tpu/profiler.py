@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import atexit
 import bisect
+import collections
 import glob
 import json
 import os
@@ -48,7 +49,7 @@ from .base import MXNetError
 __all__ = [
     "set_config", "profiler_set_config", "set_state", "profiler_set_state",
     "dump", "dump_profile", "dumps", "device_report", "pause", "resume",
-    "op_scope",
+    "op_scope", "counting", "count", "step_counters",
     "now_us", "run_generation", "record_span", "record_counter",
     "record_instant", "record_meta", "events_snapshot",
     "Domain", "Task", "Frame", "Event", "Counter", "Marker",
@@ -641,6 +642,71 @@ def note_program(key, text_fn):
     :func:`device_report` reads the scope of every traced instruction,
     and is called only when the report is asked for."""
     _programs[key] = text_fn
+
+
+# ------------------------------------------------- a step's own counters
+_counting = threading.local()  # .box: {name: (how, value)} while a forward
+#                                pass that a compiled step traces collects
+_COUNTER_STEPS = 4096
+_step_counters = collections.deque(maxlen=_COUNTER_STEPS)  # what the newest
+#                                steps counted, arrays still on the device
+
+
+class counting:
+    """Collects, as ``{name: (how, value)}``, what the blocks of a forward
+    pass :func:`count` while it is traced.  The compiled train step opens
+    one round its forward pass and hands the totals out through its own
+    state (``opt_state["_counters"]``); a stack that rematerialises a
+    block opens one inside the rematerialised function and hands its
+    totals to the outer one with :func:`count_all`."""
+
+    def __enter__(self):
+        self._outer = getattr(_counting, "box", None)
+        _counting.box = {}
+        return _counting.box
+
+    def __exit__(self, *exc):
+        _counting.box = self._outer
+
+
+def count(name, value, how="sum"):
+    """Add ``value`` to the counter ``name`` of the forward pass being
+    traced (``how`` ``"sum"``, or ``"max"`` for a high-water mark);
+    nothing outside :class:`counting`."""
+    box = getattr(_counting, "box", None)
+    if box is None:
+        return
+    if name in box:
+        import jax.numpy as jnp
+
+        old = box[name][1]
+        value = jnp.maximum(old, value) if how == "max" else old + value
+    box[name] = (how, value)
+
+
+def count_all(counted):
+    for name, (how, value) in counted.items():
+        count(name, value, how)
+
+
+def note_step_counters(counters):
+    """The train step's host side leaves every step's counters here, as
+    the arrays the step returned: no read-back until they are asked
+    for.  The newest ``_COUNTER_STEPS`` steps are kept."""
+    _step_counters.append(counters)
+
+
+def step_counters(last=None):
+    """``{name: float}`` of what the blocks of the newest compiled train
+    step counted (a mixture of experts' ``moe_assignments``,
+    ``moe_assignments_held``, ``moe_rows_max``, ``moe_dropped``), ``{}``
+    before the first; with ``last=n`` the list of the newest ``n`` steps'
+    (fewer where fewer ran), oldest first.  Waits for those steps."""
+    steps = [{k: float(v) for k, v in c.items()}
+             for c in list(_step_counters)[-(last or 1):]]
+    if last is None:
+        return steps[-1] if steps else {}
+    return steps
 
 
 def device_report():

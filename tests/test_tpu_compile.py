@@ -321,6 +321,60 @@ def test_vgg_stage1_runs_paired_without_a_relayout(one_chip, tpu_target):
 
 
 # ------------------------------------ the ps exchange of a one-leaf bucket
+# ------------------------------------ the language-model zoo's train step
+def test_hybrid_stack_step_names_its_blocks_and_kernels(one_chip,
+                                                        tpu_target):
+    """One step of a narrow ``nemotron_h`` (every kind of layer, tile-sized
+    widths) compiled for the chip: each block that a metric reads is a
+    scope in the text (``chipbench/metrics/mamba_ms.train.py`` and its
+    neighbours read them by name) and no operation hides under a scope
+    of a helper's own (an ``einsum``'s spelling, a ``cumsum``),
+    attention's forward runs as ``flash_attention_fwd``, and the counters
+    leave the step as four scalars of its state."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import gluon, parallel
+    from mxnet_tpu.gluon.model_zoo import language
+
+    net = language.nemotron_h(
+        vocab_size=512, hidden_size=256, pattern="ME*", mamba_num_heads=4,
+        mamba_head_dim=64, n_groups=2, ssm_state_size=128, chunk_size=128,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=128,
+        n_routed_experts=16, num_experts_per_tok=6,
+        moe_intermediate_size=256, moe_shared_expert_intermediate_size=512,
+        experts_held=(0, 8))
+    net.initialize(init=mx.init.Xavier())
+    step, params, state = parallel.make_train_step(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(), optimizer="sgd",
+        learning_rate=0.01, momentum=0.9, compute_dtype="bfloat16",
+        donate=False, autotune=False)
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    x = jax.ShapeDtypeStruct((2, 256), jnp.int32, sharding=one_chip)
+    y = jax.ShapeDtypeStruct((2, 256), jnp.float32, sharding=one_chip)
+    text = step.lower(jax.tree_util.tree_map(spec, params),
+                      jax.tree_util.tree_map(spec, state), x, y,
+                      jax.random.key(0), 1.0).compile().as_text()
+    for scope in ("mamba2mixer0/", "mamba2mixer0_ssdscan0/",
+                  "sparsemoe0_moerouter0/", "sparsemoe0_routedexperts0/",
+                  "sparsemoe0_squaredrelumlp0/", "gqattention0/",
+                  "mx_forward", "mx_optimizer"):
+        assert scope in text, scope
+    assert _kernel_names(text) == {"flash_attention_fwd"}
+    # the benchmark's parser files an event under the innermost scope
+    # that is no wrapper: it has to be a block's (or the kernel's) name
+    from chipbench import trace_reduce
+
+    blocks = {trace_reduce.phase_and_block(scope)[1]
+              for scope in trace_reduce.phase_table(text).values()}
+    assert all(b in ("", "flash_attention_fwd") or "nemotronh" in b
+               or "softmaxcrossentropyloss" in b for b in blocks), blocks
+    assert sorted(state["_counters"]) == [
+        "moe_assignments", "moe_assignments_held", "moe_dropped",
+        "moe_rows_max"]
+
+
 @pytest.fixture(scope="module")
 def four_chips(topo):
     import numpy as onp
