@@ -238,10 +238,10 @@ def _route(u, router, bias, *, k, scale):
 
 
 @_op
-def _routed(u, ids, weights, up, down, *, held):
+def _routed(u, ids, weights, up, down, *, held, experts, scope):
     tokens = u.reshape(-1, u.shape[-1])
-    return _re.routed_experts(tokens, ids, weights, up, down,
-                              held=held).reshape(u.shape)
+    return _re.routed_experts(tokens, ids, weights, up, down, held=held,
+                              experts=experts, scope=scope).reshape(u.shape)
 
 
 class MoERouter(HybridBlock):
@@ -268,22 +268,26 @@ class MoERouter(HybridBlock):
 
 class RoutedExperts(HybridBlock):
     """The experts ``experts_held`` (a ``range`` of ids) of a bank of
+    ``experts`` (all are held where it is not given) of
     ``down_e(relu(up_e u)^2)``: each bank one 2-D leaf, an expert's rows
     together.  Computes every assignment to a held expert, whatever the
-    imbalance, as one wide MLP gated by the routing weights
-    (``ops/routed_experts.py``), and counts them."""
+    imbalance: a grouped product over the routed rows alone, or, in a
+    step whose rows exceed the buffer that shapes fix, every held expert
+    over every token (``ops/routed_experts.py``); and counts them."""
 
     #: what a step's forward pass counts here (``profiler.count``)
     step_counters = {"moe_assignments": "sum", "moe_assignments_held": "sum",
-                     "moe_rows_max": "max", "moe_dropped": "sum"}
+                     "moe_rows_max": "max", "moe_dropped": "sum",
+                     "moe_layers": "sum", "moe_layers_grouped": "sum"}
 
-    def __init__(self, units, hidden, experts_held, prefix=None,
-                 params=None):
+    def __init__(self, units, hidden, experts_held, experts=None,
+                 prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         held = experts_held
         if not isinstance(held, range) or held.step != 1 or not len(held):
             raise ValueError(f"experts_held must be a run of ids: {held}")
         self._held = (held.start, len(held))
+        self._experts = experts
         with self.name_scope():
             self.up_weight = self.params.get(
                 "up_weight", shape=(len(held) * hidden, units))
@@ -292,7 +296,8 @@ class RoutedExperts(HybridBlock):
 
     def hybrid_forward(self, F, u, ids, weights, up_weight, down_weight):
         return nd.invoke(_routed, [u, ids, weights, up_weight, down_weight],
-                         held=self._held)
+                         held=self._held, experts=self._experts,
+                         scope=self.name)
 
 
 class SparseMoE(HybridBlock):
@@ -307,7 +312,7 @@ class SparseMoE(HybridBlock):
         with self.name_scope():
             self.router = MoERouter(units, experts, k, scale)
             self.experts = RoutedExperts(
-                units, hidden, experts_held or range(experts))
+                units, hidden, experts_held or range(experts), experts)
             self.shared = SquaredReLUMLP(units, shared_hidden)
 
     def hybrid_forward(self, F, u):

@@ -269,7 +269,7 @@ def test_counters_leave_the_compiled_step_through_its_state():
         net, gluon.loss.SoftmaxCrossEntropyLoss(), optimizer="sgd",
         learning_rate=0.01, momentum=0.9, compute_dtype="bfloat16")
     names = {"moe_assignments", "moe_assignments_held", "moe_rows_max",
-             "moe_dropped"}
+             "moe_dropped", "moe_layers", "moe_layers_grouped"}
     assert set(opt_state["_counters"]) == names
     # on a CPU the block's own arrays are where they were
     assert all(a is p.data()._data for a, p in
@@ -284,6 +284,8 @@ def test_counters_leave_the_compiled_step_through_its_state():
         got = profiler.step_counters()
         assert set(got) == names and got["moe_dropped"] == 0.0
         assert got["moe_assignments"] == 4 * 48 * 6  # four mixtures
+        # 8 of 16 held, 6 chosen: the budget is every possible row
+        assert got["moe_layers"] == got["moe_layers_grouped"] == 4
         assert 0 < got["moe_assignments_held"] < got["moe_assignments"]
         assert got["moe_rows_max"] >= got["moe_assignments_held"] / 32
     assert len(trees) == 1 and np.isfinite(float(loss))

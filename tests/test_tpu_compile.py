@@ -21,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from mxnet_tpu.ops import flash_attention as fa
 from mxnet_tpu.ops import kernel_target, pallas_conv, pallas_opt
+from mxnet_tpu.ops import routed_experts as rex
 from mxnet_tpu.optimizer.optimizer import LARS, SGD, Adam
 
 #: ResNet-50's trainable parameters as one flat bucket
@@ -208,6 +209,24 @@ def _sgd_update(w, g, m):
         SGD(momentum=0.9, learning_rate=0.1), w, g, (m,), 2.0)
 
 
+def _routed_bank(u, weights, up, down, ct):
+    """The routed bank forward and backward over a routing made here: 8
+    of 128 experts held, 6 chosen a token."""
+    ids = (jnp.arange(u.shape[0])[:, None] * 7
+           + jnp.arange(6)[None, :] * 19) % 128
+    out, vjp = jax.vjp(
+        lambda u, w, up, down: rex.routed_experts(
+            u, ids, w, up, down, held=(0, 8), experts=128),
+        u, weights, up, down)
+    return (out,) + vjp(ct)
+
+
+def _bank_specs(tokens, width, inner, dtype):
+    return [((tokens, width), dtype), ((tokens, 6), jnp.float32),
+            ((8 * inner, width), dtype), ((8 * width, inner), dtype),
+            ((tokens, width), dtype)]
+
+
 _F32_BUCKET = ((1 << 20,), jnp.float32)
 _NAMED = {
     "bnreluconv_bwd": None,  # through _conv_bwd_text
@@ -217,6 +236,8 @@ _NAMED = {
         functools.partial(fa.flash_attention, causal=True,
                           variant="pallas"),
         [((1, 8, 512, 128), jnp.float32)] * 3),
+    "routed_experts": (_routed_bank,
+                       _bank_specs(1024, 256, 384, jnp.bfloat16)),
 }
 
 
@@ -230,9 +251,23 @@ def test_kernel_is_named_in_its_custom_call(one_chip, tpu_target, kernel):
     else:
         fn, specs = _NAMED[kernel]
         text = _compiled_text(fn, one_chip, *specs)
-    expected = {"lars_norms", "lars_update"} if kernel == "lars" \
-        else {kernel}
+    expected = {"lars": {"lars_norms", "lars_update"},
+                "routed_experts": set(rex.KERNELS)}.get(kernel, {kernel})
     assert _kernel_names(text) == expected
+
+
+# ------------------------------------------------------- the routed bank
+def test_routed_bank_compiles_at_the_cells_shapes(one_chip, tpu_target):
+    """``nemotron3_nano_train``'s bank, forward and backward: 8192 tokens
+    of width 2688, 8 held experts of inner 1856 (no multiple of 128: a
+    bank's last block hangs over the edge), bf16; the three grouped
+    products are in the text beside the dense bank of an overflow, and
+    their buffers are the 9,216 rows that shapes fix."""
+    text = _compiled_text(_routed_bank, one_chip,
+                          *_bank_specs(8192, 2688, 1856, jnp.bfloat16))
+    assert _kernel_names(text) == set(rex.KERNELS)
+    assert "bf16[9216,2688]" in text and "bf16[9216,1856]" in text
+    assert "conditional(" in text
 
 
 # ------------------------------------------- the W-paired 64-channel stage
@@ -330,7 +365,9 @@ def test_hybrid_stack_step_names_its_blocks_and_kernels(one_chip,
     neighbours read them by name) and no operation hides under a scope
     of a helper's own (an ``einsum``'s spelling, a ``cumsum``),
     attention's forward runs as ``flash_attention_fwd``, and the counters
-    leave the step as four scalars of its state."""
+    leave the step as six scalars of its state; the routed bank's three
+    grouped products are kernels whose names are their blocks, and hold
+    both parts by which the mixture's and the bank's readers find them."""
     import mxnet_tpu as mx
     from mxnet_tpu import gluon, parallel
     from mxnet_tpu.gluon.model_zoo import language
@@ -339,7 +376,7 @@ def test_hybrid_stack_step_names_its_blocks_and_kernels(one_chip,
         vocab_size=512, hidden_size=256, pattern="ME*", mamba_num_heads=4,
         mamba_head_dim=64, n_groups=2, ssm_state_size=128, chunk_size=128,
         num_attention_heads=4, num_key_value_heads=2, head_dim=128,
-        n_routed_experts=16, num_experts_per_tok=6,
+        n_routed_experts=64, num_experts_per_tok=6,
         moe_intermediate_size=256, moe_shared_expert_intermediate_size=512,
         experts_held=(0, 8))
     net.initialize(init=mx.init.Xavier())
@@ -361,18 +398,31 @@ def test_hybrid_stack_step_names_its_blocks_and_kernels(one_chip,
                   "sparsemoe0_squaredrelumlp0/", "gqattention0/",
                   "mx_forward", "mx_optimizer"):
         assert scope in text, scope
-    assert _kernel_names(text) == {"flash_attention_fwd"}
+    assert _kernel_names(text) == {"flash_attention_fwd", *rex.KERNELS}
     # the benchmark's parser files an event under the innermost scope
     # that is no wrapper: it has to be a block's (or the kernel's) name
     from chipbench import trace_reduce
 
+    scopes = trace_reduce.phase_table(text)
     blocks = {trace_reduce.phase_and_block(scope)[1]
-              for scope in trace_reduce.phase_table(text).values()}
-    assert all(b in ("", "flash_attention_fwd") or "nemotronh" in b
+              for scope in scopes.values()}
+    kernels = ("flash_attention_fwd",) + rex.KERNELS
+    assert all(b in ("",) + kernels or "nemotronh" in b
                or "softmaxcrossentropyloss" in b for b in blocks), blocks
+    # a grouped product's instructions go under its own name, which both
+    # moe_ms.train and expert_roofline.train find; what else a branch of
+    # the bank's cond holds goes under the bank's block
+    for kernel in rex.KERNELS:
+        of_kernel = {trace_reduce.phase_and_block(scope)[1]
+                     for name, scope in scopes.items()
+                     if trace_reduce.unnumbered(name) == kernel}
+        assert of_kernel == {kernel}, (kernel, of_kernel)
+        assert "sparsemoe" in kernel and "routedexperts" in kernel
+    assert "conditional(" in text  # 8 of 64 held: a budget can overflow
+    assert not any("branch_" in b or b == "cond" for b in blocks), blocks
     assert sorted(state["_counters"]) == [
         "moe_assignments", "moe_assignments_held", "moe_dropped",
-        "moe_rows_max"]
+        "moe_layers", "moe_layers_grouped", "moe_rows_max"]
 
 
 @pytest.fixture(scope="module")
