@@ -190,21 +190,20 @@ class Mamba2Mixer(HybridBlock):
 
 # ----------------------------------------------------------- attention
 @_op
-def _gq_attention(q, k, v, *, heads, kv_heads):
+def _gq_attention(q, k, v, *, heads, kv_heads, scope=None):
     """Causal attention of ``heads`` query heads over ``kv_heads``
     key/value heads (each serves ``heads / kv_heads`` of them), through
-    ``ops/flash_attention.py``; no positional embedding."""
+    ``ops/flash_attention.py``, which reads each key/value head once for
+    its group; no positional embedding.  ``scope`` names the backward
+    kernels."""
     bsz, length = q.shape[0], q.shape[1]
     dim = q.shape[2] // heads
 
     def split(t, n):
         return t.reshape(bsz, length, n, dim).transpose(0, 2, 1, 3)
 
-    def serve(t):
-        return jnp.repeat(split(t, kv_heads), heads // kv_heads, axis=1)
-
-    out = _fa.flash_attention(split(q, heads), serve(k), serve(v),
-                              causal=True)
+    out = _fa.flash_attention(split(q, heads), split(k, kv_heads),
+                              split(v, kv_heads), causal=True, scope=scope)
     return out.transpose(0, 2, 1, 3).reshape(bsz, length, heads * dim)
 
 
@@ -226,7 +225,7 @@ class GQAttention(HybridBlock):
     def hybrid_forward(self, F, x):
         out = nd.invoke(
             _gq_attention, [self.q_proj(x), self.k_proj(x), self.v_proj(x)],
-            heads=self._heads, kv_heads=self._kv_heads)
+            heads=self._heads, kv_heads=self._kv_heads, scope=self.name)
         return self.o_proj(out)
 
 

@@ -291,3 +291,128 @@ def test_padded_rows_contribute_exactly_zero(causal, variant):
         f"{variant}: padded garbage leaked into the output"
     onp.testing.assert_allclose(got, onp.asarray(ref),
                                 rtol=1e-5, atol=1e-6)
+
+
+# ------------------- the training pair: grouped heads, the operands' dtype
+def _grouped(rng, heads, kv_heads, sq, sk, d=32, dtype="float32"):
+    def draw(h, s):
+        return jnp.asarray(rng.randn(2, h, s, d).astype("float32") * 0.5)
+
+    q, k, v, ct = draw(heads, sq), draw(kv_heads, sk), draw(kv_heads, sk), \
+        draw(heads, sq)
+    return tuple(x.astype(dtype) for x in (q, k, v)) + (ct,)
+
+
+def _fwd_bwd(fn, q, k, v, ct):
+    out, vjp = jax.vjp(fn, q, k, v)
+    return (out,) + vjp(ct.astype(out.dtype))
+
+
+@pytest.mark.parametrize("seqs,variant", [((256, 256), None),
+                                          ((70, 90), "pallas_pad")],
+                         ids=["aligned", "ragged"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("heads,kv_heads", [(4, 2), (8, 1), (2, 2)])
+def test_kernel_pair_matches_reference(heads, kv_heads, causal, dtype,
+                                       seqs, variant):
+    """The forward kernel and the two backward kernels, interpreted,
+    against the float32 reference over the same operands (grouped key
+    /value heads repeated for it): bf16 operands enter the MXU as bf16
+    with float32 sums, so their tolerance is bf16's; float32 keeps
+    today's."""
+    q, k, v, ct = _grouped(onp.random.RandomState(37), heads, kv_heads,
+                           *seqs, dtype=dtype)
+    scale = 32 ** -0.5
+    got = _fwd_bwd(lambda q_, k_, v_: flash_attention(
+        q_, k_, v_, causal=causal, interpret=True, variant=variant),
+        q, k, v, ct)
+    want = _fwd_bwd(lambda q_, k_, v_: _naive_attention(
+        q_, k_, v_, causal, scale), *(x.astype(jnp.float32)
+                                      for x in (q, k, v)), ct)
+    for name, a, b, x in zip(("out", "dq", "dk", "dv"), got, want,
+                             (q, q, k, v)):
+        assert a.dtype == x.dtype and a.shape == x.shape, name
+        a, b = onp.asarray(a, dtype="float32"), onp.asarray(b)
+        if dtype == "float32":
+            onp.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-4,
+                                        err_msg=name)
+        else:
+            err = onp.abs(a - b).max() / onp.abs(b).max()
+            assert err < 2e-2, (name, err)
+
+
+def test_kernel_pair_is_the_kernels_on_both_passes(monkeypatch):
+    """Where the forward ran the kernel the backward runs the two
+    kernels (the chunked jnp backward is not reached), and where the
+    fused jnp math ran forward the jnp backward follows it."""
+    from mxnet_tpu.ops import flash_attention as fa
+
+    ran = []
+    for name in ("_flash_backward_pallas", "_chunked_bwd"):
+        real = getattr(fa, name)
+        monkeypatch.setattr(fa, name, lambda *a, _r=real, _n=name, **kw:
+                            ran.append(_n) or _r(*a, **kw))
+    q, k, v, ct = _grouped(onp.random.RandomState(5), 4, 2, 128, 128)
+    for variant in ("pallas", "naive"):
+        _fwd_bwd(lambda q_, k_, v_: flash_attention(
+            q_, k_, v_, causal=True, variant=variant), q, k, v, ct)
+    assert ran == ["_flash_backward_pallas", "_chunked_bwd"]
+
+
+def test_backward_declines_what_it_cannot_hold(monkeypatch):
+    """A query sequence longer than ``dkdv`` holds whole, beside keys the
+    forward holds: the backward's decline is counted and the chunked jnp
+    backward gives the same gradients."""
+    from mxnet_tpu.ops import flash_attention as fa
+    from mxnet_tpu.ops import kernel_target
+
+    q, k, v, ct = _grouped(onp.random.RandomState(9), 4, 2, 256, 128)
+    monkeypatch.setattr(fa, "_KV_VMEM_BUDGET", 128 * 4 * 128 * 4)
+    assert fa.max_seq_k(32, "float32") == 128
+    before = kernel_target.declined_counts().get("flash_attention", 0)
+    got = _fwd_bwd(lambda q_, k_, v_: flash_attention(
+        q_, k_, v_, variant="pallas"), q, k, v, ct)
+    assert kernel_target.declined_counts()["flash_attention"] == before + 1
+    want = _fwd_bwd(lambda q_, k_, v_: _naive_attention(
+        q_, k_, v_, False, 32 ** -0.5), q, k, v, ct)
+    for a, b in zip(got, want):
+        onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),
+                                    rtol=1e-3, atol=1e-4)
+
+
+@pytest.mark.parametrize("variant", ["naive", "pallas"])
+def test_gq_attention_serves_groups_without_repeating(variant):
+    """``GQAttention``'s op hands the kernels its 2 key/value heads
+    unrepeated: output and gradients are those of the 8 query heads
+    over key/value heads repeated to 8, the way it ran before."""
+    from mxnet_tpu import autotune as at
+    from mxnet_tpu.gluon.nn.sequence_layers import _gq_attention
+
+    rng = onp.random.RandomState(11)
+    heads, kv_heads, dim, length = 8, 2, 32, 128
+    q = jnp.asarray(rng.randn(2, length, heads * dim).astype("float32"))
+    k, v = (jnp.asarray(rng.randn(2, length, kv_heads * dim)
+                        .astype("float32")) for _ in range(2))
+    ct = jnp.asarray(rng.randn(2, length, heads * dim).astype("float32"))
+
+    def repeated(q_, k_, v_):
+        def split(t, n):
+            return t.reshape(2, length, n, dim).transpose(0, 2, 1, 3)
+
+        def serve(t):
+            return jnp.repeat(split(t, kv_heads), heads // kv_heads, axis=1)
+
+        out = flash_attention(split(q_, heads), serve(k_), serve(v_),
+                              causal=True)
+        return out.transpose(0, 2, 1, 3).reshape(2, length, heads * dim)
+
+    with at.force(flash_attention=variant):
+        got = _fwd_bwd(lambda q_, k_, v_: _gq_attention.fn(
+            q_, k_, v_, heads=heads, kv_heads=kv_heads,
+            scope="gqattention0"), q, k, v, ct)
+        want = _fwd_bwd(repeated, q, k, v, ct)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),
+                                    rtol=1e-5, atol=1e-6)

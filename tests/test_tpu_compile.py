@@ -86,8 +86,22 @@ def test_flash_fwd_bwd_compiles(one_chip, tpu_target, dtype, seq):
     assert "tpu_custom_call" in text
 
 
+def _attention_kernels(text):
+    """The attention kernels a compiled text calls, by the names their
+    ``pallas_call`` passes (a caller's scope before the backward's and
+    transformations round them stripped)."""
+    import re
+
+    return set(re.findall(r"(flash_attention_(?:fwd|bwd_dq|bwd_dkdv))"
+                          r"\)*/pallas_call", text))
+
+
+_ATTENTION_KERNELS = {"flash_attention_fwd", "flash_attention_bwd_dq",
+                      "flash_attention_bwd_dkdv"}
+
+
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-@pytest.mark.parametrize("variant", ["pallas", "pallas_b256"])
+@pytest.mark.parametrize("variant", [None, "pallas", "pallas_b256"])
 def test_flash_longest_claimed_compiles_and_next_is_declined(
         one_chip, tpu_target, dtype, variant):
     longest = fa.max_seq_k(128, dtype)
@@ -95,16 +109,50 @@ def test_flash_longest_claimed_compiles_and_next_is_declined(
     def attn(q, k, v):
         return fa.flash_attention(q, k, v, causal=True, variant=variant)
 
-    spec = ((1, 8, longest, 128), jnp.dtype(dtype))
-    assert "tpu_custom_call" in _compiled_text(attn, one_chip,
-                                               *[spec] * 3)
+    def attn_fwd_bwd(q, k, v, ct):
+        out, vjp = jax.vjp(attn, q, k, v)
+        return (out,) + vjp(ct)
+
+    # the forward and both backward kernels hold the longest claimed,
+    # grouped (8 query heads over 2) as the default path's blocks
+    before = kernel_target.declined_counts().get("flash_attention", 0)
+    q = ((1, 8, longest, 128), jnp.dtype(dtype))
+    kv = ((1, 2, longest, 128), jnp.dtype(dtype))
+    text = _compiled_text(attn_fwd_bwd, one_chip, q, kv, kv, q)
+    assert _attention_kernels(text) == _ATTENTION_KERNELS
+    assert kernel_target.declined_counts().get("flash_attention", 0) \
+        == before
     # one block beyond: declined before lowering (counted), the fused
     # jnp math compiles in its place, and no compiler error is raised
-    before = kernel_target.declined_counts().get("flash_attention", 0)
     spec = ((1, 1, longest + 256, 128), jnp.dtype(dtype))
     text = _compiled_text(attn, one_chip, *[spec] * 3)
     assert "tpu_custom_call" not in text
     assert kernel_target.declined_counts()["flash_attention"] > before
+
+
+def test_grouped_attention_trains_in_kernels_at_the_cells_shape(
+        one_chip, tpu_target):
+    """``nemotron3_nano_train``'s attention, forward and backward: 32
+    query heads over 2 key/value heads, two sequences of 4096 x 128
+    bf16, causal.  The three kernels run it, none declines, and no
+    float32 array of scores (``[..., 512, 4096]`` and the like) is left
+    in the text."""
+    import re
+
+    def attn_fwd_bwd(q, k, v, ct):
+        out, vjp = jax.vjp(functools.partial(
+            fa.flash_attention, causal=True, scope="gqattention0"), q, k, v)
+        return (out,) + vjp(ct)
+
+    before = kernel_target.declined_counts().get("flash_attention", 0)
+    q = ((2, 32, 4096, 128), jnp.bfloat16)
+    kv = ((2, 2, 4096, 128), jnp.bfloat16)
+    text = _compiled_text(attn_fwd_bwd, one_chip, q, kv, kv, q)
+    assert _attention_kernels(text) == _ATTENTION_KERNELS
+    assert "gqattention0_flash_attention_bwd_dkdv" in text
+    assert not re.findall(r"f32\[[\d,]*\d{3,},4096\]", text)
+    assert kernel_target.declined_counts().get("flash_attention", 0) \
+        == before
 
 
 # --------------------------------------------------- fused bucket optimizer
@@ -364,10 +412,11 @@ def test_hybrid_stack_step_names_its_blocks_and_kernels(one_chip,
     scope in the text (``chipbench/metrics/mamba_ms.train.py`` and its
     neighbours read them by name) and no operation hides under a scope
     of a helper's own (an ``einsum``'s spelling, a ``cumsum``),
-    attention's forward runs as ``flash_attention_fwd``, and the counters
-    leave the step as six scalars of its state; the routed bank's three
-    grouped products are kernels whose names are their blocks, and hold
-    both parts by which the mixture's and the bank's readers find them."""
+    attention's forward runs as ``flash_attention_fwd`` and its backward as
+    two kernels whose names hold the block's, and the counters leave the
+    step as six scalars of its state; the routed bank's three grouped
+    products are kernels whose names are their blocks, and hold both
+    parts by which the mixture's and the bank's readers find them."""
     import mxnet_tpu as mx
     from mxnet_tpu import gluon, parallel
     from mxnet_tpu.gluon.model_zoo import language
@@ -398,7 +447,10 @@ def test_hybrid_stack_step_names_its_blocks_and_kernels(one_chip,
                   "sparsemoe0_squaredrelumlp0/", "gqattention0/",
                   "mx_forward", "mx_optimizer"):
         assert scope in text, scope
-    assert _kernel_names(text) == {"flash_attention_fwd", *rex.KERNELS}
+    # attention's backward kernels carry the block's name
+    attention_bwd = fa._bwd_names(net.layers[2].mixer.name)
+    assert _kernel_names(text) == {"flash_attention_fwd", *attention_bwd,
+                                   *rex.KERNELS}
     # the benchmark's parser files an event under the innermost scope
     # that is no wrapper: it has to be a block's (or the kernel's) name
     from chipbench import trace_reduce
@@ -406,18 +458,22 @@ def test_hybrid_stack_step_names_its_blocks_and_kernels(one_chip,
     scopes = trace_reduce.phase_table(text)
     blocks = {trace_reduce.phase_and_block(scope)[1]
               for scope in scopes.values()}
-    kernels = ("flash_attention_fwd",) + rex.KERNELS
+    kernels = ("flash_attention_fwd",) + attention_bwd + rex.KERNELS
     assert all(b in ("",) + kernels or "nemotronh" in b
                or "softmaxcrossentropyloss" in b for b in blocks), blocks
-    # a grouped product's instructions go under its own name, which both
-    # moe_ms.train and expert_roofline.train find; what else a branch of
-    # the bank's cond holds goes under the bank's block
-    for kernel in rex.KERNELS:
+    # a kernel's instructions go under its own name: the grouped
+    # products' names hold the parts by which moe_ms.train and
+    # expert_roofline.train find them, the attention backward's the one
+    # attn_ms.train finds; what else a branch of the bank's cond holds
+    # goes under the bank's block
+    for kernel, parts in [(k, ("sparsemoe", "routedexperts"))
+                          for k in rex.KERNELS] + \
+            [(k, ("gqattention",)) for k in attention_bwd]:
         of_kernel = {trace_reduce.phase_and_block(scope)[1]
                      for name, scope in scopes.items()
                      if trace_reduce.unnumbered(name) == kernel}
         assert of_kernel == {kernel}, (kernel, of_kernel)
-        assert "sparsemoe" in kernel and "routedexperts" in kernel
+        assert all(part in kernel for part in parts), kernel
     assert "conditional(" in text  # 8 of 64 held: a budget can overflow
     assert not any("branch_" in b or b == "cond" for b in blocks), blocks
     assert sorted(state["_counters"]) == [
