@@ -19,14 +19,16 @@ leaves; XLA inserts the all-reduce from the shardings) and
 at build time.  Each keeps its own unscale arithmetic (ROADMAP D13).
 :class:`HostStep` is what the caller holds: the jitted step behind the
 host's own work (RunLog compile events, the profiler's note, the
-numerics read-back).
+record of every call, the numerics read-back).
 """
 from __future__ import annotations
 
 import collections
 import copy
 import functools
+import time
 import warnings
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -589,9 +591,13 @@ class HostStep:
             self.zero_layout = _zero.bucket_layout(ex.plan, ex.stage)
         self._seen, self._last = set(), None
         self._nm_period = _nm.sample_period() if numerics_on else 0
-        self._nm_step = self._calls = 0
+        self._calls = 0
         # MXNET_FAULT_SPEC names the point, or the step never asks
         self._faults = faultsim.armed("step.loss_nan")
+        # every call's record (profiler.step_records); the watcher's
+        # thread ends with this object
+        self._record = _profiler.StepWatch()
+        weakref.finalize(self, self._record.close)
 
     def _compile_event(self, rl, args):
         """One RunLog record per (re)trace of the fused step: the
@@ -642,24 +648,18 @@ class HostStep:
             noted,
             lambda: self._jitted.lower(*shapes).compile().as_text())
 
-    def _read_numerics(self, rl, result):
-        """Sampled readback of the in-graph summaries: the ONLY steps
-        that pay a device sync for the monitor.  Inside an outer trace
-        (bench's chained fori_loop) the values are tracers — nothing to
-        read, skip."""
+    def _read_numerics(self, rl, result, n):
+        """Sampled readback of the in-graph summaries of call ``n``: the
+        ONLY steps that pay a device sync for the monitor."""
         try:
-            loss_v, _, new_s = result
-            vecs = new_s.get("_numerics")
-            if vecs is not None and not isinstance(loss_v,
-                                                   jax.core.Tracer):
-                i = self._nm_step
-                self._nm_step = i + 1
-                if i % self._nm_period == 0:
-                    _nm.emit(rl, i, vecs, where="grad")
+            vecs = result[2].get("_numerics")
+            if vecs is not None and n % self._nm_period == 0:
+                _nm.emit(rl, n, vecs, where="grad")
         except Exception:
             pass  # the monitor must never kill the step
 
     def __call__(self, p, o, x, y, key, t):
+        t_enter = time.perf_counter()
         if self._faults and faultsim.inject("step.loss_nan") == "nan":
             x, y = _poisoned(x, y)
         # MXNET_RUNLOG unset => current() is None => zero per-step work
@@ -676,15 +676,22 @@ class HostStep:
             if self._ex.stage == 3:
                 p = _zero.adopt_layout(self._ex.plan, p)
         # the host span that causes this step's device work, on the
-        # profiler's clock (inactive outside a profiler session)
-        with _tm.tracing.region("mx_step", step_num=self._calls):
+        # profiler's clock (inactive outside a profiler session); the
+        # record's n is its step_num
+        n = self._calls
+        with _tm.tracing.region("mx_step", step_num=n):
             result = self._jitted(p, o, x, y, key, t)
-        self._calls += 1
-        if "_counters" in result[2]:
-            # the arrays as they are: nobody waits unless somebody asks
-            _profiler.note_step_counters(result[2]["_counters"])
+        t_return = time.perf_counter()
+        self._calls = n + 1
+        if isinstance(result[0], jax.core.Tracer):
+            # inside an outer trace (bench's chained fori_loop): no step
+            # ran, nothing to record or read
+            return result
+        # the arrays as they are: nobody waits unless somebody asks
+        self._record.note(n, t_enter, t_return, result[0],
+                          result[2].get("_counters"))
         if self._nm_period and rl is not None:
-            self._read_numerics(rl, result)
+            self._read_numerics(rl, result, n)
         return result
 
 

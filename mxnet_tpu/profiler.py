@@ -30,26 +30,40 @@ its transpose for backward, ``mx_loss``, ``mx_guard``, ``mx_exchange``,
 ``mx_optimizer``), the costliest gluon blocks, Pallas kernels by their
 ``name=``, and the device's idle gaps by the ``mx_*`` host span
 (``telemetry.tracing.region``) that covers most of each.
+
+Without any session, the compiled train step keeps a record of each
+call (:class:`StepWatch`, a ring of the newest 4096): when the call
+began, when it had handed the step to the runtime, when a watcher
+thread saw the step's loss ready on the device, and the seconds of
+garbage collection in between, all on ``time.perf_counter()``.
+:func:`step_records` returns them.  Between two calls in a row, the
+device waited on the host for ``max(0, t_return - previous t_done)``
+and worked alone from ``max(previous t_done, t_return)`` to
+``t_done``; ``dumps()`` ends with the five slowest such steps ("Slowest
+steps"), split so, which is where a stalling job looks first.
 """
 from __future__ import annotations
 
 import atexit
 import bisect
 import collections
+import gc
 import glob
 import json
 import os
+import queue
 import re
 import tempfile
 import threading
 import time
+import weakref
 
 from .base import MXNetError
 
 __all__ = [
     "set_config", "profiler_set_config", "set_state", "profiler_set_state",
     "dump", "dump_profile", "dumps", "device_report", "pause", "resume",
-    "op_scope", "counting", "count", "step_counters",
+    "op_scope", "counting", "count", "step_counters", "step_records",
     "now_us", "run_generation", "record_span", "record_counter",
     "record_instant", "record_meta", "events_snapshot",
     "Domain", "Task", "Frame", "Event", "Counter", "Marker",
@@ -350,7 +364,9 @@ def dumps(reset=False, format="table", sort_by="total", ascending=False):
     ``format="json"`` gives an object ``{"ops": [...], "device":
     ...}``.  After a stopped run that had ``profile_device=True`` the
     device section is :func:`device_report`'s (further tables under
-    the op table); else it is null, and the table ends with the ops.
+    the op table); else it is null.  Once a compiled train step has run,
+    the table ends with its five slowest steps (:func:`step_records`,
+    no session needed).
     """
     if format not in ("table", "json"):
         raise MXNetError(f"invalid format {format!r}")
@@ -378,6 +394,9 @@ def dumps(reset=False, format="table", sort_by="total", ascending=False):
                      f"{mx:>12.1f}{av:>12.1f}")
     if device is not None:
         lines.append(_device_table(device))
+    slowest = _slowest_steps()
+    if slowest:
+        lines.append(_steps_table(slowest))
     return "\n".join(lines)
 
 
@@ -644,12 +663,115 @@ def note_program(key, text_fn):
     _programs[key] = text_fn
 
 
-# ------------------------------------------------- a step's own counters
+# ------------------------------------------- a step's own record and counters
 _counting = threading.local()  # .box: {name: (how, value)} while a forward
 #                                pass that a compiled step traces collects
-_COUNTER_STEPS = 4096
-_step_counters = collections.deque(maxlen=_COUNTER_STEPS)  # what the newest
-#                                steps counted, arrays still on the device
+_RING_STEPS = 4096
+_step_counters = collections.deque(maxlen=_RING_STEPS)  # the newest steps'
+#                                records (_Step), their counters still arrays
+#                                on the device
+_stamped = threading.Condition()  # a watcher stamped a step's completion
+_gc = [0.0, 0.0]  # seconds of garbage collection so far; when the running
+#                   collection started
+_watches = weakref.WeakSet()  # StepWatch objects whose thread runs
+_watching = False  # _end_watches is registered with atexit
+
+
+class _Step:
+    """One call of a compiled train step, on ``time.perf_counter()``.
+    ``gc_s`` holds the collector's running total at ``t_return`` until
+    the watcher stamps ``t_done`` and makes it the difference."""
+
+    __slots__ = ("n", "t_enter", "t_return", "t_done", "gc_s", "counters",
+                 "watch")
+
+    def __init__(self, n=None, t_enter=None, t_return=None, counters=None,
+                 watch=None):
+        self.n, self.t_enter, self.t_return = n, t_enter, t_return
+        self.counters, self.watch = counters, watch
+        self.t_done = None
+        self.gc_s = _gc[0]
+
+
+def _on_gc(phase, info):
+    now = time.perf_counter()
+    if phase == "start":
+        _gc[1] = now
+    else:
+        _gc[0] += now - _gc[1]
+
+
+def _watch_steps(ready):
+    """Stamp when each step's loss is ready on the device, off the
+    calling path (the idiom of ``io.device_feed._watch``): ``ready``
+    gives ``(record, loss)`` in call order and ``None`` at its end; the
+    loss is dropped as soon as it is stamped, and no other array is
+    held."""
+    for rec, loss in iter(ready.get, None):
+        try:
+            loss.block_until_ready()
+        except RuntimeError:
+            pass  # deleted, donated or failed: the step is over either way
+        del loss
+        with _stamped:
+            rec.gc_s = _gc[0] - rec.gc_s
+            rec.t_done = time.perf_counter()
+            _stamped.notify_all()
+        del rec
+
+
+def _end_watches():
+    for watch in list(_watches):
+        watch.close()
+
+
+class StepWatch:
+    """The record of one compiled train step (``parallel.HostStep``):
+    :meth:`note` leaves a record of every call in the ring that
+    :func:`step_records` and :func:`step_counters` read, and a thread of
+    its own, started at the first call, stamps when each call's loss is
+    ready.  :meth:`close` (the step's finalizer, and at interpreter
+    exit) ends the thread: a daemon thread left in a native wait at
+    finalization aborts the process."""
+
+    #: how long a close waits for the thread to take its sentinel
+    JOIN_S = 10.0
+
+    def __init__(self):
+        self._ready = self._thread = None
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+
+    def note(self, n, t_enter, t_return, loss, counters=None):
+        """Call ``n`` entered at ``t_enter`` and handed its step to the
+        runtime at ``t_return``; ``loss`` is that step's loss array,
+        ``counters`` what it counted (``opt_state["_counters"]``)."""
+        rec = _Step(n, t_enter, t_return, counters, self)
+        _step_counters.append(rec)
+        if self._thread is None:
+            self._start()
+        self._ready.put((rec, loss))
+
+    def _start(self):
+        global _watching
+        if not _watching:
+            # registered after jax's own exit hooks, so it runs first
+            atexit.register(_end_watches)
+            _watching = True
+        self._ready = queue.SimpleQueue()
+        self._thread = threading.Thread(
+            target=_watch_steps, args=(self._ready,), name="HostStep-done",
+            daemon=True)
+        self._thread.start()
+        _watches.add(self)
+
+    def close(self):
+        thread = self._thread
+        if thread is None or not thread.is_alive():
+            return
+        self._ready.put(None)
+        if thread is not threading.current_thread():
+            thread.join(self.JOIN_S)
 
 
 class counting:
@@ -690,10 +812,10 @@ def count_all(counted):
 
 
 def note_step_counters(counters):
-    """The train step's host side leaves every step's counters here, as
-    the arrays the step returned: no read-back until they are asked
-    for.  The newest ``_COUNTER_STEPS`` steps are kept."""
-    _step_counters.append(counters)
+    """A step's counters alone, as the arrays the step returned, in a
+    record without times (:class:`StepWatch` notes the train step's
+    own).  The newest ``_RING_STEPS`` records are kept."""
+    _step_counters.append(_Step(counters=counters))
 
 
 def step_counters(last=None):
@@ -701,12 +823,54 @@ def step_counters(last=None):
     step counted (a mixture of experts' ``moe_assignments``,
     ``moe_assignments_held``, ``moe_rows_max``, ``moe_dropped``), ``{}``
     before the first; with ``last=n`` the list of the newest ``n`` steps'
-    (fewer where fewer ran), oldest first.  Waits for those steps."""
+    (fewer where fewer ran), oldest first.  Steps of a net that counts
+    nothing are not among them.  Waits for those steps."""
+    counted = [r.counters for r in list(_step_counters)
+               if r.counters is not None]
     steps = [{k: float(v) for k, v in c.items()}
-             for c in list(_step_counters)[-(last or 1):]]
+             for c in counted[-(last or 1):]]
     if last is None:
         return steps[-1] if steps else {}
     return steps
+
+
+def step_records(last=None):
+    """The newest compiled train steps' records (all that the ring keeps,
+    or the newest ``last``), oldest first, each ``{"n": the how-manieth
+    call of its step, "t_enter": when the call began, "t_return": when it
+    had handed the step to the runtime, "t_done": when the step's loss
+    was ready on the device, "gc_s": seconds of garbage collection
+    between t_return and t_done}``, the times on ``time.perf_counter()``
+    (the clock of :func:`now_us`).  Waits for the steps it returns."""
+    recs = [r for r in list(_step_counters) if r.t_enter is not None]
+    recs = recs[max(0, len(recs) - last):] if last is not None else recs
+    with _stamped:
+        _stamped.wait_for(lambda: all(r.t_done is not None for r in recs))
+    return [{"n": r.n, "t_enter": r.t_enter, "t_return": r.t_return,
+             "t_done": r.t_done, "gc_s": r.gc_s} for r in recs]
+
+
+def _slowest_steps(n=5):
+    """The ``n`` longest completion intervals among the stamped records,
+    as ``(interval, step, host late, device, call, gc)`` in seconds:
+    between two calls of one step in a row, the device waited on the
+    host for ``host late`` and ran ``device`` on its own."""
+    recs = [r for r in list(_step_counters) if r.t_done is not None]
+    rows = [(b.t_done - a.t_done, b.n, max(0.0, b.t_return - a.t_done),
+             b.t_done - max(a.t_done, b.t_return), b.t_return - b.t_enter,
+             b.gc_s)
+            for a, b in zip(recs, recs[1:])
+            if b.watch is a.watch and b.n == a.n + 1]
+    return sorted(rows, key=lambda r: -r[0])[:n]
+
+
+def _steps_table(rows):
+    lines = ["", "Slowest steps (ms)", f"{'Step':>8s}{'Interval':>12s}"
+             f"{'Host late':>12s}{'Device':>12s}{'Call':>12s}{'GC':>12s}"]
+    lines += [f"{step:>8d}" + "".join(f"{v * 1e3:>12.3f}" for v in (
+        interval, late, device, call, gc_s))
+        for interval, step, late, device, call, gc_s in rows]
+    return "\n".join(lines)
 
 
 def device_report():
