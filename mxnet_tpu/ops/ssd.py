@@ -18,10 +18,14 @@ the transpose of these products; the caller rematerialises the block
 keep the ``(chunks, heads, L, L)`` masks.
 
 The products are ``lax.dot_general`` calls, each under a comment with its
-``einsum`` spelling, and the running sums ``lax.cumsum``: ``jnp.einsum``
-and ``jnp.cumsum`` wrap their work in a scope of their own name, under
-which a profile would file the scan's time instead of under the block
-that called it.
+``einsum`` spelling: ``jnp.einsum`` and ``jnp.cumsum`` wrap their work in
+a scope of their own name, under which a profile would file the scan's
+time instead of under the block that called it.  The running sum within
+a chunk is a product too, with a triangle of ones (float32 operands at
+``Precision.HIGHEST``, so every term enters whole): a v5e runs
+``lax.cumsum`` over a chunk's positions as a window sum, some 1.9 ms a
+pass at Nemotron-3-Nano's shape, where the product takes under 0.03 ms.
+The running sum over the chunks (a few dozen) stays ``lax.cumsum``.
 """
 from __future__ import annotations
 
@@ -60,8 +64,10 @@ def ssd_chunked_scan(x, dt, a, b, c, d, *, chunk):
     dts = dt.astype(f32).reshape(bsz, n, chunk, groups, rep)
     bs = b.reshape(bsz, n, chunk, groups, state)
     cs = c.reshape(bsz, n, chunk, groups, state)
-    cum = jax.lax.cumsum(dts * a.astype(f32).reshape(groups, rep), axis=2)
-    cum = jnp.moveaxis(cum, 2, -1)  # (b, n, g, r, l): positions last
+    # "bnsgr,sl->bngrl": the running sum, positions last
+    upper = jnp.triu(jnp.ones((chunk, chunk), f32))  # [s, l]: s <= l
+    cum = _dot(dts * a.astype(f32).reshape(groups, rep), upper,
+               ((2,), (0,)), ((), ()), precision=jax.lax.Precision.HIGHEST)
     xdt = xs.astype(f32) * dts[..., None]
 
     # within a chunk: y_t += sum_{s<=t} exp(cum_t - cum_s) (C_t . B_s) dt_s x_s

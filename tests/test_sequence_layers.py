@@ -79,17 +79,51 @@ def test_rms_norm_and_squared_relu_mlp_are_the_reference_s():
            lambda ws, x: REF.relu2_mlp(x, *ws), w, u)
 
 
-@pytest.mark.parametrize("length", [24, 29, 5])
-def test_chunked_scan_is_the_recurrence(length):
+#: (heads, head_dim, groups, state, chunk): the tiny one, and the
+#: cell's head_dim, state and chunk with four heads a group
+_SCAN_SHAPES = {"tiny": (4, 8, 2, 16, 8), "wide": (8, 64, 2, 128, 128)}
+
+
+@pytest.mark.parametrize("length,shape,dtype", [
+    pytest.param(24, "tiny", "float32", id="24"),
+    pytest.param(29, "tiny", "float32", id="29"),
+    pytest.param(5, "tiny", "float32", id="5"),
+    pytest.param(300, "wide", "float32", id="wide-300"),
+    pytest.param(300, "wide", "bfloat16", id="wide-300-bf16"),
+    pytest.param(77, "wide", "bfloat16", id="wide-77-bf16")])
+def test_chunked_scan_is_the_recurrence(length, shape, dtype):
     """Several chunks, a length that is no multiple of the chunk, and one
-    shorter than a chunk; forward and every gradient."""
-    x = _rand(0, 2, length, 4, 8)
-    dt = jax.nn.softplus(_rand(1, 2, length, 4))
-    a = -jnp.exp(_rand(2, 4, scale=0.3))
-    b, c = _rand(3, 2, length, 2, 16), _rand(4, 2, length, 2, 16)
-    d = 1.0 + _rand(5, 4, scale=0.1)
-    _agree(lambda ws, *xs: ssd.ssd_chunked_scan(*xs, *ws, chunk=8),
-           lambda ws, *xs: REF.scan(*xs, *ws), [d], x, dt, a, b, c)
+    shorter than a chunk; forward and every gradient; in bf16 (``x``,
+    ``B``, ``C`` and the cotangent, as the cell runs them) within a bf16
+    rounding of the float32 recurrence."""
+    heads, head_dim, groups, state, chunk = _SCAN_SHAPES[shape]
+    x = _rand(0, 2, length, heads, head_dim)
+    dt = jax.nn.softplus(_rand(1, 2, length, heads))
+    a = -jnp.exp(_rand(2, heads, scale=0.3))
+    scale = 16 ** 0.5 / state ** 0.5  # C . B of one size at any state
+    b = _rand(3, 2, length, groups, state, scale=scale)
+    c = _rand(4, 2, length, groups, state, scale=scale)
+    d = 1.0 + _rand(5, heads, scale=0.1)
+    ours = (lambda ws, *xs: ssd.ssd_chunked_scan(*xs, *ws, chunk=chunk))
+    if dtype == "float32":
+        _agree(ours, lambda ws, *xs: REF.scan(*xs, *ws), [d], x, dt, a, b, c)
+        return
+    bf = jnp.bfloat16
+    low = (x.astype(bf), dt, a, b.astype(bf), c.astype(bf))
+    cot = _rand(6, *x.shape).astype(bf)
+
+    def values(f, xs, cot):
+        y, vjp = jax.vjp(f, [d], *xs)
+        assert y.dtype == xs[0].dtype
+        return [y] + jax.tree_util.tree_leaves(vjp(cot))
+    with jax.default_matmul_precision("highest"):
+        exact = values(lambda ws, *xs: REF.scan(*xs, *ws),
+                       tuple(t.astype(jnp.float32) for t in low),
+                       cot.astype(jnp.float32))
+    for got, want in zip(values(ours, low, cot), exact):
+        got, want = (np.asarray(t, np.float32) for t in (got, want))
+        # y, dD, dx, ddt, dA, dB, dC: at most 0.0032 on the CPU
+        assert np.linalg.norm(got - want) < 0.01 * np.linalg.norm(want)
 
 
 def _mixer_leaves():

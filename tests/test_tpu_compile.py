@@ -22,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 from mxnet_tpu.ops import flash_attention as fa
 from mxnet_tpu.ops import kernel_target, pallas_conv, pallas_opt
 from mxnet_tpu.ops import routed_experts as rex
+from mxnet_tpu.ops import ssd
 from mxnet_tpu.optimizer.optimizer import LARS, SGD, Adam
 
 #: ResNet-50's trainable parameters as one flat bucket
@@ -153,6 +154,30 @@ def test_grouped_attention_trains_in_kernels_at_the_cells_shape(
     assert not re.findall(r"f32\[[\d,]*\d{3,},4096\]", text)
     assert kernel_target.declined_counts().get("flash_attention", 0) \
         == before
+
+
+# ------------------------------------------------------ state-space scan
+def test_scan_runs_no_window_sum_over_a_chunk_at_the_cells_shape(one_chip):
+    """``nemotron3_nano_train``'s scan, forward and backward (two
+    sequences of 4096, 64 heads of 64 in 8 groups, state 128, chunk 128,
+    bf16): the running sum within a chunk is a product, so no
+    ``reduce-window`` 128 positions long is left in either pass (a v5e
+    ran each as a 1.9 ms window sum); the one over the 32 chunks stays."""
+    import re
+
+    def fwd_bwd(x, dt, a, b, c, d, ct):
+        y, vjp = jax.vjp(functools.partial(ssd.ssd_chunked_scan, chunk=128),
+                         x, dt, a, b, c, d)
+        return (y,) + vjp(ct)
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    text = _compiled_text(
+        fwd_bwd, one_chip, ((2, 4096, 64, 64), bf16), ((2, 4096, 64), f32),
+        ((64,), f32), ((2, 4096, 8, 128), bf16), ((2, 4096, 8, 128), bf16),
+        ((64,), f32), ((2, 4096, 64, 64), bf16))
+    windows = re.findall(r"reduce-window\(.*?window=\{size=([\dx]+)", text)
+    assert windows and all("128" not in w.split("x") for w in windows), \
+        windows
 
 
 # --------------------------------------------------- fused bucket optimizer
