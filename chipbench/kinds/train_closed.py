@@ -462,8 +462,16 @@ def readings(cell, seeds, n_controls, flush=None, program_until=None,
     what it read.  No seed's program starts later than ``program_until``
     seconds after the clock's origin, no reading later than ``until``: a
     call on the chip is paid by the second, and a cold one compiles for
-    minutes."""
+    minutes.
+
+    The chip holds the program's state once, whatever the cell's size:
+    each seed takes the state the seed before it left, its weights placed
+    anew from the seed and its optimizer state set to nought in place,
+    which is the state that :func:`build` makes (a step whose state starts
+    otherwise is refused).  The host keeps a seed's losses, leaf norms and
+    batches, and draws its weights again for the reference."""
     import jax
+    import jax.numpy as jnp
 
     config, traffic, say = cell["config"], cell["traffic"], cell["say"]
     built = build(cell)
@@ -479,27 +487,39 @@ def readings(cell, seeds, n_controls, flush=None, program_until=None,
         return limit is not None \
             and time.perf_counter() - cell["t0"] > limit
 
-    template = (built.pop("params"), built.pop("opt_state"))
-    copy = jax.jit(lambda tree: jax.tree_util.tree_map(lambda a: a + 0,
-                                                       tree))
+    params, opt_state = built.pop("params"), built.pop("opt_state")
+    zeros = jax.jit(lambda tree: jax.tree_util.tree_map(
+        lambda a: jnp.all(a == 0), tree))(opt_state)
+    if not all(jax.tree_util.tree_leaves(zeros)):
+        raise ValueError("readings restart each seed's optimizer state at "
+                         "nought, and this step's does not start there")
+    wipe = jax.jit(
+        lambda tree: jax.tree_util.tree_map(jnp.zeros_like, tree),
+        donate_argnums=0,
+        out_shardings=jax.tree_util.tree_map(lambda a: a.sharding,
+                                             opt_state))
     kept = {}
     for seed in seeds:
         if kept and late(program_until):
             say(f"no time for the program on seed {seed} and after")
             break
-        params, opt_state = copy(template)
-        loop, feed, w0, pool = start(cell, built, seed, params, opt_state,
-                                     traffic["check_steps"])
+        loop, feed, w0, pool = start(cell, built, seed, params,
+                                     wipe(opt_state), traffic["check_steps"])
+        params = opt_state = None  # the loop's now: one copy on the chip
         try:
-            kept[seed] = (first_steps(cell, built, loop, w0), w0, pool)
+            kept[seed] = (first_steps(cell, built, loop, w0), pool)
         finally:
             feed.close()
         say(f"seed {seed}: program losses "
             + " ".join(f"{v:.4f}" for v in loop.losses))
+        params, opt_state = loop.params, loop.opt_state
         loop.params = loop.opt_state = None
     built.pop("step")
-    del template
+    del params, opt_state
     out = {"names": built["names"]}
+
+    def weights_of(seed):
+        return [np.asarray(a) for a in weights.make(specs_of(cell), seed)]
 
     def read(seed, who, side, ref):
         values = compare.numbers(side, ref)[0]
@@ -514,19 +534,19 @@ def readings(cell, seeds, n_controls, flush=None, program_until=None,
             flush(out)
 
     refs = {}
-    for seed, (prog, w0, pool) in kept.items():
+    for seed, (prog, pool) in kept.items():
         if refs and late(until):
             say(f"no time for the reference on seed {seed} and after")
             break
-        refs[seed] = reference_side(cell, built, w0, pool)
+        refs[seed] = reference_side(cell, built, weights_of(seed), pool)
         read(seed, "program", prog, refs[seed])
     for who, fault in faults.items():
         for seed in list(refs)[:n_controls]:
             if late(until):
                 say(f"no time for {who} on seed {seed} and after")
                 break
-            _, w0, pool = kept[seed]
-            read(seed, who, reference_side(cell, built, w0, pool, **fault),
+            read(seed, who, reference_side(cell, built, weights_of(seed),
+                                           kept[seed][1], **fault),
                  refs[seed])
     return out
 

@@ -4,22 +4,16 @@ experts this chip holds, in percent: the mean over the window's steps of
 ``moe_assignments_held / moe_assignments``.  The program counts both
 inside its compiled step and hands them out through the step's state;
 its host side keeps every step's (``mxnet_tpu.profiler.step_counters``),
-the traced tail's steps after the window's.  16 experts of 128 held would
-read 6.25 under even routing; no balancing rule runs, so it drifts.
-Nothing where the program has no such counters (a program without them,
-a net without a mixture)."""
+the traced tail's steps after the window's
+(``chipbench/step_record.py`` ``window_counters``).  16 experts of 128
+held would read 6.25 under even routing; no balancing rule runs, so it
+drifts.  Nothing where the program has no such counters (a program
+without them, a net without a mixture)."""
+from chipbench import step_record
 
 
 def read(run):
-    try:
-        from mxnet_tpu import profiler
-    except ImportError:
-        return None
-    if not hasattr(profiler, "step_counters") or not run.get("window"):
-        return None
-    tail = run["traffic"]["trace_steps"] if run.get("trace") else 0
-    steps = profiler.step_counters(last=run["window"]["steps"] + tail)
-    steps = steps[:len(steps) - tail] if tail else steps
+    steps = step_record.window_counters(run) or []
     shares = [s["moe_assignments_held"] / s["moe_assignments"]
               for s in steps if s.get("moe_assignments")]
     return 100.0 * sum(shares) / len(shares) if shares else None

@@ -11,7 +11,12 @@ out.  Between step ``i - 1`` and step ``i`` the device waited on the
 host from ``t_done[i - 1]`` to ``t_return[i]`` where that is later, and
 worked on its own from the later of the two to ``t_done[i]``.  The
 readers of ``device_step_ms.train``, ``host_late_ms.train``,
-``step_excess_ms.train`` and ``step_call_ms.train`` start here."""
+``step_excess_ms.train`` and ``step_call_ms.train`` start here.
+
+The same ring holds what the blocks of the compiled step counted
+(``profiler.step_counters()``): :func:`window_counters` gives the
+window's, which the mixture's readers (``moe_held_pct.train``,
+``moe_grouped_pct.train``) average."""
 import numpy as np
 
 
@@ -36,3 +41,18 @@ def window_records(run):
         return None
     return {k: np.array([r[k] for r in found], dtype=np.float64)
             for k in ("t_enter", "t_return", "t_done")}
+
+
+def window_counters(run):
+    """The counters of the window's steps, oldest first, the traced
+    tail's (which come after) left out; None where the program keeps no
+    such counters or the run has no window."""
+    try:
+        from mxnet_tpu import profiler
+    except ImportError:
+        return None
+    if not hasattr(profiler, "step_counters") or not run.get("window"):
+        return None
+    tail = run["traffic"]["trace_steps"] if run.get("trace") else 0
+    steps = profiler.step_counters(last=run["window"]["steps"] + tail)
+    return steps[:len(steps) - tail] if tail else steps

@@ -58,9 +58,10 @@ def test_program_without_the_new_counters(name, reads):
 
 def test_readers_go_through_run_py():
     cell = cb.load_cell("vgg16_train")
-    new = [m for m in cell["per_layer"] if m["name"].startswith("feed_")
-           and m["name"] != "feed_wait_ms.train"]
-    assert len(new) == 3
+    names = {"feed_busy_pct.train", "feed_source_ms.train",
+             "feed_depth.train"}
+    new = [m for m in cell["per_layer"] if m["name"] in names]
+    assert {m["name"] for m in new} == names
     got = cb.read_metrics(new, {"window": WINDOW})
     assert {k: v["unit"] for k, v in got.items()} == {
         "feed_busy_pct.train": "%", "feed_source_ms.train": "ms/step",

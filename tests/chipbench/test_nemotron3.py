@@ -42,6 +42,10 @@ PUBLISHED = {
     "topk_group": 1, "use_bias": False, "use_conv_bias": True,
     "use_mamba_kernels": True, "vocab_size": 131072}
 REDUCED = {"num_hidden_layers": 9, "n_routed_experts": 8, "vocab_size": 16384}
+#: the per-layer metrics that ``BENCHMARK.json`` lists for this cell alone
+OWN_METRICS = ("mamba_ms.train", "ssd_roofline.train", "moe_ms.train",
+               "expert_roofline.train", "moe_held_pct.train",
+               "attn_ms.train", "moe_grouped_pct.train")
 
 
 @pytest.fixture(scope="module")
@@ -200,28 +204,54 @@ def test_each_new_reader_reads_a_made_up_reduced_trace(config):
     assert 0.0 < _read("expert_roofline.train", run) < 100.0
 
 
-def test_held_share_is_the_window_s_mean_of_the_step_s_counters(config):
+@pytest.fixture
+def counters():
+    """The program's ring of step records, empty for the test, then as it
+    was."""
     from mxnet_tpu import profiler
 
-    run = _made_up_run(config)
-    tail = run["traffic"]["trace_steps"]
     kept = list(profiler._step_counters)
     profiler._step_counters.clear()
-    try:
-        assert _read("moe_held_pct.train", run) is None
-        shares = [0.5, 0.0625, 0.125, 0.25] + [0.9] * tail
-        for share in shares:  # a warm-up step, the window's 3, the tail
-            profiler.note_step_counters(
-                {"moe_assignments": 1000.0,
-                 "moe_assignments_held": 1000.0 * share})
-        assert _read("moe_held_pct.train", run) == pytest.approx(
-            100.0 * (0.0625 + 0.125 + 0.25) / 3)
-        # an untraced run: the window's steps are the newest
-        assert _read("moe_held_pct.train", dict(run, trace=None)) \
-            == pytest.approx(90.0)
-    finally:
-        profiler._step_counters.clear()
-        profiler._step_counters.extend(kept)
+    yield profiler.note_step_counters
+    profiler._step_counters.clear()
+    profiler._step_counters.extend(kept)
+
+
+def test_held_share_is_the_window_s_mean_of_the_step_s_counters(
+        config, counters):
+    run = _made_up_run(config)
+    tail = run["traffic"]["trace_steps"]
+    assert _read("moe_held_pct.train", run) is None
+    shares = [0.5, 0.0625, 0.125, 0.25] + [0.9] * tail
+    for share in shares:  # a warm-up step, the window's 3, the tail
+        counters({"moe_assignments": 1000.0,
+                  "moe_assignments_held": 1000.0 * share})
+    assert _read("moe_held_pct.train", run) == pytest.approx(
+        100.0 * (0.0625 + 0.125 + 0.25) / 3)
+    # an untraced run: the window's steps are the newest
+    assert _read("moe_held_pct.train", dict(run, trace=None)) \
+        == pytest.approx(90.0)
+
+
+def test_grouped_share_is_the_window_s_mean_of_the_step_s_counters(
+        config, counters):
+    """Of four mixture layers a step, those that took the grouped path:
+    the window's mean, the warm-up before it and the traced tail after it
+    left out; nothing without counters or without a mixture layer."""
+    run = _made_up_run(config)
+    tail = run["traffic"]["trace_steps"]
+    assert _read("moe_grouped_pct.train", run) is None
+    grouped = [0, 4, 3, 4] + [2] * tail  # a warm-up step, the window's 3
+    for n in grouped:
+        counters({"moe_layers": 4.0, "moe_layers_grouped": float(n),
+                  "moe_assignments": 1000.0, "moe_assignments_held": 50.0})
+    assert _read("moe_grouped_pct.train", run) == pytest.approx(
+        100.0 * (1.0 + 0.75 + 1.0) / 3)
+    assert _read("moe_grouped_pct.train", dict(run, trace=None)) \
+        == pytest.approx(50.0)
+    for _ in grouped:  # a step that ran no mixture layer reads nothing
+        counters({"moe_layers": 0.0, "moe_layers_grouped": 0.0})
+    assert _read("moe_grouped_pct.train", run) is None
 
 
 @pytest.mark.parametrize("name", [
@@ -241,14 +271,52 @@ def test_a_reader_finds_nothing_where_its_block_is_absent(config, name):
 
 
 def test_benchmark_entries_name_the_cell(config):
+    """The configuration, the cell and the cell's own per-layer entries,
+    each found by its name and never by its place: a later PR appends
+    configurations, cells and metrics of its own to every list."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    mine = [m for m in bench["per_layer"] if m.get("workloads") == [CELL]]
-    assert [m["name"] for m in mine] == [
-        "mamba_ms.train", "ssd_roofline.train", "moe_ms.train",
-        "expert_roofline.train", "moe_held_pct.train", "attn_ms.train"]
-    assert all(m["moves"] == "images_per_s" for m in mine)
-    assert bench["per_layer"][-6:] == mine and bench["workloads"][-1] == {
-        "name": CELL, "config": NAME, "traffic": "train_tok4096_bs2",
-        "chips": 1, "why": bench["workloads"][-1]["why"]}
-    assert bench["configs"][-1]["source"] == config["source"]
+    [entry] = [c for c in bench["configs"] if c["name"] == NAME]
+    assert entry["source"] == config["source"]
+    assert entry["reduced"] == config["reduced"]
+    [cell] = [w for w in bench["workloads"] if w["name"] == CELL]
+    assert {k: cell[k] for k in ("config", "traffic", "chips")} == {
+        "config": NAME, "traffic": "train_tok4096_bs2", "chips": 1}
+    mine = {m["name"]: m for m in bench["per_layer"]
+            if m.get("workloads") == [CELL]}
+    assert set(OWN_METRICS) <= set(mine)
+    assert all(mine[n]["moves"] == "images_per_s" for n in OWN_METRICS)
+
+
+def test_readings_hold_one_copy_of_the_state_and_read_as_from_build():
+    """``readings`` (``chipbench/readings.py``) keeps one copy of the
+    program's state on the chip, handing each seed the one the seed
+    before left, wiped, and draws a seed's weights again for the
+    reference: a seed read second reads exactly what the first steps
+    from the state that ``build`` makes read, with the same verdict."""
+    import time
+
+    import jax
+    from chipbench import compare
+    from test_chipbench import _tiny
+
+    cell = dict(_tiny(CELL), devices=jax.devices(), say=lambda m: None,
+                t0=time.perf_counter())
+    kind = cb.load_module("kinds", cell["traffic"]["kind"])
+    out = kind.readings(cell, [12, 11], 0)
+
+    built = kind.build(cell)
+    loop, feed, w0, pool = kind.start(
+        cell, built, 11, built.pop("params"), built.pop("opt_state"),
+        cell["traffic"]["check_steps"])
+    try:
+        prog = kind.first_steps(cell, built, loop, w0)
+    finally:
+        feed.close()
+    ref = kind.reference_side(cell, built, w0, pool)
+    values = compare.numbers(prog, ref)[0]
+    assert out[11]["program"] == values
+    assert out[11]["correct"]["program"] == compare.verdict(
+        values, cell["traffic"]["limits"])[0] is True
+    assert out[11]["leaves"]["reference"] == kind._plain(ref)
+    assert out[11]["leaves"]["program"] == kind._plain(prog)
